@@ -26,9 +26,6 @@ def main():
             f"Checking two phase commit with {rm_count} resource managers "
             "on the device frontier checker."
         )
-        from _cli import pin_device_platform
-
-        pin_device_platform()
         from stateright_tpu.tensor.models import TensorTwoPhaseSys
 
         report(TensorTwoPhaseSys(rm_count).checker().spawn_tpu())

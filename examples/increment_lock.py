@@ -31,9 +31,6 @@ def main():
             f"Model checking increment_lock with {n} threads on the device "
             f"frontier checker{' using symmetry reduction' if sym else ''}."
         )
-        from _cli import pin_device_platform
-
-        pin_device_platform()
         from stateright_tpu.tensor.models import TensorIncrementLock
 
         report(

@@ -41,9 +41,6 @@ def main():
             f"Model checking Single Decree Paxos with {client_count} clients "
             "on the device frontier checker."
         )
-        from _cli import pin_device_platform
-
-        pin_device_platform()
         from stateright_tpu.tensor.paxos import TensorPaxos
 
         batch, table = (2048, 16) if client_count <= 2 else (8192, 22)

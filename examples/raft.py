@@ -16,9 +16,6 @@ from stateright_tpu.core.discovery import HasDiscoveries
 
 
 def _model(server_count: int, max_term: int):
-    from _cli import pin_device_platform
-
-    pin_device_platform()
     from stateright_tpu.tensor.models import TensorRaft
 
     return TensorRaft(server_count, max_term)

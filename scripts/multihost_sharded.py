@@ -1,4 +1,4 @@
-"""Two-process `jax.distributed` validation of ShardedSearch (VERDICT r3 #7).
+"""Two-process `jax.distributed` validation of ShardedSearch.
 
 Each process contributes 4 virtual CPU devices (gloo collectives) and runs
 the SAME SPMD program: one 8-device global mesh, one whole-search dispatch.
@@ -16,7 +16,7 @@ Run one process per rank (the test harness does this):
 
 Each rank prints one JSON line with the global counts; the counts must be
 identical on every rank and match the single-process goldens
-(2PC-4: 8,258 generated / 1,568 unique — BASELINE_MEASURED.md).
+(2PC-4: 8,258 generated / 1,568 unique — bench.GOLDEN).
 """
 
 import argparse

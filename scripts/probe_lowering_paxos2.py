@@ -1,7 +1,7 @@
 """Probe: lower the real 2-client Paxos ActorModel with the GENERIC lowering
 and compare against the reference golden (32,971 generated / 16,668 unique,
 ref examples/paxos.rs:327,351) and the hand-built TensorPaxos. Reports closure
-wall time and table sizes (VERDICT r2 'next' #3)."""
+wall time and table sizes."""
 import os, sys, time
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

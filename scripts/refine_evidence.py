@@ -1,4 +1,4 @@
-"""Timing evidence for warm-round refinement (VERDICT r4 next #6): refine
+"""Timing evidence for warm-round refinement: refine
 paxos-C end-to-end on this host and print per-round + total wall time.
 Usage: python scripts/refine_evidence.py [clients=2] [batch=2048] [table_log2=21]
 """

@@ -38,12 +38,6 @@ def collect_testers(model, cap):
 
 
 def main() -> int:
-    import jax
-
-    p = os.environ.get("JAX_PLATFORMS")
-    if p:
-        jax.config.update("jax_platforms", p)
-
     from stateright_tpu.actor import Network
     from stateright_tpu.actor.register import GetOk
     from stateright_tpu.examples.abd import AbdModelCfg

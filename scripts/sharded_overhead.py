@@ -1,5 +1,4 @@
-"""Sharded-vs-single overhead ratio on the virtual CPU mesh (VERDICT r4
-next #4): run the SAME workload on the single-device resident engine and on
+"""Sharded-vs-single overhead ratio on the virtual CPU mesh: run the SAME workload on the single-device resident engine and on
 the N-device sharded engine, print states/s for both and the ratio.
 
 Usage: python scripts/sharded_overhead.py [workload=2pc7] [n_chips=8]
@@ -111,5 +110,5 @@ sps_shard = shard.state_count / shard.duration
 print(f"sharded-{n_chips}:  {sps_shard:,.0f} states/s ({shard.duration:.2f}s)")
 print(
     f"RATIO sharded/single = {sps_shard / sps_single:.3f} "
-    f"(>0.5 means <2x overhead — VERDICT r4 next #4 target)"
+    f"(>0.5 means <2x overhead)"
 )

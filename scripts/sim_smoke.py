@@ -31,11 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def main() -> int:
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
     from stateright_tpu.core.discovery import HasDiscoveries
     from stateright_tpu.examples.two_phase_commit import TwoPhaseSys
     from stateright_tpu.tensor.models import TensorTwoPhaseSys

@@ -6,14 +6,18 @@ get C++ implementations compiled on first use with the toolchain baked into
 the image (no pybind11 — plain C ABI + ctypes). Everything has a pure-Python
 fallback, so a missing compiler degrades performance, never correctness.
 
-Shared objects are cached next to the sources in `build/` keyed by source
-mtime, so repeat imports don't pay the compile.
+Artifacts are cached next to the sources in `build/`, named by a hash of
+the source text, the compiler flags and the host (`-march=native` code
+built on one machine may not run on another), so repeat imports don't pay
+the compile and a tree copied to another host never reuses a stale build.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 
@@ -23,27 +27,55 @@ _lock = threading.Lock()
 _cache: dict = {}
 
 
+def _host_id() -> bytes:
+    """What a native build depends on besides its source and flags: the
+    machine and its CPU model and feature flags."""
+    parts = [platform.machine(), platform.node()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            parts += sorted(
+                {ln for ln in f if ln.startswith(("model name", "flags"))}
+            )
+    except OSError:
+        pass
+    return "\n".join(parts).encode()
+
+
+def _flags(exe: bool) -> list:
+    if exe:
+        return ["-O3", "-march=native", "-pthread"]
+    return ["-O2", "-shared", "-fPIC"]
+
+
+def artifact_path(name: str, *, exe: bool = False) -> str:
+    """Where `build` keeps `<name>.cpp`'s artifact: named by a hash of the
+    source text, the compiler flags and `_host_id()`."""
+    h = hashlib.sha256()
+    with open(os.path.join(_DIR, f"{name}.cpp"), "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_flags(exe)).encode())
+    h.update(_host_id())
+    return os.path.join(
+        _BUILD, f"{name}-{h.hexdigest()[:16]}" + ("" if exe else ".so")
+    )
+
+
 def build(name: str, *, exe: bool = False, timeout: float = 300.0) -> str:
-    """Compile `<name>.cpp` into the build cache (keyed by source mtime) and
-    return the artifact path. `exe=False` builds a shared object for ctypes;
-    `exe=True` builds a standalone optimized executable (used by the bench
-    harness for the CPU baseline checker)."""
+    """Compile `<name>.cpp` into the build cache (see `artifact_path`) and
+    return the artifact path. `exe=False` builds a shared object for
+    ctypes; `exe=True` builds a standalone optimized executable (used by
+    the bench harness for the CPU baseline checker)."""
     src = os.path.join(_DIR, f"{name}.cpp")
-    out = os.path.join(_BUILD, name + ("" if exe else ".so"))
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    out = artifact_path(name, exe=exe)
+    if os.path.exists(out):
         return out
     os.makedirs(_BUILD, exist_ok=True)
-    flags = (
-        ["-O3", "-march=native", "-pthread"]
-        if exe
-        else ["-O2", "-shared", "-fPIC"]
-    )
     # Per-process temp name so concurrent compiles can't interleave output;
     # os.replace makes the publish atomic.
     tmp = f"{out}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            ["g++", "-std=c++17", *flags, "-o", tmp, src],
+            ["g++", "-std=c++17", *_flags(exe), "-o", tmp, src],
             check=True,
             capture_output=True,
             timeout=timeout,

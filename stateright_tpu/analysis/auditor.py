@@ -44,15 +44,8 @@ from typing import Optional
 
 import jax
 
-try:  # jax >= 0.4.x
-    from jax.extend import core as jcore
-except ImportError:  # pragma: no cover - older images
-    from jax import core as jcore  # type: ignore
-
-try:
-    from jax._src import source_info_util as _siu
-except ImportError:  # pragma: no cover - private-API drift
-    _siu = None
+from jax._src import source_info_util as _siu
+from jax.extend import core as jcore
 
 #: primitives that are host round trips — never legal inside a step.
 CALLBACK_PRIMS = {
@@ -152,14 +145,10 @@ def _aval_bytes(aval) -> int:
 
 
 def _loc(eqn) -> str:
-    if _siu is not None:
-        try:
-            frame = _siu.user_frame(eqn.source_info)
-            if frame is not None:
-                return f"{frame.file_name}:{frame.start_line}"
-        except Exception:
-            pass
-    return "unknown"
+    frame = _siu.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "unknown"
+    return f"{frame.file_name}:{frame.start_line}"
 
 
 def _sub_jaxprs(params: dict):

@@ -52,7 +52,6 @@ TRACED_HOFS = {
     "jax.vmap",
     "jax.pmap",
     "jax.shard_map",
-    "jax.experimental.shard_map.shard_map",
     "jax.checkpoint",
     "jax.remat",
 }

@@ -27,7 +27,7 @@ class TpuChecker(Checker):
         # ResidentSearch options like table_layout ("split"/"kv"),
         # insert_variant (knobs.INSERT_VARIANTS: "sort"/"phased"/"capped"/
         # "capped-phased"/"pallas" — the last is the partitioned-VMEM
-        # Pallas kernel, interpret mode off-TPU),
+        # Pallas kernel, interpret mode on the CPU),
         # append ("scatter"/"dus"), queue_log2, donate_chunks, the
         # tiered-store knobs (store="tiered", high_water, low_water,
         # summary_log2 — stateright_tpu/store/), and the telemetry knobs

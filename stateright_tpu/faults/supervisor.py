@@ -20,9 +20,8 @@ Recovery policy, in order:
    the named resource through the engines' own checkpoint+regrow machinery
    instead of burning generic retries.
 3. **Degrade ladder** — repeated failures at one rung escalate:
-   retry-same-config → shrink batch K → enable (or widen) the tiered store
-   → `JAX_PLATFORMS=cpu` as the last resort (effective for engines built
-   after the switch; recorded either way).
+   retry-same-config → shrink batch K → enable (or widen) the tiered store.
+   The ladder never leaves the device: a run that fails there fails.
 4. **Watchdog** — each slice runs under a deadline; a hang is cancelled
    (injected hang gates) or abandoned (real ones) and converted into a
    retriable `WatchdogTimeout`.
@@ -59,7 +58,7 @@ from .plan import (
 ENGINES = ("frontier", "resident", "sharded")
 
 #: Degrade ladder rung names, in escalation order.
-RUNGS = ("retry", "shrink_batch", "tiered", "cpu")
+RUNGS = ("retry", "shrink_batch", "tiered")
 
 
 @dataclass
@@ -363,21 +362,6 @@ class Supervisor:
                 self.engine_kwargs["store"] = "tiered"
             # A store change cannot ride a checkpoint resume (the store
             # config travels in checkpoint meta); restart fresh.
-            self._drop_checkpoint()
-        elif rung == "cpu":
-            # Last resort. The env var covers worker subprocesses and any
-            # jax not yet initialized; jax.config.update is the in-process
-            # attempt — best-effort, because a backend that has already
-            # served a computation may be pinned for the process lifetime
-            # (in which case this rung is recorded as attempted and the
-            # remaining retries run on the original platform).
-            os.environ["JAX_PLATFORMS"] = "cpu"
-            try:
-                import jax
-
-                jax.config.update("jax_platforms", "cpu")
-            except Exception:  # noqa: BLE001 — backend already pinned
-                pass
             self._drop_checkpoint()
 
     def _drop_checkpoint(self) -> None:

@@ -21,7 +21,7 @@ from __future__ import annotations
 #: ShardedSearch (`insert_variant=`). "pallas" is the partitioned-VMEM
 #: route-then-probe kernel (tensor/pallas_hashtable.py — SURVEY §7's
 #: prescribed "open-addressing table in HBM updated by a Pallas kernel");
-#: on non-TPU backends it runs under Pallas interpret mode with exact
+#: on the CPU backend it runs under Pallas interpret mode with exact
 #: set/is_new parity to the XLA designs. The name → insert-fn dispatch
 #: lives in ONE module, tensor/inserts.py, which all three engines import
 #: (check_registry() pins the two against each other).

@@ -36,6 +36,7 @@ a mutation of the committed V5E/CPU1 anchors.
 from __future__ import annotations
 
 import json
+import logging
 import math
 import os
 import time
@@ -87,22 +88,28 @@ _INF_GBPS = 1e18  # a bandwidth so high its 1/gbps theta component is ~0
 
 
 def calib_enabled() -> bool:
-    return os.environ.get(ENV_ENABLE, "1") != "0"
+    """Off with SR_TPU_CALIB=0, and off — with the reason logged — on a
+    device the cost model has no peaks for."""
+    if os.environ.get(ENV_ENABLE, "1") == "0":
+        return False
+    try:
+        default_device_kind()
+    except ValueError as e:
+        logging.getLogger(__name__).warning("calibration off: %s", e)
+        return False
+    return True
 
 
 def default_device_kind() -> str:
-    """Device-kind name for prediction: env override, else the active jax
-    backend (cpu -> the CPU1 spec, anything accelerated -> V5E)."""
+    """Spec name for prediction: env override, else the committed spec of
+    `jax.devices()[0].device_kind` (ValueError for a kind the cost model's
+    table does not list — never another device's peaks)."""
     kind = os.environ.get(ENV_DEVICE)
     if kind:
         return kind
-    try:  # jax is already resident in every engine process; stay lazy
-        import jax
+    import jax  # resident in every engine process already; stay lazy
 
-        platform = jax.default_backend()
-    except Exception:
-        platform = "cpu"
-    return "cpu-1core" if platform == "cpu" else "tpu-v5e"
+    return costmodel.spec_for_device_kind(jax.devices()[0].device_kind).name
 
 
 def theta_of(device: DeviceSpec) -> list:

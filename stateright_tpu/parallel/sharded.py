@@ -37,7 +37,7 @@ Sharding invariants:
   available by passing dest_capacity=batch_size*max_actions explicitly; the
   round-4 default reserved that bound per destination, which made every
   all-to-all, insert, and append run on N x the real traffic — measured as
-  a 5.4x sharding overhead on the 8-device virtual mesh (VERDICT r4 #5).
+  a 5.4x sharding overhead on the 8-device virtual mesh.
 - Routing positions come from per-destination cumsums (static unroll over the
   N destinations), not a sort: the received batch may contain duplicates and
   the hash-table insert resolves them (phase-3 arena).
@@ -53,17 +53,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-
-# jax.shard_map landed as a top-level API after 0.4.x (with check_vma
-# replacing check_rep); fall back to the experimental home so the engine
-# runs on both sides of the rename.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax 0.4.x images
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
 
 from ..faults.ckptio import fenced_savez, load_latest
 from ..faults.plan import maybe_fault
@@ -921,30 +910,30 @@ class ShardedSearch:
             out = jax.tree.map(lambda x: jnp.asarray(x)[None], c)
             return out, shard(summary)
 
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             per_chip,
             mesh=mesh,
             in_specs=(P(),) * 12,
             out_specs=P(ax),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
-        seed_sm = _shard_map(
+        seed_sm = jax.shard_map(
             per_chip_seed,
             mesh=mesh,
             in_specs=(P(),) * 9,
             out_specs=P(ax),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
         # NOTE: NOT donated by default — the host keeps the pre-chunk carry
         # alive so an overflow reverts to the last sound chunk boundary
         # (checkpoint-then-raise instead of discarding the run).
         # `donate_chunks=True` flips the trade (see __init__).
-        chunk_sm = _shard_map(
+        chunk_sm = jax.shard_map(
             per_chip_chunk,
             mesh=mesh,
             in_specs=(P(ax),) + (P(),) * 7,
             out_specs=(P(ax), P(ax)),
-            **_SHARD_MAP_KW,
+            check_vma=False,
         )
         chunk_jit = (
             jax.jit(chunk_sm, donate_argnums=(0,))
@@ -1516,8 +1505,7 @@ class ShardedSearch:
           per-bucket counts + evictable-bucket gathers (the device-side
           pre-filter), not whole tables.
 
-        ROUND8_NOTES.md records the measured delta. Single-process meshes
-        only (enforced in __init__)."""
+        Single-process meshes only (enforced in __init__)."""
         c = self._carry
         N = self.n_chips
         S = 1 << self.table_log2

@@ -540,7 +540,7 @@ class ServiceFleet:
                     self.lease_store.grant(lease_member(i))
                     proc, url = spawn_replica_proc(
                         i, store_root, kw, timeout_s=spawn_timeout_s,
-                        scratch=self.scratch_dir,
+                        scratch=self.scratch_dir, live_procs=self._procs,
                     )
                     self._procs.append(proc)
                     self.replicas.append(
@@ -701,6 +701,7 @@ class ServiceFleet:
                 timeout_s=self._spawn_timeout_s,
                 scratch=self.scratch_dir,
                 incarnation=lease.epoch,
+                live_procs=self._procs,
             )
             new = RemoteReplica(
                 idx, url, proc=proc, tracer=self._tracer_raw,
@@ -761,6 +762,7 @@ class ServiceFleet:
                     idx, self.store_root, self._service_kw,
                     timeout_s=self._spawn_timeout_s,
                     scratch=self.scratch_dir,
+                    live_procs=self._procs,
                 )
                 new = RemoteReplica(
                     idx, url, proc=proc, tracer=self._tracer_raw,

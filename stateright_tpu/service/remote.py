@@ -49,7 +49,7 @@ import threading
 import time
 import urllib.error
 import urllib.request
-from typing import Optional
+from typing import Optional, Sequence
 
 from ..core.discovery import HasDiscoveries
 from ..faults.blobstore import is_blob_uri
@@ -599,6 +599,43 @@ def serve_replica(
 # -- process spawning ----------------------------------------------------------
 
 
+def _parent_holds_chip() -> bool:
+    """True when this process has started a JAX TPU backend (and so owns
+    the chip until it exits). Never starts a backend itself."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized() and "tpu" in xla_bridge.backends()
+
+
+def check_chip_free(env: dict, live_procs: Sequence = ()) -> None:
+    """Refuse a replica subprocess that would need a chip it cannot get.
+
+    A replica whose environment leaves ``JAX_PLATFORMS`` unset, or names
+    the TPU, uses the chip. A chip belongs to one process at a time, and a
+    JAX process claims every chip it sees, so such a replica cannot start
+    while this process holds the chip, nor next to another live
+    chip-using replica: RuntimeError, never a silent CPU run."""
+    platforms = [p.strip() for p in env.get("JAX_PLATFORMS", "").split(",")]
+    if any(platforms) and "tpu" not in platforms:
+        return
+    if _parent_holds_chip():
+        # srlint: fault-ok spawn-time placement check, before any replica exists
+        raise RuntimeError(
+            "this process holds the TPU, so a replica subprocess cannot "
+            "reach it; run the fleet in-process or give replicas "
+            "JAX_PLATFORMS=cpu"
+        )
+    if any(p.poll() is None for p in live_procs):
+        # srlint: fault-ok spawn-time placement check, before the replica exists
+        raise RuntimeError(
+            "another replica process already holds this host's chips (a "
+            "JAX process claims every chip it sees); one chip-using "
+            "replica process per host"
+        )
+
+
 def spawn_replica_proc(
     idx: int,
     root: str,
@@ -607,6 +644,7 @@ def spawn_replica_proc(
     env_extra: Optional[dict] = None,
     scratch: Optional[str] = None,
     incarnation: Optional[int] = None,
+    live_procs: Sequence = (),
 ) -> tuple:
     """Launch one `replica_main` subprocess over the shared store root and
     wait for it to DISCOVER itself: the child publishes a
@@ -622,7 +660,12 @@ def spawn_replica_proc(
     journals (required when `root` is a blob URI; defaults to `root`).
     `incarnation` marks a REJOIN respawn: the child journals under the
     ``replica<idx>@e<epoch>`` writer so the restarted stream merges
-    cleanly next to the fenced old incarnation's."""
+    cleanly next to the fenced old incarnation's.
+
+    The child runs on the platform the caller's environment names
+    (``JAX_PLATFORMS``); `live_procs` are the caller's other replica
+    processes, checked by `check_chip_free` when that platform is the
+    chip."""
     from .discovery import MemberDirectory
 
     member = lease_member(idx)
@@ -636,8 +679,8 @@ def spawn_replica_proc(
     suffix = f".e{incarnation}" if incarnation else ""
     log_path = os.path.join(scratch, "logs", f"{member}{suffix}.log")
     env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
     env.update(env_extra or {})
+    check_chip_free(env, live_procs)
     cmd = [
         sys.executable, "-m", "stateright_tpu.service.replica_main",
         "--idx", str(idx),

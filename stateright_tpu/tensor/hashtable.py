@@ -300,8 +300,7 @@ def _insert_impl_kv(t_kv, p_lo, p_hi, lo, hi, parent_lo, parent_hi, active):
     Claim logic is byte-for-byte the split fast path with bucket=64; see
     `_insert_impl` for the algorithm and safety argument. Flag-gated via
     the engines' `table_layout="kv"` until the silicon race decides a
-    default (VERDICT r4 next #1: the bucket-row gathers were the
-    second-largest slice of the insert after the sort).
+    default (the bucket-row gathers were the second-largest slice of the insert after the sort).
     """
     size = p_lo.shape[0]  # S slots; t_kv has 2S lanes
     bucket = min(KV_BUCKET, size)
@@ -453,7 +452,7 @@ def _insert_impl_phased(
     t_lo, t_hi, p_lo, p_hi, lo, hi, parent_lo, parent_hi, active
 ):
     """The round-1..3 PHASED scatter-max insert, revived as a raceable
-    variant (VERDICT r4 next #7): at paxos-2 scale the sort-claim insert's
+    variant: at paxos-2 scale the sort-claim insert's
     fixed sort cost dominated tiny frontiers (162k -> 94k states/s at
     b=2048 on v5e) while the phased design's ~few serialized probe rounds
     are cheap when batches are small and collisions rare. The engines race
@@ -547,7 +546,7 @@ def _insert_impl_phased(
 # is batch × max_actions, many of those lanes are padding (sub-full
 # frontiers pop fixed-size batches) or duplicates of already-visited
 # states, and the sort volume is why measured states/s FALLS with batch
-# size (b=32768 was 1.6x slower than b=4096 on paxos-3 — ROUND4_NOTES;
+# size (b=32768 was 1.6x slower than b=4096 on paxos-3 in round 4;
 # same super-linear term on the CPU backend, so it is algorithmic). The
 # capped path makes per-call probe AND sort cost scale with the POPULATED
 # lanes instead:
@@ -568,7 +567,7 @@ def _insert_impl_phased(
 # home-bucket rows, then tile only the missing lanes — was measured and
 # cost-modeled: the extra full-width gather re-reads rows the claim tiles
 # gather again, and loses to this fused form at every candidate fraction;
-# see tensor/costmodel.py and ROUND6_NOTES.md.)
+# see tensor/costmodel.py.)
 #
 # Correctness rides entirely on the underlying insert: the wrapper only
 # compacts and re-batches the active lanes, each original lane lands in

@@ -19,7 +19,7 @@ Two designs beyond the original lockstep batch:
   ~1 instead of collapsing to the tail walk (the r8 service's
   continuous-batching insight applied inside the walk kernel), so walks/s
   scales with the trace count. `continuous=False` reproduces the original
-  one-walk-per-lane dispatch (the lane_util A/B in ROUND14_NOTES.md).
+  one-walk-per-lane dispatch (the baseline of the lane_util A/B).
 - **Shared visited table** (`dedup="shared"`; knobs.SIM_DEDUP_KINDS): the
   per-trace [T, 2^C] cycle tables are replaced by a small per-walk depth RING
   (cycles with period <= `ring` are detected; longer walks fall to the depth

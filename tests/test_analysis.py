@@ -588,7 +588,7 @@ def test_f64_promotion_is_flagged(jnp):
     from stateright_tpu.analysis.auditor import audit_fn
 
     import jax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     def promote(x):
         return x.astype("float64") * 2.0

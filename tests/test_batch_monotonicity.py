@@ -1,9 +1,9 @@
 """Batch-scaling shape tests for the capped insert (tier-1, CPU backend).
 
-The tentpole claim (ISSUE r6 / VERDICT r5 #3): with the capped insert, the
+The tentpole claim: with the capped insert, the
 engine's per-step cost grows AT MOST LINEARLY with batch size — the
 full-batch B·log(B) sort term that made b=32768 1.6x slower than b=4096 on
-paxos-3 (ROUND4_NOTES, reproduced on CPU) is gone.
+paxos-3 (round 4, reproduced on CPU) is gone.
 
 What is pinned, and why in these units: raw states/s on paxos-2 CANNOT be
 monotone in batch size for ANY insert design — the workload's frontier is
@@ -14,8 +14,7 @@ observable as LANE THROUGHPUT: popped lanes per second, i.e.
 batch x max_actions x steps / time. A super-linear insert term makes lane
 throughput FALL as batch grows (measured: the sort path degrades ~18%
 from b=4096 to b=32768 on this box); the capped path must hold it
-non-decreasing (within noise). The raw A/B states/s table lives in
-ROUND6_NOTES.md.
+non-decreasing (within noise).
 
 Golden parity for every capped variant rides along (the satellite's
 correctness oracle: 2pc-4 = 8,258 generated / 1,568 unique).

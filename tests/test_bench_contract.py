@@ -1,8 +1,7 @@
 """bench.py JSON-contract tests.
 
 The driver records bench.py's one-line JSON as BENCH_r{N}.json; the judge and
-dashboards read `value`/`vs_baseline` from it.  The contract (VERDICT r3 weak
-#1): those fields describe the DEVICE engine only — when no device result
+dashboards read `value`/`vs_baseline` from it.  The contract: those fields describe the DEVICE engine only — when no device result
 exists they must be null, never the C++ baseline number, so an empty-device
 run can't masquerade as a healthy 1.0x.
 """
@@ -232,7 +231,7 @@ def test_device_detail_pins_pallas_row_keys():
     # the capped-insert wall time and the pallas-vs-capped speed ratio must
     # survive into detail.device so the ROADMAP-item-2 "biggest raw-speed
     # lever" claim is auditable in every BENCH_r*.json next to the
-    # costmodel's committed ranking (ROUND12_NOTES.md).
+    # costmodel's committed ranking (costmodel.predict_ranking).
     for key in ("sec_capped", "pallas_vs_capped"):
         assert key in bench.DEVICE_DETAIL_FIELDS
     row = bench.device_detail(

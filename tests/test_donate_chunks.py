@@ -1,7 +1,6 @@
 """donate_chunks: the chunked-dispatch donation mode (round 4) — XLA updates
 the carry in place instead of copying the multi-GB table/queue state per
-dispatch (~300s/dispatch at table 2^27 on CPU; BENCH_CPU_2PC10_r04.json is
-the at-scale result). Contract under test: identical results to the
+dispatch (~300s/dispatch at table 2^27 on CPU in round 4). Contract under test: identical results to the
 non-donated engine, resumability across run() calls, and the documented
 overflow trade (no recovery carry)."""
 
@@ -48,7 +47,7 @@ def test_sharded_donated_chunked_run_matches_goldens():
 
 
 def test_append_variants_agree():
-    """`append_new_dus` (kept for a TPU re-race; ROUND4_NOTES.md decided
+    """`append_new_dus` (kept for a TPU re-race; round 4 found
     scatter wins on CPU) must stay semantically identical to `append_new`
     on the rows that matter: [0, tail) after any append sequence."""
     import jax.numpy as jnp

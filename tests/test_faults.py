@@ -381,7 +381,7 @@ def test_degrade_ladder_escalates_and_is_recorded(tmp_path):
     )
     f = assert_golden(r, faults_expected=5)
     assert f["degrade_steps"] >= 1
-    assert 1 <= f["degrade_rung"] <= 3
+    assert 1 <= f["degrade_rung"] <= 2
 
 
 def test_supervisor_gives_up_past_fault_budget(tmp_path):

@@ -1,4 +1,4 @@
-"""Two-process `jax.distributed` validation (VERDICT r3 #7): ShardedSearch
+"""Two-process `jax.distributed` validation: ShardedSearch
 over a mesh spanning two OS processes (4 virtual CPU devices each, gloo
 collectives) must complete with the single-process goldens, identically on
 every rank. Proves the `make_mesh` multi-host claim (parallel/sharded.py)

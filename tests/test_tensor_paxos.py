@@ -98,8 +98,8 @@ def test_paxos3_golden_counts():
     Golden counts were established by the compiled C++ baseline checker
     (stateright_tpu/_native/baseline_bfs.cpp), whose semantics are anchored to
     the reference's 16,668-state paxos-2 golden (examples/paxos.rs:327), and
-    independently reproduced by the device engine on real TPU hardware
-    (BASELINE_MEASURED.md): 1,194,428 unique / 2,420,477 generated."""
+    independently reproduced by the device engine on a TPU in round 4:
+    1,194,428 unique / 2,420,477 generated."""
     from stateright_tpu.tensor.resident import ResidentSearch
 
     r = ResidentSearch(

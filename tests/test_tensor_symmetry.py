@@ -127,7 +127,7 @@ def _full_key_rep(state):
 
 @pytest.mark.slow
 def test_2pc5_verdict_parity_reduced_vs_unreduced(tpc5_runs):
-    """VERDICT r3 #4a: on a space where reduced/unreduced counts diverge
+    """On a space where reduced/unreduced counts diverge
     (2PC-5: 314 vs 8,832), property VERDICTS must be identical — reduction
     only changes which orbit member is stored, never what is proven.
     Discovery semantics: a `sometimes` name present = witnessed (pass); an
@@ -191,7 +191,7 @@ def test_value_sort_reduction_is_traversal_order_dependent():
 
 @pytest.mark.slow
 def test_2pc7_symmetry_at_scale():
-    """VERDICT r3 #4b: device symmetry beyond toys. 2PC-7: 296,448 unique
+    """Device symmetry beyond toys. 2PC-7: 296,448 unique
     full states (cross-validated against the C++ baseline checker:
     generated 2,744,706 / unique 296,448) reduce to 920 full-key orbits,
     cross-validated against an independent host-DFS implementation of the
@@ -283,7 +283,7 @@ def test_increment_lock_6_sym_golden():
 
 
 def test_value_sort_device_dfs_reproduces_reference_665():
-    """Opt-in reference-parity symmetry ON DEVICE (VERDICT r4 next #8): the
+    """Opt-in reference-parity symmetry ON DEVICE: the
     device value-sort canonicalization kernel, driven in reference DFS
     order, reproduces the published 2PC-5 golden of 665
     (ref: examples/2pc.rs:163-168) — alongside the engines' default
