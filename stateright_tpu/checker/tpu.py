@@ -36,9 +36,11 @@ class TpuChecker(Checker):
         # races. With resident=False the host-orchestrated engine accepts
         # insert_variant, the tiered-store knobs, and telemetry (it races
         # the same visited-set designs). `trace_out=<path>` records host
-        # phases as Chrome trace-event JSON, saved when the search thread
-        # finishes (load it in Perfetto; see obs/trace.py).
-        from ..obs import Tracer
+        # phases as Chrome trace-event JSON, each event's args carrying one
+        # check id; saved when the search thread finishes and again when
+        # discoveries() has built the paths (load it in Perfetto; see
+        # obs/trace.py).
+        from ..obs import Tracer, mint_trace_id
         from ..tensor.frontier import FrontierSearch
         from ..tensor.model import TensorModel
         from ..tensor.resident import ResidentSearch
@@ -106,7 +108,9 @@ class TpuChecker(Checker):
                 )
         self._trace_out = trace_out
         if trace_out is not None:
-            engine_kwargs["tracer"] = Tracer(annotate=True)
+            engine_kwargs["tracer"] = Tracer(
+                annotate=True, args={"check": mint_trace_id()}
+            )
         self._search = (
             ResidentSearch(model, batch_size, table_log2, **engine_kwargs)
             if resident
@@ -179,11 +183,14 @@ class TpuChecker(Checker):
         except BaseException as e:  # noqa: BLE001 — surfaced by join()
             self._panic = e
         finally:
-            if self._trace_out is not None:
-                try:
-                    self._search._tracer.save(self._trace_out)
-                except OSError:
-                    pass  # tracing must never fail a finished search
+            self._save_trace()
+
+    def _save_trace(self) -> None:
+        if self._trace_out is not None:
+            try:
+                self._search._tracer.save(self._trace_out)
+            except OSError:
+                pass  # tracing must never fail a finished search
 
     def _visit_paths(self) -> None:
         """Call the visitor with a full Path for every evaluated state.
@@ -328,10 +335,13 @@ class TpuChecker(Checker):
         if self._discovery_paths is None:
             # Reconstruction dumps the device table; results are immutable
             # once the search thread finishes, so build the paths once.
-            self._discovery_paths = {
-                name: self._search.reconstruct_path(fp)
-                for name, fp in self._result.discoveries.items()
-            }
+            with self._search._tracer.span("checker.discoveries",
+                                           cat="checker"):
+                self._discovery_paths = {
+                    name: self._search.reconstruct_path(fp)
+                    for name, fp in self._result.discoveries.items()
+                }
+            self._save_trace()  # the path spans come after the search's save
         return dict(self._discovery_paths)
 
     def join(self) -> "TpuChecker":

@@ -43,6 +43,7 @@ from .schema import (
     DETAIL_KEYS,
     EVENT_TYPES,
     FAULTS_DETAIL_KEYS,
+    PHASES_DETAIL_KEYS,
     SERVICE_DETAIL_KEYS,
     TELEMETRY_KEYS,
     TERMINAL_EVENT_BY_STATUS,
@@ -64,6 +65,7 @@ __all__ = [
     "DETAIL_KEYS",
     "EVENT_TYPES",
     "FAULTS_DETAIL_KEYS",
+    "PHASES_DETAIL_KEYS",
     "SERVICE_DETAIL_KEYS",
     "TELEMETRY_KEYS",
     "TERMINAL_EVENT_BY_STATUS",

@@ -34,6 +34,9 @@ DETAIL_KEYS = {
     "timed_out": "True when the job hit its service deadline",
     # telemetry spine (obs/ring.py `StepRing.summary`)
     "telemetry": "step-telemetry digest sub-dict (TELEMETRY_KEYS)",
+    # resident engine's whole-search call, staged (tensor/resident.py)
+    "phases": "per-stage wall seconds of the search call sub-dict "
+              "(PHASES_DETAIL_KEYS)",
     # chaos plane + supervisor (stateright_tpu/faults/)
     "faults": "fault-injection/recovery counters sub-dict "
               "(FAULTS_DETAIL_KEYS)",
@@ -205,6 +208,20 @@ TELEMETRY_KEYS = {
                       "(shared dedup only)",
     "dedup_hit_rate": "fraction of walk states already present in the "
                       "shared visited table (dedup='shared' only)",
+}
+
+
+#: Keys of `detail["phases"]` (tensor/resident.py, the whole-search path):
+#: wall seconds of the stages jit runs for the search program in this run,
+#: each timed always and spanned as `resident.<stage>` inside
+#: `resident.search`. The first three are 0 when the run reused the
+#: executable an earlier run() of the same engine compiled.
+PHASES_DETAIL_KEYS = {
+    "trace_s": "tracing the search closure to a jaxpr",
+    "lower_s": "lowering the jaxpr to an HLO module",
+    "compile_s": "backend compile, or the load of the executable from "
+                 "the persistent compilation cache",
+    "execute_s": "running the executable through the one summary fetch",
 }
 
 
@@ -410,6 +427,7 @@ TERMINAL_EVENT_BY_STATUS = {
 DETAIL_SUBSCHEMAS = (
     ("service", SERVICE_DETAIL_KEYS),
     ("telemetry", TELEMETRY_KEYS),
+    ("phases", PHASES_DETAIL_KEYS),
     ("faults", FAULTS_DETAIL_KEYS),
     ("corpus", CORPUS_DETAIL_KEYS),
     ("tenant", TENANT_DETAIL_KEYS),

@@ -10,9 +10,10 @@ run leaves behind (`trace_out=` on `CheckerBuilder`/`spawn_tpu`/
 `CheckService`) loads directly in Perfetto (https://ui.perfetto.dev) or
 chrome://tracing.
 
-With `annotate=True` each span also enters a `jax.profiler.TraceAnnotation`,
-so when a jax profiler session is active the host phases line up with the
-XLA device trace in the same Perfetto view.
+With `annotate=True` each span also enters a `jax.profiler.TraceAnnotation`
+under the span's plain name (args stay in the JSON), so when a jax profiler
+session is active the host phases line up with the XLA device trace in the
+same Perfetto view.
 
 `NULL_TRACER` is the default collaborator everywhere: `span()` returns a
 shared no-op context manager, so call sites trace unconditionally with ~zero
@@ -36,6 +37,9 @@ class _NullSpan:
 
     def __exit__(self, *exc):
         return False
+
+    def set(self, **args) -> None:
+        pass
 
 
 _NULL_SPAN = _NullSpan()
@@ -74,6 +78,11 @@ class _Span:
         )
         return False
 
+    def set(self, **args) -> None:
+        """Add args known only once the phase has run (counters such as
+        the entries a span built); they land in the recorded event."""
+        self._args.update(args)
+
 
 class Tracer:
     """Collects trace events; thread-safe (the check service spans from its
@@ -94,8 +103,12 @@ class Tracer:
         out: Optional[str] = None,
         flush_every: int = 256,
         flush_interval_s: float = 2.0,
+        args: Optional[dict] = None,
     ):
         self.annotate = annotate
+        # Args every recorded event carries (e.g. the check id that joins
+        # one spawn's spans); an event's own args win on a shared key.
+        self.args = dict(args or {})
         self.max_events = max_events
         self.out = out
         self.flush_every = max(int(flush_every), 1)
@@ -122,6 +135,7 @@ class Tracer:
         return _Span(self, name, cat, args)
 
     def instant(self, name: str, cat: str = "host", **args) -> None:
+        args = {**self.args, **args}
         with self._lock:
             if len(self.events) >= self.max_events:
                 self.dropped += 1
@@ -142,6 +156,7 @@ class Tracer:
         self._write_snapshot(snap)
 
     def _record(self, name, cat, t0, t1, args) -> None:
+        args = {**self.args, **args}
         with self._lock:
             if len(self.events) >= self.max_events:
                 self.dropped += 1

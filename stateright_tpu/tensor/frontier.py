@@ -37,7 +37,7 @@ from ..knobs import APPEND_KINDS, STORE_KINDS, WARM_KINDS
 from ..faults.ckptio import fenced_savez, load_latest
 from ..faults.plan import maybe_fault
 from ..store import warm as warm_seam
-from ..obs import REGISTRY, StepRing, as_tracer, build_detail
+from ..obs import NULL_TRACER, REGISTRY, StepRing, as_tracer, build_detail
 from .costmodel import ENGINE_VARIANTS
 from .fingerprint import device_fingerprint, pack_fp
 from .hashtable import _insert_impl
@@ -123,46 +123,55 @@ def expand_insert(
     """
     K = states.shape[0]
     A = model.max_actions
-    succs, valid = model.expand(states)
-    valid = valid & active[:, None]
-    flat = succs.reshape(K * A, model.lanes)
-    validf = valid.reshape(-1) & model.within_boundary(flat)
-    # Generated-state count is pre-dedup, post-boundary (ref: bfs.rs:288-291).
-    gen_rows = validf.reshape(K, A).sum(axis=1).astype(jnp.uint32)
-    # Terminality counts deduped successors too, but not boundary-excluded
-    # ones (ref: bfs.rs:287-333).
-    has_succ = validf.reshape(K, A).any(axis=1)
+    # Named scopes mark each phase's ops in the HLO metadata (a device
+    # trace's `tf_op` path) for per-kernel attribution; they change neither
+    # the program nor its fusion. XLA names a fusion by its root op, so a
+    # fusion that spans two scopes counts toward one of them.
+    with jax.named_scope("expand"):
+        succs, valid = model.expand(states)
+        valid = valid & active[:, None]
+        flat = succs.reshape(K * A, model.lanes)
+        validf = valid.reshape(-1) & model.within_boundary(flat)
+        # Generated-state count is pre-dedup, post-boundary (ref:
+        # bfs.rs:288-291).
+        gen_rows = validf.reshape(K, A).sum(axis=1).astype(jnp.uint32)
+        # Terminality counts deduped successors too, but not
+        # boundary-excluded ones (ref: bfs.rs:287-333).
+        has_succ = validf.reshape(K, A).any(axis=1)
 
-    slo, shi = state_fingerprint(model, flat)
-    par_lo = jnp.repeat(lo, A)
-    par_hi = jnp.repeat(hi, A)
-    if salt_lo is not None:
-        from .fingerprint import salt_fp
+    with jax.named_scope("fingerprint"):
+        slo, shi = state_fingerprint(model, flat)
+        par_lo = jnp.repeat(lo, A)
+        par_hi = jnp.repeat(hi, A)
+        if salt_lo is not None:
+            from .fingerprint import salt_fp
 
-        sl_rep = jnp.repeat(salt_lo, A)
-        sh_rep = jnp.repeat(salt_hi, A)
-        key_lo, key_hi = salt_fp(slo, shi, sl_rep, sh_rep)
-        par_lo, par_hi = salt_fp(par_lo, par_hi, sl_rep, sh_rep)
-    else:
-        key_lo, key_hi = slo, shi
-    if summary is not None and getattr(insert, "fused_summary", False):
-        t_lo, t_hi, p_lo, p_hi, is_new, suspect, ovf = insert(
-            t_lo, t_hi, p_lo, p_hi, key_lo, key_hi, par_lo, par_hi, validf,
-            summary,
-        )
-    else:
-        t_lo, t_hi, p_lo, p_hi, is_new, ovf = insert(
-            t_lo, t_hi, p_lo, p_hi, key_lo, key_hi, par_lo, par_hi, validf
-        )
-        if summary is not None:
-            from ..store.summary import maybe_contains
-
-            slog2, khash = summary_cfg
-            suspect = is_new & maybe_contains(
-                summary, key_lo, key_hi, slog2, khash
+            sl_rep = jnp.repeat(salt_lo, A)
+            sh_rep = jnp.repeat(salt_hi, A)
+            key_lo, key_hi = salt_fp(slo, shi, sl_rep, sh_rep)
+            par_lo, par_hi = salt_fp(par_lo, par_hi, sl_rep, sh_rep)
+        else:
+            key_lo, key_hi = slo, shi
+    with jax.named_scope("insert"):
+        if summary is not None and getattr(insert, "fused_summary", False):
+            t_lo, t_hi, p_lo, p_hi, is_new, suspect, ovf = insert(
+                t_lo, t_hi, p_lo, p_hi, key_lo, key_hi, par_lo, par_hi,
+                validf, summary,
             )
         else:
-            suspect = jnp.zeros_like(is_new)
+            t_lo, t_hi, p_lo, p_hi, is_new, ovf = insert(
+                t_lo, t_hi, p_lo, p_hi, key_lo, key_hi, par_lo, par_hi,
+                validf,
+            )
+            if summary is not None:
+                from ..store.summary import maybe_contains
+
+                slog2, khash = summary_cfg
+                suspect = is_new & maybe_contains(
+                    summary, key_lo, key_hi, slog2, khash
+                )
+            else:
+                suspect = jnp.zeros_like(is_new)
     return (
         t_lo, t_hi, p_lo, p_hi,
         flat, slo, shi, is_new, suspect,
@@ -331,17 +340,22 @@ def replay_fp_chain(model: TensorModel, chain: list) -> Path:
     return Path(pairs)
 
 
-def reconstruct_path(model: TensorModel, parent_map: dict, fp: int) -> Path:
+def reconstruct_path(
+    model: TensorModel, parent_map: dict, fp: int, tracer=NULL_TRACER
+) -> Path:
     """Walk device parent pointers, then re-execute (the TLC
     fingerprint-stack technique, ref: src/checker/bfs.rs:380-409).
-    Fingerprints are packed host ints (see tensor/fingerprint.py pack_fp)."""
+    Fingerprints are packed host ints (see tensor/fingerprint.py pack_fp).
+    The replay is the span `path.replay`, its `steps` the transitions
+    replayed (one expand of one state each)."""
     chain: list[int] = []
     cur = fp
     while cur:
         chain.append(cur)
         cur = parent_map.get(cur, 0)
     chain.reverse()
-    return replay_fp_chain(model, chain)
+    with tracer.span("path.replay", cat="path", steps=len(chain) - 1):
+        return replay_fp_chain(model, chain)
 
 
 @dataclass
@@ -1231,4 +1245,4 @@ class FrontierSearch:
             # chain acyclic; a post-spill re-claim's parent can sit deeper
             # than the state itself.
             parent_map.update(self._store.parent_map())
-        return reconstruct_path(self.model, parent_map, fp)
+        return reconstruct_path(self.model, parent_map, fp, self._tracer)

@@ -24,6 +24,7 @@ property has a discovery (src/checker/bfs.rs:278-280), when the configured
 
 from __future__ import annotations
 
+import contextlib
 import time
 from functools import partial
 from typing import NamedTuple, Optional
@@ -45,7 +46,14 @@ from ..knobs import (
 from ..faults.ckptio import fenced_savez, load_latest, normalize_ckpt_path
 from ..faults.plan import maybe_fault
 from ..store import warm as warm_seam
-from ..obs import N_COLS, REGISTRY, StepRing, as_tracer, build_detail
+from ..obs import (
+    N_COLS,
+    PHASES_DETAIL_KEYS,
+    REGISTRY,
+    StepRing,
+    as_tracer,
+    build_detail,
+)
 from .costmodel import ENGINE_VARIANTS
 from .fingerprint import pack_fp
 from .frontier import (
@@ -64,6 +72,11 @@ from .frontier import (
 from .hashtable import KV_BUCKET, _insert_impl
 from .inserts import check_table_log2, resolve_insert
 from .model import TensorModel
+
+
+#: Static arguments of the whole-search program: required mask, any mask
+#: and the step cap (one executable per value, as jit keys them).
+_SEARCH_STATIC = (3, 4, 7)
 
 
 def _finish_masks(finish_when: HasDiscoveries, props) -> tuple[int, int]:
@@ -467,6 +480,19 @@ class ResidentSearch:
             REGISTRY.register("calib", self._calib.metrics)
         self.props = model.properties()
         self._kernel, self._seed_k, self._chunk_k = self._build()
+        # Whole-search executables, compiled in stages (_search_executable),
+        # and the wall seconds of each stage: this run's (detail["phases"])
+        # and the engine's totals with the path counters (metrics()).
+        self._executables: dict = {}
+        self._phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
+        self._counters = {
+            "trace_seconds_total": 0.0,
+            "lower_seconds_total": 0.0,
+            "compile_seconds_total": 0.0,
+            "execute_seconds_total": 0.0,
+            "parent_map_entries": 0,
+            "replay_steps_total": 0,
+        }
         self._last_tables = None
         self._parent_map = None
         self._seed = None
@@ -588,22 +614,32 @@ class ResidentSearch:
             active = active & ((tmd == 0) | (depth < tmd))
 
             # -- property evaluation (ref: bfs.rs:230-280) ---------------------
+            # Named scopes here and in expand_insert attribute the step's
+            # device ops to its phases in a profiler trace (HLO metadata
+            # only: the program is unchanged).
             discovered = c.discovered
             disc_lo, disc_hi = c.disc_lo, c.disc_hi
             if P:
-                masks = jnp.stack([p.condition(model, states) for p in props])
-                for i in always_i:
-                    discovered, disc_lo, disc_hi = _record(
-                        discovered, disc_lo, disc_hi, i, active & ~masks[i], lo, hi
+                with jax.named_scope("properties"):
+                    masks = jnp.stack(
+                        [p.condition(model, states) for p in props]
                     )
-                for i in sometimes_i:
-                    discovered, disc_lo, disc_hi = _record(
-                        discovered, disc_lo, disc_hi, i, active & masks[i], lo, hi
-                    )
-                for i in eventually_i:
-                    ebits = jnp.where(
-                        masks[i], ebits & jnp.uint32(~(1 << i) & 0xFFFFFFFF), ebits
-                    )
+                    for i in always_i:
+                        discovered, disc_lo, disc_hi = _record(
+                            discovered, disc_lo, disc_hi, i,
+                            active & ~masks[i], lo, hi,
+                        )
+                    for i in sometimes_i:
+                        discovered, disc_lo, disc_hi = _record(
+                            discovered, disc_lo, disc_hi, i,
+                            active & masks[i], lo, hi,
+                        )
+                    for i in eventually_i:
+                        ebits = jnp.where(
+                            masks[i],
+                            ebits & jnp.uint32(~(1 << i) & 0xFFFFFFFF),
+                            ebits,
+                        )
 
             # -- expand + fingerprint + dedup + insert (shared core) -----------
             (
@@ -620,12 +656,15 @@ class ResidentSearch:
 
             # -- eventually counterexamples at terminal states -----------------
             if eventually_i:
-                term = active & ~has_succ
-                for i in eventually_i:
-                    bad = term & ((ebits >> jnp.uint32(i)) & 1).astype(bool)
-                    discovered, disc_lo, disc_hi = _record(
-                        discovered, disc_lo, disc_hi, i, bad, lo, hi
-                    )
+                with jax.named_scope("properties"):
+                    term = active & ~has_succ
+                    for i in eventually_i:
+                        bad = term & (
+                            (ebits >> jnp.uint32(i)) & 1
+                        ).astype(bool)
+                        discovered, disc_lo, disc_hi = _record(
+                            discovered, disc_lo, disc_hi, i, bad, lo, hi
+                        )
 
             # -- tiered store: split claims into enqueue vs suspect ------------
             # A fresh claim whose fingerprint hits the Bloom summary of the
@@ -640,21 +679,23 @@ class ResidentSearch:
 
             # -- append new states to the queue tail (cumsum compaction) -------
             src_row = jnp.arange(K * A, dtype=jnp.int32) // A
-            q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
-                c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth, c.tail,
-                flat, slo, shi, ebits[src_row], depth[src_row] + 1, enq,
-            )
+            with jax.named_scope("append"):
+                q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
+                    c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth, c.tail,
+                    flat, slo, shi, ebits[src_row], depth[src_row] + 1, enq,
+                )
             new_count = tail - c.tail
             hot_claims = c.hot_claims + is_new.sum().astype(jnp.int32)
             if tiered:
-                (
-                    s_states, s_lo, s_hi, s_ebits, s_depth, s_tail,
-                ) = _append(
-                    c.s_states, c.s_lo, c.s_hi, c.s_ebits, c.s_depth,
-                    c.s_tail,
-                    flat, slo, shi, ebits[src_row], depth[src_row] + 1,
-                    suspect,
-                )
+                with jax.named_scope("append"):
+                    (
+                        s_states, s_lo, s_hi, s_ebits, s_depth, s_tail,
+                    ) = _append(
+                        c.s_states, c.s_lo, c.s_hi, c.s_ebits, c.s_depth,
+                        c.s_tail,
+                        flat, slo, shi, ebits[src_row], depth[src_row] + 1,
+                        suspect,
+                    )
                 # Host-service exits (non-fatal): spill trigger crossed,
                 # suspect buffer near capacity, or queue tail past the
                 # compaction threshold.
@@ -844,7 +885,7 @@ class ResidentSearch:
                 ]
             )
 
-        @partial(jax.jit, static_argnums=(3, 4, 7))
+        @partial(jax.jit, static_argnums=_SEARCH_STATIC)
         def search(
             init_states,  # uint32[K, L] padded
             init_lo,  # uint32[K]
@@ -1100,6 +1141,7 @@ class ResidentSearch:
         K = self.batch_size
         start = time.monotonic()
         self._parent_map = None  # invalidate any prior reconstruction cache
+        self._phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
         if self._ring is not None and self._carry is None and self._ring.steps:
             # Fresh search (no suspended carry): telemetry starts over too.
             self._ring = self._ring.fresh()
@@ -1155,28 +1197,34 @@ class ResidentSearch:
             # Chaos-plane boundary: a simulated OOM/XLA fault lands before
             # the whole-search dispatch (faults/plan.py).
             maybe_fault("engine.step", engine="resident")
+            args = (
+                *dev,
+                required_mask,
+                any_mask,
+                t_lo32,
+                t_hi32,
+                max_steps,
+                jnp.int32(n0),
+                jnp.uint32(n_raw & 0xFFFFFFFF),
+                jnp.uint32(n_raw >> 32),
+                tmd,
+                self._dyn_dev,
+            )
             with self._tracer.span("resident.search", cat="engine"):
-                t_lo, t_hi, p_lo, p_hi, summary, tm_rows = self._kernel(
-                    *dev,
-                    required_mask,
-                    any_mask,
-                    t_lo32,
-                    t_hi32,
-                    max_steps,
-                    jnp.int32(n0),
-                    jnp.uint32(n_raw & 0xFFFFFFFF),
-                    jnp.uint32(n_raw >> 32),
-                    tmd,
-                    self._dyn_dev,
-                )
-                # ONE device->host transfer for the entire result.
-                summary = np.asarray(summary)
+                exe = self._search_executable(args)
+                with self._stage("execute"):
+                    t_lo, t_hi, p_lo, p_hi, summary, tm_rows = exe(*(
+                        a for i, a in enumerate(args)
+                        if i not in _SEARCH_STATIC
+                    ))
+                    # ONE device->host transfer for the entire result.
+                    summary = np.asarray(summary)
             if self._ring is not None:
                 # Whole-search dispatch: one bulk drain at the end (the ring
                 # holds the LAST 2^telemetry_log2 steps; earlier rows count
-                # as dropped). The window average includes compile time on a
-                # cold first run.
-                w_us = (time.monotonic() - start) * 1e6
+                # as dropped). The window is the execute stage alone: no
+                # tracing, lowering or executable load.
+                w_us = self._phases["execute_s"] * 1e6
                 self._ring.drain(np.asarray(tm_rows), int(summary[8]),
                                  window_us=w_us)
                 if self._calib is not None:
@@ -1358,6 +1406,8 @@ class ResidentSearch:
             max_depth = w["max_depth"]
             discoveries = dict(w["discoveries"])
         detail = self._detail()
+        if not chunked:
+            detail = {**(detail or {}), "phases": dict(self._phases)}
         if self._warm_kind is not None:
             detail = dict(detail or {})
             detail["corpus"] = {
@@ -1376,6 +1426,43 @@ class ResidentSearch:
             detail=detail,
         )
 
+    @contextlib.contextmanager
+    def _stage(self, stage: str):
+        """Span `resident.<stage>` and add its wall seconds to this run's
+        phases and the engine's totals (always timed: two clock reads)."""
+        t0 = time.monotonic()
+        try:
+            with self._tracer.span("resident." + stage, cat="engine"):
+                yield
+        finally:
+            dt = time.monotonic() - t0
+            self._phases[stage + "_s"] += dt
+            self._counters[stage + "_seconds_total"] += dt
+
+    def _search_executable(self, args: tuple):
+        """The compiled whole-search program for `args`, built in the
+        stages jit runs internally, each its own span: trace, lower, and
+        compile (a backend compile or a load from the persistent cache).
+        The same module (`jit_search`) and executable as a plain call of
+        `self._kernel`; kept per static arguments and operand-table
+        shapes, which is what jit's own cache would key on here."""
+        dyn = args[-1]
+        key = (
+            tuple(args[i] for i in _SEARCH_STATIC),
+            jax.tree.structure(dyn),
+            tuple((x.shape, x.dtype) for x in jax.tree.leaves(dyn)),
+        )
+        exe = self._executables.get(key)
+        if exe is None:
+            with self._stage("trace"):
+                traced = self._kernel.trace(*args)
+            with self._stage("lower"):
+                lowered = traced.lower()
+            with self._stage("compile"):
+                exe = lowered.compile()
+            self._executables[key] = exe
+        return exe
+
     def telemetry_summary(self) -> Optional[dict]:
         """Step-telemetry digest (obs/ring.py; None with telemetry off) —
         surfaced in SearchResult.detail["telemetry"] and `/metrics`."""
@@ -1387,7 +1474,7 @@ class ResidentSearch:
         """Flat counter snapshot for the obs registry / Prometheus export.
         Host-side values only (drained telemetry + store counters) — a
         scrape never syncs the device mid-search."""
-        out: dict = {}
+        out: dict = dict(self._counters)
         if self._ring is not None:
             out.update(
                 steps=self._ring.steps,
@@ -1842,27 +1929,32 @@ class ResidentSearch:
                     "no table snapshot to reconstruct from: run() has not "
                     "completed since the last reset/donated resume"
                 )
-            t_lo, t_hi, p_lo, p_hi = (
-                np.asarray(x) for x in self._last_tables
-            )
-            if self.table_layout == "kv":
-                b = min(KV_BUCKET, 1 << self.table_log2)
-                kv = t_lo.reshape(-1, 2 * b)
-                t_lo = kv[:, :b].reshape(-1)
-                t_hi = kv[:, b:].reshape(-1)
-            nz = t_lo != 0
-            keys = pack_fp(t_lo[nz], t_hi[nz])
-            parents = pack_fp(p_lo[nz], p_hi[nz])
-            self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
-            if self._store is not None:
-                # Spill entries win on keys present in both tiers: they
-                # carry the ORIGINAL (BFS-discovery) parent, which keeps
-                # reconstructed chains acyclic.
-                self._parent_map.update(self._store.parent_map())
+            with self._tracer.span("path.parent_map", cat="path") as span:
+                t_lo, t_hi, p_lo, p_hi = (
+                    np.asarray(x) for x in self._last_tables
+                )
+                if self.table_layout == "kv":
+                    b = min(KV_BUCKET, 1 << self.table_log2)
+                    kv = t_lo.reshape(-1, 2 * b)
+                    t_lo = kv[:, :b].reshape(-1)
+                    t_hi = kv[:, b:].reshape(-1)
+                nz = t_lo != 0
+                keys = pack_fp(t_lo[nz], t_hi[nz])
+                parents = pack_fp(p_lo[nz], p_hi[nz])
+                self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
+                if self._store is not None:
+                    # Spill entries win on keys present in both tiers: they
+                    # carry the ORIGINAL (BFS-discovery) parent, which keeps
+                    # reconstructed chains acyclic.
+                    self._parent_map.update(self._store.parent_map())
+                span.set(entries=len(self._parent_map))
+            self._counters["parent_map_entries"] = len(self._parent_map)
         return self._parent_map
 
     def reconstruct_path(self, fp: int):
         """TLC-style reconstruction from the final table contents (the logic
         is shared with the host-orchestrated engine)."""
         self.build_parent_map()
-        return reconstruct_path(self.model, self._parent_map, fp)
+        path = reconstruct_path(self.model, self._parent_map, fp, self._tracer)
+        self._counters["replay_steps_total"] += len(path) - 1
+        return path

@@ -26,6 +26,7 @@ from stateright_tpu.obs import (
 )
 from stateright_tpu.obs.schema import (
     DETAIL_KEYS,
+    PHASES_DETAIL_KEYS,
     SERVICE_DETAIL_KEYS,
     TELEMETRY_KEYS,
 )
@@ -178,7 +179,37 @@ def test_resident_telemetry_off_restores_plain_detail(tpc3):
     )
     r = rs.run()
     assert (r.state_count, r.unique_state_count) == GOLD_2PC3
-    assert r.detail is None  # device store + telemetry off = no detail
+    # device store + telemetry off = only the staged call's phases
+    assert set(r.detail) == {"phases"}
+
+
+def test_resident_whole_search_phases_and_step_window(tpc3):
+    from stateright_tpu.tensor.resident import ResidentSearch
+
+    rs = ResidentSearch(tpc3, batch_size=256, table_log2=12)
+    r = rs.run()
+    assert (r.state_count, r.unique_state_count) == GOLD_2PC3
+    assert validate_detail(r.detail) == []
+    phases = r.detail["phases"]
+    assert set(phases) == set(PHASES_DETAIL_KEYS)
+    assert all(v > 0 for v in phases.values()), phases
+    # The ring's window is the execute stage alone: no trace, lowering or
+    # compile in the per-step wall time.
+    t = r.detail["telemetry"]
+    assert t["step_us"]["mean"] == pytest.approx(
+        phases["execute_s"] * 1e6 / t["steps"]
+    )
+    m = rs.metrics()
+    for k, v in phases.items():
+        assert m[k[:-2] + "_seconds_total"] == v
+    # A second run reuses the executable: no stage but execute runs.
+    r2 = rs.run()
+    assert (r2.state_count, r2.unique_state_count) == GOLD_2PC3
+    assert r2.discoveries == r.discoveries
+    p2 = r2.detail["phases"]
+    assert p2["trace_s"] == p2["lower_s"] == p2["compile_s"] == 0.0
+    assert p2["execute_s"] > 0
+    assert rs.metrics()["compile_seconds_total"] == phases["compile_s"]
 
 
 # -- tracing -------------------------------------------------------------------
@@ -228,6 +259,101 @@ def test_spawn_tpu_trace_out_writes_perfetto_file(tpc3, tmp_path):
     # The checker also surfaces the telemetry digest + table fill live.
     assert checker.telemetry_summary()["steps"] > 0
     assert 0 < checker.table_fill() <= 1
+
+
+STAGES = ("resident.trace", "resident.lower", "resident.compile",
+          "resident.execute")
+
+
+@pytest.fixture(scope="module")
+def traced_check(tpc3, tmp_path_factory):
+    """One traced spawn_tpu + discoveries() under a CPU profiler session:
+    (checker, paths, the saved Chrome trace's events, host-plane event
+    names of the profiler's trace)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    d = tmp_path_factory.mktemp("traced")
+    out = str(d / "check.trace.json")
+    jax.profiler.start_trace(str(d / "prof"))
+    try:
+        checker = (
+            tpc3.checker()
+            .trace_out(out)
+            .spawn_tpu(batch_size=256, table_log2=12)
+            .join()
+        )
+        paths = checker.discoveries()
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = glob.glob(str(d / "prof" / "**" / "*.xplane.pb"),
+                          recursive=True)
+    host = [
+        e.name
+        for plane in ProfileData.from_file(xplane).planes
+        if plane.name.startswith("/host:")
+        for line in plane.lines
+        for e in line.events
+    ]
+    events = _validate_chrome_trace(json.load(open(out)))
+    return checker, paths, events, host
+
+
+def _inside(inner, outer) -> bool:
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_traced_check_spans_nest_and_carry_counters(traced_check):
+    checker, paths, events, _ = traced_check
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    (search,) = by["resident.search"]
+    stages = [by[name][0] for name in STAGES]
+    assert all(len(by[name]) == 1 and _inside(by[name][0], search)
+               for name in STAGES)
+    # The four stages are the search call: they cover its span.
+    assert sum(e["dur"] for e in stages) >= 0.98 * search["dur"]
+    (disc,) = by["checker.discoveries"]
+    (pmap,) = by["path.parent_map"]
+    assert _inside(pmap, disc)
+    assert pmap["args"]["entries"] == checker.unique_state_count()
+    replays = by["path.replay"]
+    assert sorted(e["args"]["steps"] for e in replays) == sorted(
+        len(p) - 1 for p in paths.values())
+    assert all(_inside(e, disc) for e in replays)
+    # One check id joins every span of the spawn.
+    ids = {e["args"]["check"] for e in events}
+    assert len(ids) == 1 and ids.pop()
+
+
+def test_traced_check_answers_as_untraced(traced_check, tpc3):
+    checker, paths, _, _ = traced_check
+    plain = tpc3.checker().spawn_tpu(batch_size=256, table_log2=12).join()
+    assert (plain.state_count(), plain.unique_state_count(),
+            plain.max_depth()) == (checker.state_count(),
+                                   checker.unique_state_count(),
+                                   checker.max_depth())
+    plain_paths = plain.discoveries()
+    assert set(plain_paths) == set(paths)
+    for name, p in paths.items():
+        assert plain_paths[name].into_pairs() == p.into_pairs()
+
+
+def test_profiler_host_plane_has_the_span_names(traced_check):
+    # Annotations carry the plain span names (args stay in the JSON), so a
+    # device trace's reduction can match host spans by exact name.
+    _, _, events, host = traced_check
+    counts = {}
+    for e in events:
+        counts[e["name"]] = counts.get(e["name"], 0) + 1
+    assert {*STAGES, "resident.search", "search.run", "checker.discoveries",
+            "path.parent_map", "path.replay"} <= set(counts)
+    for name, n in counts.items():
+        assert host.count(name) == n, name
 
 
 # -- Prometheus export ---------------------------------------------------------
