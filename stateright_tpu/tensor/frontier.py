@@ -20,6 +20,7 @@ negatives at revisits, early exit once every property has a discovery.
 from __future__ import annotations
 
 import time
+import weakref
 from collections import deque
 from dataclasses import dataclass
 from functools import partial
@@ -304,13 +305,79 @@ def record_discovery(discovered, disc_lo, disc_hi, i, hit, lo, hi):
     return discovered, disc_lo, disc_hi
 
 
+class _Replay:
+    """The compiled programs of a path replay for one model instance: the
+    fingerprints of the init rows, and one transition (expand a row
+    uint32[1, L], fingerprint all its successors). Shape-stable, so one
+    compiled transition serves every path of any length.
+
+    Both take the model's operand tables (lowered models, `dyn_tables`) as
+    an argument and install them as `model._dyn` while tracing, as the
+    resident engine's kernels do, so tables that `extend()` refilled are
+    read fresh and new table shapes retrace. `max_actions` is a static
+    argument of the transition: with the row and table shapes it is what
+    `extend()` can change. `traces` counts the transition's traces."""
+
+    def __init__(self, model: TensorModel):
+        ref = weakref.ref(model)  # the programs must not keep the model alive
+        self.traces = 0
+
+        def replay_fingerprint(rows, dyn):
+            return _with_tables(ref(), dyn, state_fingerprint, rows)
+
+        def replay_step(row, dyn, max_actions):
+            self.traces += 1
+
+            def expand_fp(m, row):
+                succs, valid = m.expand(row)
+                return (succs[0], valid[0], *state_fingerprint(m, succs[0]))
+
+            return _with_tables(ref(), dyn, expand_fp, row)
+
+        self.fingerprint = jax.jit(replay_fingerprint)
+        self.step = jax.jit(replay_step, static_argnums=2)
+
+
+def _with_tables(model, dyn, fn, rows):
+    if not hasattr(model, "dyn_tables"):
+        return fn(model, rows)
+    model._dyn = dyn
+    try:
+        return fn(model, rows)
+    finally:
+        model._dyn = None
+
+
+#: Replay programs by id(model) (the tensor models are dataclasses, so
+#: unhashable), each dropped when its model is collected.
+_REPLAYS: dict = {}
+
+
+def _replay_programs(model: TensorModel) -> _Replay:
+    key = id(model)
+    rp = _REPLAYS.get(key)
+    if rp is None:
+        rp = _REPLAYS[key] = _Replay(model)
+        weakref.finalize(model, _REPLAYS.pop, key, None)
+    return rp
+
+
+def replay_traces(model: TensorModel) -> int:
+    """How often the replay's transition program has been traced for this
+    model instance."""
+    return _replay_programs(model).traces
+
+
 def replay_fp_chain(model: TensorModel, chain: list) -> Path:
     """Re-execute the tensor model along a chain of packed fingerprints,
     recovering decoded states and action labels (the host checkers'
-    Path.from_fingerprints technique, ref: src/checker/path.rs:20-97)."""
+    Path.from_fingerprints technique, ref: src/checker/path.rs:20-97).
+    Each transition is one call of a compiled program and one fetch."""
+    rp = _replay_programs(model)
+    dyn = model.dyn_tables() if hasattr(model, "dyn_tables") else {}
     init = np.asarray(model.init_states(), dtype=np.uint32)
-    ilo, ihi = state_fingerprint(model, jnp.asarray(init))
-    init_fps = pack_fp(np.asarray(ilo), np.asarray(ihi))
+    ilo, ihi = jax.device_get(rp.fingerprint(init, dyn))
+    init_fps = pack_fp(ilo, ihi)
     rows = np.nonzero(init_fps == np.uint64(chain[0]))[0]
     if len(rows) == 0:
         # srlint: fault-ok host-side path reconstruction after the search; no recovery path exists
@@ -321,11 +388,10 @@ def replay_fp_chain(model: TensorModel, chain: list) -> Path:
     cur_row = init[rows[0]]
     pairs = []
     for next_fp in chain[1:]:
-        succs, valid = model.expand(jnp.asarray(cur_row[None]))
-        slo, shi = state_fingerprint(model, succs[0])
-        sfps = pack_fp(np.asarray(slo), np.asarray(shi))
-        succs = np.asarray(succs)[0]
-        valid = np.asarray(valid)[0]
+        succs, valid, slo, shi = jax.device_get(
+            rp.step(cur_row[None], dyn, model.max_actions)
+        )
+        sfps = pack_fp(slo, shi)
         hits = np.nonzero(valid & (sfps == np.uint64(next_fp)))[0]
         if len(hits) == 0:
             # srlint: fault-ok host-side path reconstruction after the search; no recovery path exists
@@ -341,21 +407,31 @@ def replay_fp_chain(model: TensorModel, chain: list) -> Path:
 
 
 def reconstruct_path(
-    model: TensorModel, parent_map: dict, fp: int, tracer=NULL_TRACER
+    model: TensorModel, parent_map: dict, fp: int, tracer=NULL_TRACER,
+    counters: Optional[dict] = None,
 ) -> Path:
     """Walk device parent pointers, then re-execute (the TLC
     fingerprint-stack technique, ref: src/checker/bfs.rs:380-409).
     Fingerprints are packed host ints (see tensor/fingerprint.py pack_fp).
     The replay is the span `path.replay`, its `steps` the transitions
-    replayed (one expand of one state each)."""
+    replayed (one expand of one state each) and `traced` 1 if it traced
+    the transition program. `counters` (an engine's), when given, add
+    both up in `replay_steps_total` and `replay_traces_total`."""
     chain: list[int] = []
     cur = fp
     while cur:
         chain.append(cur)
         cur = parent_map.get(cur, 0)
     chain.reverse()
-    with tracer.span("path.replay", cat="path", steps=len(chain) - 1):
-        return replay_fp_chain(model, chain)
+    with tracer.span("path.replay", cat="path", steps=len(chain) - 1) as span:
+        traces = replay_traces(model)
+        path = replay_fp_chain(model, chain)
+        traced = int(replay_traces(model) != traces)
+        span.set(traced=traced)
+    if counters is not None:
+        counters["replay_steps_total"] += len(chain) - 1
+        counters["replay_traces_total"] += traced
+    return path
 
 
 @dataclass

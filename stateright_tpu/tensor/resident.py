@@ -492,6 +492,7 @@ class ResidentSearch:
             "execute_seconds_total": 0.0,
             "parent_map_entries": 0,
             "replay_steps_total": 0,
+            "replay_traces_total": 0,
         }
         self._last_tables = None
         self._parent_map = None
@@ -1955,6 +1956,6 @@ class ResidentSearch:
         """TLC-style reconstruction from the final table contents (the logic
         is shared with the host-orchestrated engine)."""
         self.build_parent_map()
-        path = reconstruct_path(self.model, self._parent_map, fp, self._tracer)
-        self._counters["replay_steps_total"] += len(path) - 1
-        return path
+        return reconstruct_path(
+            self.model, self._parent_map, fp, self._tracer, self._counters
+        )

@@ -266,21 +266,24 @@ STAGES = ("resident.trace", "resident.lower", "resident.compile",
 
 
 @pytest.fixture(scope="module")
-def traced_check(tpc3, tmp_path_factory):
+def traced_check(tmp_path_factory):
     """One traced spawn_tpu + discoveries() under a CPU profiler session:
     (checker, paths, the saved Chrome trace's events, host-plane event
-    names of the profiler's trace)."""
+    names of the profiler's trace). Its own model instance, whose path
+    replay no other test has compiled."""
     import glob
 
     import jax
     from jax.profiler import ProfileData
+
+    from stateright_tpu.tensor.models import TensorTwoPhaseSys
 
     d = tmp_path_factory.mktemp("traced")
     out = str(d / "check.trace.json")
     jax.profiler.start_trace(str(d / "prof"))
     try:
         checker = (
-            tpc3.checker()
+            TensorTwoPhaseSys(3).checker()
             .trace_out(out)
             .spawn_tpu(batch_size=256, table_log2=12)
             .join()
@@ -325,6 +328,9 @@ def test_traced_check_spans_nest_and_carry_counters(traced_check):
     assert sorted(e["args"]["steps"] for e in replays) == sorted(
         len(p) - 1 for p in paths.values())
     assert all(_inside(e, disc) for e in replays)
+    # The replay's transition program is traced once a check, then reused.
+    assert all(e["args"]["traced"] in (0, 1) for e in replays)
+    assert sum(e["args"]["traced"] for e in replays) == 1
     # One check id joins every span of the spawn.
     ids = {e["args"]["check"] for e in events}
     assert len(ids) == 1 and ids.pop()
