@@ -147,7 +147,10 @@ class CheckerBuilder:
         `tensor_model()`. `mode` picks the engine (knobs.CHECKER_MODES):
         "search" (default) is the exhaustive frontier checker;
         "simulation" is the device random-walk engine — equivalent to
-        `spawn_simulation(device=True, **kwargs)`."""
+        `spawn_simulation(device=True, **kwargs)`. `chips=N` runs the
+        search on N devices (the fingerprint-sharded engine,
+        parallel/sharded.py), with `batch_size` and `table_log2` per chip:
+        `spawn_tpu(batch_size=8192, table_log2=23, chips=4)`."""
         from ..knobs import CHECKER_MODES
 
         if mode not in CHECKER_MODES:  # knob universe: knobs.py

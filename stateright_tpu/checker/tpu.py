@@ -21,6 +21,7 @@ class TpuChecker(Checker):
         table_log2: int = 20,
         resident: bool = None,
         trace_out: Optional[str] = None,
+        chips: int = 1,
         **engine_kwargs,
     ):
         # engine_kwargs pass through to the underlying engine —
@@ -40,6 +41,16 @@ class TpuChecker(Checker):
         # check id; saved when the search thread finishes and again when
         # discoveries() has built the paths (load it in Perfetto; see
         # obs/trace.py).
+        #
+        # `chips=N` (N > 1) runs the check on the first N devices through
+        # the multi-chip engine (parallel/sharded.py ShardedSearch over
+        # make_mesh(N)): the visited set and the queue split by state
+        # fingerprint, each chip owning a share, the successors routed to
+        # their owners by one all_to_all a step. There `batch_size` and
+        # `table_log2` are PER CHIP (N x batch_size states expanded a step,
+        # N x 2^table_log2 table slots in all); the handle is the same.
+        # Its engine options: dest_capacity, donate_chunks, append,
+        # insert_variant, the tiered-store and the telemetry knobs.
         from ..obs import Tracer, mint_trace_id
         from ..tensor.frontier import FrontierSearch
         from ..tensor.model import TensorModel
@@ -81,6 +92,11 @@ class TpuChecker(Checker):
                     "visitors on spawn_tpu require the resident engine "
                     "(the default); drop resident=False"
                 )
+            if chips > 1:
+                raise NotImplementedError(
+                    "visitors on spawn_tpu require the one-chip resident "
+                    "engine (the default); drop chips"
+                )
             if engine_kwargs.get("store") == "tiered":
                 raise NotImplementedError(
                     "visitors on spawn_tpu require the device store (the "
@@ -96,26 +112,55 @@ class TpuChecker(Checker):
         # finer-grained (per-device-step) progress instead.
         if resident is None:
             resident = True
-        if not resident:
-            unsupported = set(engine_kwargs) - {
-                "insert_variant", "store", "high_water", "low_water",
-                "summary_log2", "telemetry", "telemetry_log2",
-            }
-            if unsupported:
+        common = {"insert_variant", "store", "high_water", "low_water",
+                  "summary_log2", "telemetry", "telemetry_log2"}
+        if chips < 1:
+            raise ValueError(f"chips must be at least 1, got {chips}")
+        if chips > 1:
+            import jax
+
+            if not resident:
                 raise ValueError(
-                    f"engine options {sorted(unsupported)} require the "
-                    "resident engine (drop resident=False)"
+                    "chips > 1 runs the multi-chip engine; it has no "
+                    "host-orchestrated form (drop resident=False)"
                 )
+            if chips > len(jax.devices()):
+                raise ValueError(
+                    f"chips={chips} but only {len(jax.devices())} devices "
+                    "are visible"
+                )
+            allowed, drop = common | {
+                "dest_capacity", "donate_chunks", "append", "tracer",
+            }, "chips"
+        elif not resident:
+            allowed, drop = common, "resident=False"
+        else:
+            allowed = None
+        if allowed is not None and set(engine_kwargs) - allowed:
+            raise ValueError(
+                f"engine options {sorted(set(engine_kwargs) - allowed)} "
+                f"require the resident engine (drop {drop})"
+            )
         self._trace_out = trace_out
         if trace_out is not None:
             engine_kwargs["tracer"] = Tracer(
                 annotate=True, args={"check": mint_trace_id()}
             )
-        self._search = (
-            ResidentSearch(model, batch_size, table_log2, **engine_kwargs)
-            if resident
-            else FrontierSearch(model, batch_size, table_log2, **engine_kwargs)
-        )
+        if chips > 1:
+            from ..parallel.sharded import ShardedSearch, make_mesh
+
+            self._search = ShardedSearch(
+                model, make_mesh(chips), batch_size, table_log2,
+                **engine_kwargs,
+            )
+        elif resident:
+            self._search = ResidentSearch(
+                model, batch_size, table_log2, **engine_kwargs
+            )
+        else:
+            self._search = FrontierSearch(
+                model, batch_size, table_log2, **engine_kwargs
+            )
         self._options = options
         self._result = None
         self._discovery_paths = None
@@ -130,6 +175,7 @@ class TpuChecker(Checker):
             self._live["unique"] = unique
             self._live["depth"] = depth
 
+        from ..tensor.frontier import FrontierSearch
         from ..tensor.resident import ResidentSearch
 
         kwargs = dict(
@@ -138,13 +184,13 @@ class TpuChecker(Checker):
             target_max_depth=self._options.target_max_depth_,
             timeout=self._options.timeout_,
         )
-        if (
-            self._options.timeout_ is not None
-            or not isinstance(self._search, ResidentSearch)
+        if self._options.timeout_ is not None or isinstance(
+            self._search, FrontierSearch
         ):
             # Chunked/host-orchestrated runs surface live counters; a
-            # single-dispatch resident run has no host involvement to report
-            # from (forcing it chunked just for counters would cost perf).
+            # single-dispatch resident or sharded run has no host
+            # involvement to report from (forcing it chunked just for
+            # counters would cost perf).
             kwargs["progress"] = progress
         if (
             self._recorder is not None
@@ -313,14 +359,16 @@ class TpuChecker(Checker):
     def table_fill(self) -> Optional[float]:
         """Visited-table fill for the WriteReporter `fill=` field: the
         tiered store's exact hot_fill when present, else live uniques over
-        table slots (exact for the device store — claims == uniques)."""
+        table slots over every chip (exact for the device store — claims
+        == uniques)."""
         stats = self.store_stats()
         if stats and "hot_fill" in stats:
             return stats["hot_fill"]
         log2 = getattr(self._search, "table_log2", None)
         if log2 is None:
             log2 = self._search.table.log2_size
-        return min(self.unique_state_count() / (1 << log2), 1.0)
+        slots = (1 << log2) * getattr(self._search, "n_chips", 1)
+        return min(self.unique_state_count() / slots, 1.0)
 
     def drift_ratio(self) -> Optional[float]:
         """Measured/predicted ratio of the engine's live calibration

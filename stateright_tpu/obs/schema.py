@@ -34,7 +34,8 @@ DETAIL_KEYS = {
     "timed_out": "True when the job hit its service deadline",
     # telemetry spine (obs/ring.py `StepRing.summary`)
     "telemetry": "step-telemetry digest sub-dict (TELEMETRY_KEYS)",
-    # resident engine's whole-search call, staged (tensor/resident.py)
+    # the resident and sharded engines' whole-search call, staged
+    # (tensor/resident.py Stages)
     "phases": "per-stage wall seconds of the search call sub-dict "
               "(PHASES_DETAIL_KEYS)",
     # chaos plane + supervisor (stateright_tpu/faults/)
@@ -211,11 +212,12 @@ TELEMETRY_KEYS = {
 }
 
 
-#: Keys of `detail["phases"]` (tensor/resident.py, the whole-search path):
-#: wall seconds of the stages jit runs for the search program in this run,
-#: each timed always and spanned as `resident.<stage>` inside
-#: `resident.search`. The first three are 0 when the run reused the
-#: executable an earlier run() of the same engine compiled.
+#: Keys of `detail["phases"]` (tensor/resident.py `Stages`, the whole-search
+#: path of the resident and the sharded engines): wall seconds of the stages
+#: jit runs for the search program in this run, each timed always and
+#: spanned as `<engine>.<stage>` inside `<engine>.search` (`resident` or
+#: `sharded`). The first three are 0 when the run reused the executable an
+#: earlier run() of the same engine compiled.
 PHASES_DETAIL_KEYS = {
     "trace_s": "tracing the search closure to a jaxpr",
     "lower_s": "lowering the jaxpr to an HLO module",

@@ -81,6 +81,7 @@ from ..tensor.resident import (
     ABORT_QUEUE,
     ABORT_TABLE,
     EXIT_SERVICE,
+    Stages,
     _compact_queue,
     _finish_masks,
     _inject_rows,
@@ -303,6 +304,19 @@ class ShardedSearch:
         self._TMR = (1 << telemetry_log2) if telemetry else 0
         self._ring = StepRing(self._TMR) if telemetry else None
         self._tracer = as_tracer(tracer)
+        # The whole-search call in jit's stages, each timed and spanned
+        # (`sharded.trace/lower/compile/execute`), and the engine's path and
+        # exchange counters (metrics()). `_exchange_mark` is the (steps,
+        # generated) point up to which the exchange is counted.
+        self._stages = Stages("sharded", self._tracer)
+        self._counters = {
+            "parent_map_entries": 0,
+            "replay_steps_total": 0,
+            "replay_traces_total": 0,
+            "exchange_rows_total": 0,
+            "exchange_slots_total": 0,
+        }
+        self._exchange_mark = None
         self._metrics_name = REGISTRY.register("sharded", self.metrics)
         # Calibration comparator (obs/calib.py): prices ONE shard's
         # lockstep step (per-shard batch/table — every shard dispatches the
@@ -567,99 +581,114 @@ class ShardedSearch:
                 )
 
                 # -- property masks on popped states (bfs.rs:230-280) ----------
+                # Named scopes attribute the step's device ops to its phases
+                # in a profiler trace, with the resident body's names plus
+                # `exchange` and `sync` (HLO metadata only: the program is
+                # unchanged).
                 discovered = c.discovered
                 disc_lo, disc_hi = c.disc_lo, c.disc_hi
                 if P_:
-                    masks = jnp.stack([p.condition(model, states) for p in props])
-                    for i in always_i:
-                        discovered, disc_lo, disc_hi = _record(
-                            discovered, disc_lo, disc_hi, i,
-                            active & ~masks[i], lo, hi,
+                    with jax.named_scope("properties"):
+                        masks = jnp.stack(
+                            [p.condition(model, states) for p in props]
                         )
-                    for i in sometimes_i:
-                        discovered, disc_lo, disc_hi = _record(
-                            discovered, disc_lo, disc_hi, i,
-                            active & masks[i], lo, hi,
-                        )
-                    for i in eventually_i:
-                        ebits = jnp.where(
-                            masks[i],
-                            ebits & jnp.uint32(~(1 << i) & 0xFFFFFFFF),
-                            ebits,
-                        )
+                        for i in always_i:
+                            discovered, disc_lo, disc_hi = _record(
+                                discovered, disc_lo, disc_hi, i,
+                                active & ~masks[i], lo, hi,
+                            )
+                        for i in sometimes_i:
+                            discovered, disc_lo, disc_hi = _record(
+                                discovered, disc_lo, disc_hi, i,
+                                active & masks[i], lo, hi,
+                            )
+                        for i in eventually_i:
+                            ebits = jnp.where(
+                                masks[i],
+                                ebits & jnp.uint32(~(1 << i) & 0xFFFFFFFF),
+                                ebits,
+                            )
 
                 # -- expand locally --------------------------------------------
-                succs, valid = model.expand(states)
-                valid = valid & active[:, None]
-                flat = succs.reshape(K * A, L)
-                validf = valid.reshape(-1) & model.within_boundary(flat)
-                gen = validf.sum().astype(jnp.int32)
-                has_succ = validf.reshape(K, A).any(axis=1)
+                with jax.named_scope("expand"):
+                    succs, valid = model.expand(states)
+                    valid = valid & active[:, None]
+                    flat = succs.reshape(K * A, L)
+                    validf = valid.reshape(-1) & model.within_boundary(flat)
+                    gen = validf.sum().astype(jnp.int32)
+                    has_succ = validf.reshape(K, A).any(axis=1)
 
                 # -- eventually counterexamples at terminal states --------------
                 if eventually_i:
-                    term = active & ~has_succ
-                    for i in eventually_i:
-                        bad = term & ((ebits >> jnp.uint32(i)) & 1).astype(bool)
-                        discovered, disc_lo, disc_hi = _record(
-                            discovered, disc_lo, disc_hi, i, bad, lo, hi
-                        )
+                    with jax.named_scope("properties"):
+                        term = active & ~has_succ
+                        for i in eventually_i:
+                            bad = term & (
+                                (ebits >> jnp.uint32(i)) & 1
+                            ).astype(bool)
+                            discovered, disc_lo, disc_hi = _record(
+                                discovered, disc_lo, disc_hi, i, bad, lo, hi
+                            )
+
+                with jax.named_scope("fingerprint"):
+                    slo, shi = state_fingerprint(model, flat)
 
                 # -- route successors to owner chips (cumsum per destination) --
-                slo, shi = state_fingerprint(model, flat)
-                owner = jnp.where(validf, owner_of(slo, shi), N)
-                idx_in_seg = jnp.zeros(K * A, dtype=jnp.int32)
-                for d in range(N):  # static unroll
-                    sel = owner == d
-                    idx_in_seg = jnp.where(
-                        sel, jnp.cumsum(sel.astype(jnp.int32)) - 1, idx_in_seg
+                with jax.named_scope("exchange"):
+                    owner = jnp.where(validf, owner_of(slo, shi), N)
+                    idx_in_seg = jnp.zeros(K * A, dtype=jnp.int32)
+                    for d in range(N):  # static unroll
+                        sel = owner == d
+                        idx_in_seg = jnp.where(
+                            sel, jnp.cumsum(sel.astype(jnp.int32)) - 1,
+                            idx_in_seg,
+                        )
+                    live = owner < N
+                    route_ovf = jnp.any(live & (idx_in_seg >= C))
+                    dest = jnp.where(
+                        live & (idx_in_seg < C), owner * C + idx_in_seg, N * C
                     )
-                live = owner < N
-                route_ovf = jnp.any(live & (idx_in_seg >= C))
-                dest = jnp.where(
-                    live & (idx_in_seg < C), owner * C + idx_in_seg, N * C
-                )
-                parent_lo = jnp.repeat(lo, A)
-                parent_hi = jnp.repeat(hi, A)
-                ebits_rep = jnp.repeat(ebits, A)
-                depth_rep = jnp.repeat(depth + 1, A)
+                    parent_lo = jnp.repeat(lo, A)
+                    parent_hi = jnp.repeat(hi, A)
+                    ebits_rep = jnp.repeat(ebits, A)
+                    depth_rep = jnp.repeat(depth + 1, A)
 
-                # ONE packed send buffer [N*C, L+7]: state lanes then
-                # (lo, hi, parent_lo, parent_hi, ebits, depth, valid-as-u32)
-                # — one zero-fill, one row scatter, ONE all_to_all instead
-                # of eight of each (per-collective launch overhead was a
-                # visible slice of the virtual-mesh step after the
-                # dest_capacity cut; on ICI, one large message also beats
-                # eight small ones).
-                packed = jnp.concatenate(
-                    [
-                        flat,
-                        jnp.stack(
-                            [
-                                slo, shi, parent_lo, parent_hi,
-                                ebits_rep, depth_rep,
-                                live.astype(jnp.uint32),
-                            ],
-                            axis=1,
-                        ),
-                    ],
-                    axis=1,
-                )
-                s_packed = (
-                    jnp.zeros((N * C, L + 7), dtype=jnp.uint32)
-                    .at[dest].set(packed, mode="drop")
-                )
-                r_packed = jax.lax.all_to_all(
-                    s_packed.reshape(N, C, L + 7), ax, 0, 0
-                ).reshape(N * C, L + 7)
-                r_states = r_packed[:, :L]
-                r_lo = r_packed[:, L]
-                r_hi = r_packed[:, L + 1]
-                r_plo = r_packed[:, L + 2]
-                r_phi = r_packed[:, L + 3]
-                r_ebits = r_packed[:, L + 4]
-                r_depth = r_packed[:, L + 5]
-                r_valid = r_packed[:, L + 6].astype(bool)
+                    # ONE packed send buffer [N*C, L+7]: state lanes then
+                    # (lo, hi, parent_lo, parent_hi, ebits, depth,
+                    # valid-as-u32) — one zero-fill, one row scatter, ONE
+                    # all_to_all instead of eight of each (per-collective
+                    # launch overhead was a visible slice of the
+                    # virtual-mesh step after the dest_capacity cut; on
+                    # ICI, one large message also beats eight small ones).
+                    packed = jnp.concatenate(
+                        [
+                            flat,
+                            jnp.stack(
+                                [
+                                    slo, shi, parent_lo, parent_hi,
+                                    ebits_rep, depth_rep,
+                                    live.astype(jnp.uint32),
+                                ],
+                                axis=1,
+                            ),
+                        ],
+                        axis=1,
+                    )
+                    s_packed = (
+                        jnp.zeros((N * C, L + 7), dtype=jnp.uint32)
+                        .at[dest].set(packed, mode="drop")
+                    )
+                    r_packed = jax.lax.all_to_all(
+                        s_packed.reshape(N, C, L + 7), ax, 0, 0
+                    ).reshape(N * C, L + 7)
+                    r_states = r_packed[:, :L]
+                    r_lo = r_packed[:, L]
+                    r_hi = r_packed[:, L + 1]
+                    r_plo = r_packed[:, L + 2]
+                    r_phi = r_packed[:, L + 3]
+                    r_ebits = r_packed[:, L + 4]
+                    r_depth = r_packed[:, L + 5]
+                    r_valid = r_packed[:, L + 6].astype(bool)
 
                 # -- insert into the local shard (handles duplicates) ----------
                 # Tiered: a Bloom-positive fresh claim is buffered for exact
@@ -667,44 +696,53 @@ class ShardedSearch:
                 # store; a miss proves novelty on-device. The suspect probe
                 # fuses into the Pallas kernel's partition pass when that
                 # variant is selected (same protocol as the other engines).
-                if tiered and _fused:
-                    (
-                        t_lo2, t_hi2, p_lo2, p_hi2, is_new, suspect, ins_ovf,
-                    ) = _insert_step(
-                        c.t_lo, c.t_hi, c.p_lo, c.p_hi,
-                        r_lo, r_hi, r_plo, r_phi, r_valid,
-                        c.summary,
-                    )
-                else:
-                    t_lo2, t_hi2, p_lo2, p_hi2, is_new, ins_ovf = _insert_step(
-                        c.t_lo, c.t_hi, c.p_lo, c.p_hi,
-                        r_lo, r_hi, r_plo, r_phi, r_valid,
-                    )
-                    suspect = (
-                        is_new
-                        & maybe_contains(c.summary, r_lo, r_hi, slog2, khash)
-                        if tiered
-                        else None
-                    )
+                with jax.named_scope("insert"):
+                    if tiered and _fused:
+                        (
+                            t_lo2, t_hi2, p_lo2, p_hi2, is_new, suspect,
+                            ins_ovf,
+                        ) = _insert_step(
+                            c.t_lo, c.t_hi, c.p_lo, c.p_hi,
+                            r_lo, r_hi, r_plo, r_phi, r_valid,
+                            c.summary,
+                        )
+                    else:
+                        (
+                            t_lo2, t_hi2, p_lo2, p_hi2, is_new, ins_ovf,
+                        ) = _insert_step(
+                            c.t_lo, c.t_hi, c.p_lo, c.p_hi,
+                            r_lo, r_hi, r_plo, r_phi, r_valid,
+                        )
+                        suspect = (
+                            is_new
+                            & maybe_contains(
+                                c.summary, r_lo, r_hi, slog2, khash
+                            )
+                            if tiered
+                            else None
+                        )
                 enq = is_new & ~suspect if tiered else is_new
                 # -- append fresh states to the local queue (cumsum) -----------
                 _append = (
                     append_new if self.append == "scatter" else append_new_dus
                 )
-                q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
-                    c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth, c.tail,
-                    r_states, r_lo, r_hi, r_ebits, r_depth, enq,
-                )
+                with jax.named_scope("append"):
+                    q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
+                        c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth,
+                        c.tail,
+                        r_states, r_lo, r_hi, r_ebits, r_depth, enq,
+                    )
                 new_count = tail - c.tail
                 hot_claims = c.hot_claims + is_new.sum().astype(jnp.int32)
                 if tiered:
-                    (
-                        s_states, s_lo, s_hi, s_ebits, s_depth, s_tail,
-                    ) = _append(
-                        c.s_states, c.s_lo, c.s_hi, c.s_ebits, c.s_depth,
-                        c.s_tail,
-                        r_states, r_lo, r_hi, r_ebits, r_depth, suspect,
-                    )
+                    with jax.named_scope("append"):
+                        (
+                            s_states, s_lo, s_hi, s_ebits, s_depth, s_tail,
+                        ) = _append(
+                            c.s_states, c.s_lo, c.s_hi, c.s_ebits, c.s_depth,
+                            c.s_tail,
+                            r_states, r_lo, r_hi, r_ebits, r_depth, suspect,
+                        )
                     service = (
                         (hot_claims >= TRIGGER)
                         | (s_tail > SQ - N * C)
@@ -732,21 +770,25 @@ class ShardedSearch:
                 )
 
                 # -- global sync: discovery OR, counters, termination ----------
-                gathered = jax.lax.all_gather(discovered, ax)
-                discovered = gathered[0]
-                for i in range(1, N):  # static unroll: global OR of bitmasks
-                    discovered = discovered | gathered[i]
-                g_gen_step = jax.lax.psum(gen, ax)  # < 2^31 per step
-                gen_lo, gen_hi = count_add(
-                    c.gen_lo, c.gen_hi, g_gen_step.astype(jnp.uint32)
-                )
-                g_pending = jax.lax.psum(tail - head, ax)
-                g_overflow = jax.lax.psum(overflow.astype(jnp.int32), ax) > 0
-                steps = c.steps + 1
-                cont = continue_expr(
-                    g_pending, g_overflow, discovered, gen_lo, gen_hi, steps,
-                    required_mask, any_mask, target_lo, target_hi, max_steps,
-                )
+                with jax.named_scope("sync"):
+                    gathered = jax.lax.all_gather(discovered, ax)
+                    discovered = gathered[0]
+                    for i in range(1, N):  # static unroll: global OR
+                        discovered = discovered | gathered[i]
+                    g_gen_step = jax.lax.psum(gen, ax)  # < 2^31 per step
+                    gen_lo, gen_hi = count_add(
+                        c.gen_lo, c.gen_hi, g_gen_step.astype(jnp.uint32)
+                    )
+                    g_pending = jax.lax.psum(tail - head, ax)
+                    g_overflow = (
+                        jax.lax.psum(overflow.astype(jnp.int32), ax) > 0
+                    )
+                    steps = c.steps + 1
+                    cont = continue_expr(
+                        g_pending, g_overflow, discovered, gen_lo, gen_hi,
+                        steps, required_mask, any_mask, target_lo, target_hi,
+                        max_steps,
+                    )
 
                 # -- per-shard step telemetry row (obs/ring.py STEP_COLS) ------
                 # Steps are globally synced, so row i holds shard-local
@@ -822,7 +864,7 @@ class ShardedSearch:
         def shard(x):
             return x.reshape(1, *jnp.shape(x))
 
-        def per_chip(
+        def sharded_search(
             init_states, init_lo, init_hi, init_active,
             target_lo, target_hi, seed_lo, seed_hi,
             required_mask, any_mask, max_steps, target_max_depth,
@@ -855,7 +897,7 @@ class ShardedSearch:
                 shard(carry.tm_rows),
             )
 
-        def per_chip_seed(
+        def sharded_seed(
             init_states, init_lo, init_hi, init_active,
             target_lo, target_hi, seed_lo, seed_hi, max_steps,
         ):
@@ -865,7 +907,7 @@ class ShardedSearch:
             )
             return jax.tree.map(lambda x: jnp.asarray(x)[None], carry)
 
-        def per_chip_chunk(
+        def sharded_chunk(
             carry: _Carry,  # per-chip view: leading dim 1 on every leaf
             required_mask, any_mask, target_lo, target_hi,
             target_max_depth, budget, max_steps,
@@ -911,14 +953,14 @@ class ShardedSearch:
             return out, shard(summary)
 
         sharded = jax.shard_map(
-            per_chip,
+            sharded_search,
             mesh=mesh,
             in_specs=(P(),) * 12,
             out_specs=P(ax),
             check_vma=False,
         )
         seed_sm = jax.shard_map(
-            per_chip_seed,
+            sharded_seed,
             mesh=mesh,
             in_specs=(P(),) * 9,
             out_specs=P(ax),
@@ -929,7 +971,7 @@ class ShardedSearch:
         # (checkpoint-then-raise instead of discarding the run).
         # `donate_chunks=True` flips the trade (see __init__).
         chunk_sm = jax.shard_map(
-            per_chip_chunk,
+            sharded_chunk,
             mesh=mesh,
             in_specs=(P(ax),) + (P(),) * 7,
             out_specs=(P(ax), P(ax)),
@@ -1112,6 +1154,7 @@ class ShardedSearch:
                 for f_, v in fields.items()
             }
         )
+        self._exchange_mark = (0, sc)
         # Queue rows no longer cover every unique state (the prefix lives
         # in the spill tiers) — dump_states must decline.
         self._q_compacted = True
@@ -1188,47 +1231,42 @@ class ShardedSearch:
             jnp.uint32(n_raw >> 32),
         )
 
+        self._stages.new_run()
         if not chunked:
             # Chaos-plane boundary (faults/plan.py): faults land before the
             # dispatch, never mid-update.
             maybe_fault("engine.step", engine="sharded")
+            # Every operand is traced (the depth bound and the finish masks
+            # too), so one executable serves every run of this engine.
+            args = (
+                jnp.asarray(st),
+                jnp.asarray(lo),
+                jnp.asarray(hi),
+                jnp.asarray(active),
+                *t32,
+                *seed32,
+                jnp.uint32(required_mask),
+                jnp.uint32(any_mask),
+                jnp.int32(max_steps),
+                jnp.uint32(target_max_depth or 0),
+            )
             with self._tracer.span("sharded.search", cat="engine"):
-                (
-                    t_lo, t_hi, p_lo, p_hi,
-                    gen_lo, gen_hi, unique_counts, max_depths,
-                    discovered, disc_lo, disc_hi, drained, overflow, steps,
-                    tm_rows,
-                ) = jax.block_until_ready(
-                    self._kernel(
-                        jnp.asarray(st),
-                        jnp.asarray(lo),
-                        jnp.asarray(hi),
-                        jnp.asarray(active),
-                        *t32,
-                        *seed32,
-                        jnp.uint32(required_mask),
-                        jnp.uint32(any_mask),
-                        jnp.int32(max_steps),
-                        jnp.uint32(target_max_depth or 0),
-                    )
-                )
-            # ONE gather for the whole output tuple (one DCN round-trip on
-            # multi-host meshes instead of one per array).
-            (
-                t_lo, t_hi, p_lo, p_hi,
-                gen_lo, gen_hi, unique_counts, max_depths,
-                discovered, disc_lo, disc_hi, drained, overflow, steps,
-                tm_rows,
-            ) = _host((
-                t_lo, t_hi, p_lo, p_hi,
-                gen_lo, gen_hi, unique_counts, max_depths,
-                discovered, disc_lo, disc_hi, drained, overflow, steps,
-                tm_rows,
-            ))
+                exe = self._stages.executable(self._kernel, args, key=None)
+                with self._stages.stage("execute"):
+                    t_lo, t_hi, p_lo, p_hi, *outs = exe(*args)
+                    # ONE gather for the small outputs (one DCN round-trip
+                    # on multi-host meshes instead of one per array); the
+                    # tables stay on the chips until a path needs them.
+                    (
+                        gen_lo, gen_hi, unique_counts, max_depths,
+                        discovered, disc_lo, disc_hi, drained, overflow,
+                        steps, tm_rows,
+                    ) = _host(tuple(outs))
             if self._ring is not None:
                 # Whole-search dispatch: one bulk drain of every shard's
-                # ring (includes compile time in the window average).
-                w_us = (time.monotonic() - start) * 1e6
+                # ring. The window is the execute stage alone: no tracing,
+                # lowering or executable load.
+                w_us = self._stages.phases["execute_s"] * 1e6
                 self._ring.drain_sharded(tm_rows, int(steps.max()),
                                          window_us=w_us)
                 if self._calib is not None:
@@ -1251,6 +1289,8 @@ class ShardedSearch:
             result_max_depth = int(max_depths.max())
             result_steps = int(steps.max())
             complete = bool(drained.all())
+            self._exchange_mark = (0, n_raw)
+            self._count_exchange(result_steps, state_count)
         else:
             if self._carry is None:
                 self._carry = self._seed_k(
@@ -1262,6 +1302,7 @@ class ShardedSearch:
                     *seed32,
                     jnp.int32(max_steps),
                 )
+                self._exchange_mark = (0, n_raw)
                 if self._warm_summary_pending:
                     # Complete-entry replay: seed_carry built empty Bloom
                     # words; swap in each shard's preloaded summary so the
@@ -1307,9 +1348,12 @@ class ShardedSearch:
                             self._ring.generated_total,
                         )
                 codes = s[:, 7].astype(np.uint32)
-                if (codes & EXIT_SERVICE).any() and not (
-                    codes & (ABORT_TABLE | ABORT_QUEUE | ABORT_ROUTE)
-                ).any():
+                fatal = (codes & (ABORT_TABLE | ABORT_QUEUE | ABORT_ROUTE)).any()
+                if not fatal:  # the chunk's carry is kept: count its steps
+                    self._count_exchange(
+                        int(s[:, 8].max()), int(s[0, 0]) | (int(s[0, 1]) << 32)
+                    )
+                if (codes & EXIT_SERVICE).any() and not fatal:
                     # Non-fatal tiered-store service: every shard drains its
                     # suspect buffer / evicts / compacts, then the loop
                     # resumes the same carry.
@@ -1457,6 +1501,7 @@ class ShardedSearch:
                     if self._calib is not None and self._calib.chunks
                     else {}
                 ),
+                **({} if chunked else {"phases": dict(self._stages.phases)}),
             },
         )
 
@@ -1476,7 +1521,11 @@ class ShardedSearch:
     def metrics(self) -> dict:
         """Flat counter snapshot for the obs registry / Prometheus export
         (host-side values only — a scrape never syncs the mesh)."""
-        out: dict = {"n_chips": self.n_chips}
+        out: dict = {
+            "n_chips": self.n_chips,
+            **self._stages.totals,
+            **self._counters,
+        }
         if self._ring is not None:
             out.update(
                 steps=self._ring.steps,
@@ -1489,6 +1538,19 @@ class ShardedSearch:
             # the Prometheus renderer's flatten step.
             out["store"] = stats
         return out
+
+    def _count_exchange(self, steps: int, generated: int) -> None:
+        """Add the exchange since `_exchange_mark` to the counters: every
+        generated successor is one routed row (`exchange_rows_total`), and
+        every step each of the N chips sends N x dest_capacity slots
+        (`exchange_slots_total`); their ratio is the send buffers' fill."""
+        if self._exchange_mark is not None:
+            s0, g0 = self._exchange_mark
+            self._counters["exchange_rows_total"] += generated - g0
+            self._counters["exchange_slots_total"] += (
+                (steps - s0) * self.n_chips ** 2 * self.dest_capacity
+            )
+        self._exchange_mark = (steps, generated)
 
     def _service(self) -> None:
         """Host half of the tiered store for the sharded engine, with
@@ -1648,6 +1710,7 @@ class ShardedSearch:
         self._parent_map = None
         self._last_tables = None
         self._q_compacted = False
+        self._exchange_mark = None
         self._warm = None
         self._warm_states = 0
         self._warm_kind = None
@@ -1934,10 +1997,15 @@ class ShardedSearch:
                 for f, v in fields.items()
             }
         )
+        ss._exchange_mark = (
+            int(np.asarray(fields["steps"]).max()),
+            int(fields["gen_lo"][0]) | (int(fields["gen_hi"][0]) << 32),
+        )
         return ss
 
     def reconstruct_path(self, fp: int):
-        """Union the per-chip parent maps, then reconstruct as usual."""
+        """Union the per-chip parent maps (the span `path.parent_map`, one
+        transfer of every shard's table), then reconstruct as usual."""
         if self._parent_map is None:
             if self._last_tables is None:
                 # srlint: fault-ok caller-contract guard, not an I/O/device surface
@@ -1945,16 +2013,22 @@ class ShardedSearch:
                     "no table snapshot to reconstruct from: run() has not "
                     "completed since the last reset/donated overflow"
                 )
-            t_lo, t_hi, p_lo, p_hi = (
-                x.reshape(-1) for x in self._last_tables
-            )
-            nz = t_lo != 0
-            keys = pack_fp(t_lo[nz], t_hi[nz])
-            parents = pack_fp(p_lo[nz], p_hi[nz])
-            self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
-            if self._stores is not None:
-                # Rank-local spill entries win on keys in both tiers (the
-                # original BFS-discovery parent keeps chains acyclic).
-                for s in self._stores:
-                    self._parent_map.update(s.parent_map())
-        return reconstruct_path(self.model, self._parent_map, fp)
+            with self._tracer.span("path.parent_map", cat="path") as span:
+                t_lo, t_hi, p_lo, p_hi = (
+                    x.reshape(-1) for x in _host(self._last_tables)
+                )
+                nz = t_lo != 0
+                keys = pack_fp(t_lo[nz], t_hi[nz])
+                parents = pack_fp(p_lo[nz], p_hi[nz])
+                self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
+                if self._stores is not None:
+                    # Rank-local spill entries win on keys in both tiers
+                    # (the original BFS-discovery parent keeps chains
+                    # acyclic).
+                    for s in self._stores:
+                        self._parent_map.update(s.parent_map())
+                span.set(entries=len(self._parent_map))
+            self._counters["parent_map_entries"] = len(self._parent_map)
+        return reconstruct_path(
+            self.model, self._parent_map, fp, self._tracer, self._counters
+        )

@@ -182,6 +182,53 @@ def _resolve_chunking(budget, timeout, progress, carry):
     return chunked, budget
 
 
+class Stages:
+    """A whole-search call in the stages jit runs internally, each its own
+    span `<engine>.<stage>`: trace, lower, compile (a backend compile or a
+    load from the persistent cache), then execute. Always timed (two clock
+    reads a stage): `phases` holds this run's seconds (`detail["phases"]`)
+    and `totals` the engine's `<stage>_seconds_total` counters. Shared by
+    the resident and the sharded engines."""
+
+    def __init__(self, engine: str, tracer):
+        self.engine = engine
+        self.tracer = tracer
+        self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
+        self.totals = {
+            k[:-2] + "_seconds_total": 0.0 for k in PHASES_DETAIL_KEYS
+        }
+        self._executables: dict = {}
+
+    def new_run(self) -> None:
+        self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
+
+    @contextlib.contextmanager
+    def stage(self, stage: str):
+        t0 = time.monotonic()
+        try:
+            with self.tracer.span(f"{self.engine}.{stage}", cat="engine"):
+                yield
+        finally:
+            dt = time.monotonic() - t0
+            self.phases[stage + "_s"] += dt
+            self.totals[stage + "_seconds_total"] += dt
+
+    def executable(self, jitted, args: tuple, key):
+        """The compiled program of `jitted` for `args`, traced, lowered and
+        compiled once per `key` (what jit's own cache would key on): the
+        same module and executable as a plain call of `jitted`."""
+        exe = self._executables.get(key)
+        if exe is None:
+            with self.stage("trace"):
+                traced = jitted.trace(*args)
+            with self.stage("lower"):
+                lowered = traced.lower()
+            with self.stage("compile"):
+                exe = lowered.compile()
+            self._executables[key] = exe
+        return exe
+
+
 _ins_jit = jax.jit(_insert_impl)  # one compile cache shared by every regrow
 # The pallas table's slot layout is partition-relative (partition = hi mod P,
 # row = hi div P — tensor/pallas_hashtable.py), so a pallas run's regrow must
@@ -481,15 +528,10 @@ class ResidentSearch:
         self.props = model.properties()
         self._kernel, self._seed_k, self._chunk_k = self._build()
         # Whole-search executables, compiled in stages (_search_executable),
-        # and the wall seconds of each stage: this run's (detail["phases"])
-        # and the engine's totals with the path counters (metrics()).
-        self._executables: dict = {}
-        self._phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
+        # with each stage's seconds; the path counters beside them
+        # (metrics()).
+        self._stages = Stages("resident", self._tracer)
         self._counters = {
-            "trace_seconds_total": 0.0,
-            "lower_seconds_total": 0.0,
-            "compile_seconds_total": 0.0,
-            "execute_seconds_total": 0.0,
             "parent_map_entries": 0,
             "replay_steps_total": 0,
             "replay_traces_total": 0,
@@ -1142,7 +1184,7 @@ class ResidentSearch:
         K = self.batch_size
         start = time.monotonic()
         self._parent_map = None  # invalidate any prior reconstruction cache
-        self._phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
+        self._stages.new_run()
         if self._ring is not None and self._carry is None and self._ring.steps:
             # Fresh search (no suspended carry): telemetry starts over too.
             self._ring = self._ring.fresh()
@@ -1213,7 +1255,7 @@ class ResidentSearch:
             )
             with self._tracer.span("resident.search", cat="engine"):
                 exe = self._search_executable(args)
-                with self._stage("execute"):
+                with self._stages.stage("execute"):
                     t_lo, t_hi, p_lo, p_hi, summary, tm_rows = exe(*(
                         a for i, a in enumerate(args)
                         if i not in _SEARCH_STATIC
@@ -1225,7 +1267,7 @@ class ResidentSearch:
                 # holds the LAST 2^telemetry_log2 steps; earlier rows count
                 # as dropped). The window is the execute stage alone: no
                 # tracing, lowering or executable load.
-                w_us = self._phases["execute_s"] * 1e6
+                w_us = self._stages.phases["execute_s"] * 1e6
                 self._ring.drain(np.asarray(tm_rows), int(summary[8]),
                                  window_us=w_us)
                 if self._calib is not None:
@@ -1408,7 +1450,7 @@ class ResidentSearch:
             discoveries = dict(w["discoveries"])
         detail = self._detail()
         if not chunked:
-            detail = {**(detail or {}), "phases": dict(self._phases)}
+            detail = {**(detail or {}), "phases": dict(self._stages.phases)}
         if self._warm_kind is not None:
             detail = dict(detail or {})
             detail["corpus"] = {
@@ -1427,42 +1469,18 @@ class ResidentSearch:
             detail=detail,
         )
 
-    @contextlib.contextmanager
-    def _stage(self, stage: str):
-        """Span `resident.<stage>` and add its wall seconds to this run's
-        phases and the engine's totals (always timed: two clock reads)."""
-        t0 = time.monotonic()
-        try:
-            with self._tracer.span("resident." + stage, cat="engine"):
-                yield
-        finally:
-            dt = time.monotonic() - t0
-            self._phases[stage + "_s"] += dt
-            self._counters[stage + "_seconds_total"] += dt
-
     def _search_executable(self, args: tuple):
-        """The compiled whole-search program for `args`, built in the
-        stages jit runs internally, each its own span: trace, lower, and
-        compile (a backend compile or a load from the persistent cache).
-        The same module (`jit_search`) and executable as a plain call of
-        `self._kernel`; kept per static arguments and operand-table
-        shapes, which is what jit's own cache would key on here."""
+        """The compiled whole-search program (`jit_search`) for `args`,
+        built in stages (`Stages.executable`); kept per static arguments
+        and operand-table shapes, which is what jit's own cache would key
+        on here."""
         dyn = args[-1]
         key = (
             tuple(args[i] for i in _SEARCH_STATIC),
             jax.tree.structure(dyn),
             tuple((x.shape, x.dtype) for x in jax.tree.leaves(dyn)),
         )
-        exe = self._executables.get(key)
-        if exe is None:
-            with self._stage("trace"):
-                traced = self._kernel.trace(*args)
-            with self._stage("lower"):
-                lowered = traced.lower()
-            with self._stage("compile"):
-                exe = lowered.compile()
-            self._executables[key] = exe
-        return exe
+        return self._stages.executable(self._kernel, args, key)
 
     def telemetry_summary(self) -> Optional[dict]:
         """Step-telemetry digest (obs/ring.py; None with telemetry off) —
@@ -1475,7 +1493,7 @@ class ResidentSearch:
         """Flat counter snapshot for the obs registry / Prometheus export.
         Host-side values only (drained telemetry + store counters) — a
         scrape never syncs the device mid-search."""
-        out: dict = dict(self._counters)
+        out: dict = {**self._stages.totals, **self._counters}
         if self._ring is not None:
             out.update(
                 steps=self._ring.steps,

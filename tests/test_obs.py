@@ -194,10 +194,10 @@ def test_resident_whole_search_phases_and_step_window(tpc3):
     assert set(phases) == set(PHASES_DETAIL_KEYS)
     assert all(v > 0 for v in phases.values()), phases
     # The ring's window is the execute stage alone: no trace, lowering or
-    # compile in the per-step wall time.
+    # compile in the per-step wall time (the digest keeps 2 decimals).
     t = r.detail["telemetry"]
-    assert t["step_us"]["mean"] == pytest.approx(
-        phases["execute_s"] * 1e6 / t["steps"]
+    assert t["step_us"]["mean"] == round(
+        phases["execute_s"] * 1e6 / t["steps"], 2
     )
     m = rs.metrics()
     for k, v in phases.items():
@@ -360,6 +360,95 @@ def test_profiler_host_plane_has_the_span_names(traced_check):
             "path.parent_map", "path.replay"} <= set(counts)
     for name, n in counts.items():
         assert host.count(name) == n, name
+
+
+SHARDED_STAGES = ("sharded.trace", "sharded.lower", "sharded.compile",
+                  "sharded.execute")
+
+
+@pytest.fixture(scope="module")
+def sharded_traced_check(tmp_path_factory):
+    """One traced spawn_tpu(chips=4) + discoveries() on 2pc-4: (checker,
+    paths, the saved Chrome trace's events)."""
+    from stateright_tpu.tensor.models import TensorTwoPhaseSys
+
+    out = str(tmp_path_factory.mktemp("sharded") / "check.trace.json")
+    checker = (
+        TensorTwoPhaseSys(4).checker()
+        .trace_out(out)
+        .spawn_tpu(batch_size=64, table_log2=12, chips=4)
+        .join()
+    )
+    paths = checker.discoveries()
+    return checker, paths, _validate_chrome_trace(json.load(open(out)))
+
+
+def test_sharded_check_spans_nest_and_stages_cover_the_search(
+    sharded_traced_check,
+):
+    checker, paths, events = sharded_traced_check
+    by = {}
+    for e in events:
+        by.setdefault(e["name"], []).append(e)
+    (run,) = by["search.run"]
+    (search,) = by["sharded.search"]
+    assert _inside(search, run)
+    stages = [by[name][0] for name in SHARDED_STAGES]
+    assert all(len(by[name]) == 1 and _inside(by[name][0], search)
+               for name in SHARDED_STAGES)
+    # The four stages are the search call: their seconds add up to it.
+    assert sum(e["dur"] for e in stages) == pytest.approx(search["dur"],
+                                                          rel=0.01)
+    phases = checker._result.detail["phases"]
+    assert set(phases) == set(PHASES_DETAIL_KEYS)
+    assert validate_detail(checker._result.detail) == []
+    (disc,) = by["checker.discoveries"]
+    (pmap,) = by["path.parent_map"]
+    assert _inside(pmap, disc)
+    assert pmap["args"]["entries"] == checker.unique_state_count() == 1568
+    replays = by["path.replay"]
+    assert sorted(e["args"]["steps"] for e in replays) == sorted(
+        len(p) - 1 for p in paths.values())
+    assert all(_inside(e, disc) for e in replays)
+    ids = {e["args"]["check"] for e in events}
+    assert len(ids) == 1 and ids.pop()
+
+
+def test_sharded_counters_are_exact_on_2pc4(sharded_traced_check):
+    from stateright_tpu.tensor.frontier import seed_init
+
+    checker, paths, _ = sharded_traced_check
+    search = checker._search
+    m = search.metrics()
+    r = checker._result
+    _, _, _, n_raw = seed_init(search.model)
+    # Every generated successor is routed once; every step each of the N
+    # chips sends N x C slots.
+    assert m["exchange_rows_total"] == r.state_count - n_raw == 8258 - n_raw
+    assert m["exchange_rows_total"] == m["generated_states"]
+    assert m["exchange_slots_total"] == (
+        r.steps * search.n_chips ** 2 * search.dest_capacity)
+    assert m["parent_map_entries"] == 1568
+    assert m["replay_steps_total"] == sum(len(p) - 1 for p in paths.values())
+    assert m["replay_traces_total"] == 1
+    for k, v in r.detail["phases"].items():
+        assert m[k[:-2] + "_seconds_total"] == v
+
+
+def test_sharded_chunked_run_counts_the_same_exchange():
+    from stateright_tpu.parallel.sharded import ShardedSearch, make_mesh
+    from stateright_tpu.tensor.models import TensorTwoPhaseSys
+
+    whole = ShardedSearch(TensorTwoPhaseSys(4), make_mesh(4), 64, 12)
+    r = whole.run()
+    chunked = ShardedSearch(TensorTwoPhaseSys(4), make_mesh(4), 64, 12)
+    rc = chunked.run(budget=3)
+    assert (rc.state_count, rc.steps) == (r.state_count, r.steps)
+    for k in ("exchange_rows_total", "exchange_slots_total"):
+        assert chunked.metrics()[k] == whole.metrics()[k] > 0
+    # The chunked path runs no staged whole-search call.
+    assert "phases" not in rc.detail
+    assert chunked.metrics()["execute_seconds_total"] == 0.0
 
 
 # -- Prometheus export ---------------------------------------------------------
