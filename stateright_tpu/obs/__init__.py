@@ -45,6 +45,7 @@ from .schema import (
     FAULTS_DETAIL_KEYS,
     PHASES_DETAIL_KEYS,
     SERVICE_DETAIL_KEYS,
+    STAGES_COUNTER_KEYS,
     TELEMETRY_KEYS,
     TERMINAL_EVENT_BY_STATUS,
     TERMINAL_EVENTS,
@@ -67,6 +68,7 @@ __all__ = [
     "FAULTS_DETAIL_KEYS",
     "PHASES_DETAIL_KEYS",
     "SERVICE_DETAIL_KEYS",
+    "STAGES_COUNTER_KEYS",
     "TELEMETRY_KEYS",
     "TERMINAL_EVENT_BY_STATUS",
     "TERMINAL_EVENTS",

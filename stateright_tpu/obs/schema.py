@@ -216,14 +216,34 @@ TELEMETRY_KEYS = {
 #: path of the resident and the sharded engines): wall seconds of the stages
 #: jit runs for the search program in this run, each timed always and
 #: spanned as `<engine>.<stage>` inside `<engine>.search` (`resident` or
-#: `sharded`). The first three are 0 when the run reused the executable an
-#: earlier run() of the same engine compiled.
+#: `sharded`). The first three are 0 when the run took its executable from
+#: the cache: an earlier run of the same engine, or of any engine of the
+#: same model definition and configuration (compile_cache.py).
 PHASES_DETAIL_KEYS = {
     "trace_s": "tracing the search closure to a jaxpr",
     "lower_s": "lowering the jaxpr to an HLO module",
     "compile_s": "backend compile, or the load of the executable from "
                  "the persistent compilation cache",
     "execute_s": "running the executable through the one summary fetch",
+}
+
+
+#: Counters of `Stages.totals` (tensor/resident.py), reported by the
+#: `resident` and `sharded` registry sources through the engines'
+#: `metrics()`: the stages' seconds over the engine's runs, and how its
+#: whole-search calls found their executable.
+STAGES_COUNTER_KEYS = {
+    "trace_seconds_total": "seconds tracing the search (trace_s, summed)",
+    "lower_seconds_total": "seconds lowering it (lower_s, summed)",
+    "compile_seconds_total": "seconds compiling or loading it (compile_s, "
+                             "summed)",
+    "execute_seconds_total": "seconds running it (execute_s, summed)",
+    "executable_cache_hits_total": "whole-search calls whose executable "
+                                   "came from the cache: no trace, "
+                                   "lowering or load",
+    "executable_cache_misses_total": "whole-search calls that traced, "
+                                     "lowered and compiled or loaded "
+                                     "their executable",
 }
 
 

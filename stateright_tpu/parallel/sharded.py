@@ -309,6 +309,16 @@ class ShardedSearch:
         # exchange counters (metrics()). `_exchange_mark` is the (steps,
         # generated) point up to which the exchange is counted.
         self._stages = Stages("sharded", self._tracer)
+        # The model's definition and every argument `_build` reads, the
+        # mesh's devices included: engines that agree on it share one
+        # search executable (Stages.executable).
+        dk = model.definition_key()
+        self._definition = None if dk is None else (
+            dk, batch_size, table_log2, self.dest_capacity, self.append,
+            insert_variant, store, high_water, low_water, summary_log2,
+            telemetry, telemetry_log2, donate_chunks, self.axis,
+            tuple(d.id for d in self.mesh.devices.flat),
+        )
         self._counters = {
             "parent_map_entries": 0,
             "replay_steps_total": 0,
@@ -424,6 +434,7 @@ class ShardedSearch:
         _insert = resolve_insert(self.insert_variant)
         _insert_step = resolve_insert(self.insert_variant, summary_cfg=s_cfg)
         _fused = getattr(_insert_step, "fused_summary", False)
+        _append = append_new if self.append == "scatter" else append_new_dus
         SQ = self._SQ
         TMR = self._TMR
         # N*C rows of slack beyond the per-shard table size: the append
@@ -723,9 +734,6 @@ class ShardedSearch:
                         )
                 enq = is_new & ~suspect if tiered else is_new
                 # -- append fresh states to the local queue (cumsum) -----------
-                _append = (
-                    append_new if self.append == "scatter" else append_new_dus
-                )
                 with jax.named_scope("append"):
                     q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
                         c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth,
@@ -1250,8 +1258,13 @@ class ShardedSearch:
                 jnp.int32(max_steps),
                 jnp.uint32(target_max_depth or 0),
             )
-            with self._tracer.span("sharded.search", cat="engine"):
-                exe = self._stages.executable(self._kernel, args, key=None)
+            with self._tracer.span("sharded.search", cat="engine") as span:
+                exe, cached = self._stages.executable(
+                    self._kernel, args,
+                    tuple((a.shape, a.dtype) for a in args),
+                    self._definition,
+                )
+                span.set(cached=int(cached))
                 with self._stages.stage("execute"):
                     t_lo, t_hi, p_lo, p_hi, *outs = exe(*args)
                     # ONE gather for the small outputs (one DCN round-trip

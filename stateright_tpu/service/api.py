@@ -50,7 +50,7 @@ from ..obs import (
     mint_trace_id,
 )
 from .queue import AdmissionQueue, Job, JobStatus
-from .scheduler import ServiceEngine, ServiceError, StepFault
+from .scheduler import ServiceEngine, ServiceError, StepFault, group_key
 from .tenancy import DEFAULT_TENANT, QuotaExceeded, TenantQuotas
 
 
@@ -756,7 +756,7 @@ class CheckService:
             "service.preempt", cat="service", job=job.id, trace=job.trace
         )
         self._events.emit("job.preempted", job=job.id, trace=job.trace)
-        g = self._engine.groups.get(id(job.model))
+        g = self._engine.groups.get(group_key(job.model))
         if g is not None and job in g.jobs:
             g.jobs.remove(job)
         job.status = JobStatus.PREEMPTED

@@ -37,7 +37,7 @@ from __future__ import annotations
 import time
 import weakref
 from functools import partial
-from typing import Optional
+from typing import Hashable, Optional
 
 import numpy as np
 
@@ -111,8 +111,18 @@ def _build_service_step(model, K, props, insert, store):
     return step
 
 
+def group_key(model) -> Hashable:
+    """The key of a model's group (its compiled step and its batches): the
+    model's definition (`TensorModel.definition_key()`), so that jobs of
+    equal models share one group whichever instance each was submitted
+    with; the instance itself where the model names no definition."""
+    key = model.definition_key()
+    return ("instance", id(model)) if key is None else key
+
+
 class _Group:
-    """Jobs sharing one model (and therefore one compiled step)."""
+    """Jobs sharing one model definition (and therefore one compiled
+    step; `group_key`)."""
 
     def __init__(self, model, K, insert, store):
         self.model = model
@@ -285,8 +295,8 @@ class ServiceEngine:
         # observation records flush into the corpus root when one exists.
         self._calib = None
         self._calib_root = corpus_dir
-        self.groups: dict[int, _Group] = {}
-        self._group_rr: list[int] = []
+        self.groups: dict[Hashable, _Group] = {}
+        self._group_rr: list = []
         # Robustness accounting (surfaced in stats()["faults"] and each
         # job result's detail["faults"] — obs/schema.py FAULTS_DETAIL_KEYS).
         self.fault_counters = {
@@ -309,7 +319,7 @@ class ServiceEngine:
     # -- admission / retirement ------------------------------------------------
 
     def group_of(self, job: Job) -> _Group:
-        key = id(job.model)
+        key = group_key(job.model)
         g = self.groups.get(key)
         if g is None:
             g = _Group(job.model, self.batch_size, self._insert, self._store)
@@ -390,9 +400,10 @@ class ServiceEngine:
         engine lowering/table config x the job's finish policy — exactly
         the inputs that determine a cold run's visited set and result —
         plus the same address factored into its near-match components
-        (store/corpus.key_components). Cached per (model instance, finish
-        signature, tenant): the jaxpr trace behind the definition hash
-        costs milliseconds and submissions repeat.
+        (store/corpus.key_components). Cached per (model definition, or
+        instance where it names none; finish signature; tenant): the jaxpr
+        trace behind the definition hash costs milliseconds and
+        submissions repeat.
 
         A non-default tenant salts the key AND the factored "def"
         component (store/corpus.py) — per-tenant corpus namespaces, so
@@ -407,12 +418,15 @@ class ServiceEngine:
             job.finish_when, job.target_state_count, job.target_max_depth
         )
         salt = tenant_salt(getattr(job, "tenant", None))
-        sig = (id(job.model), fin, salt)
+        gk = group_key(job.model)
+        by_instance = job.model.definition_key() is None
+        sig = (gk, fin, salt)
         hit = self._corpus_keys.get(sig)
-        # Same recycled-id() guard as specdelta._COMPONENT_CACHE: the cached
-        # key only serves if the weakly-held model is the SAME object —
-        # a stale hit after id reuse would preload the wrong corpus.
-        if hit is not None and hit[0]() is job.model:
+        # Same recycled-id() guard as specdelta._COMPONENT_CACHE for a key
+        # by instance: the cached key only serves if the weakly-held model
+        # is the SAME object — a stale hit after id reuse would preload the
+        # wrong corpus. A definition names its model by value.
+        if hit is not None and (not by_instance or hit[0]() is job.model):
             return hit[1], hit[2]
         cfg = self._store.config
         lowering = {
@@ -426,6 +440,9 @@ class ServiceEngine:
         }
         key = content_key(job.model, lowering, tenant=salt)
         comp = key_components(job.model, lowering, tenant=salt)
+        if not by_instance:
+            self._corpus_keys[sig] = (None, key, comp)
+            return key, comp
         try:
             self._corpus_keys[sig] = (weakref.ref(job.model), key, comp)
         except TypeError:
@@ -989,13 +1006,13 @@ class ServiceEngine:
         return None
 
     def retire(self, job: Job) -> None:
-        g = self.groups.get(id(job.model))
+        g = self.groups.get(group_key(job.model))
         if g is not None and job in g.jobs:
             g.jobs.remove(job)
         job.drop_frontier()
         self._job_semantics_finalize(job)
         # Empty groups are kept: their compiled step is the expensive part,
-        # and a later job on the same model instance reuses it.
+        # and a later job of the same model definition reuses it.
 
     def _job_semantics_finalize(self, job: Job) -> None:
         """Per-job-retire semantics housekeeping: release the job's corpus

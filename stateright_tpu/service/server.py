@@ -54,9 +54,9 @@ RETRY_AFTER_S = "1"
 
 def default_registry() -> dict:
     """Name -> model-builder callables for the bundled tensor workloads.
-    Builders are cached per argument tuple so repeat submissions of the
-    same config share one model instance — and therefore one compiled
-    step and one batch (the continuous-batching win)."""
+    Repeat submissions of the same config build equal models, which name
+    one definition (`TensorModel.definition_key()`), so they share one
+    compiled step and one batch (the continuous-batching win)."""
     from ..tensor.models import (
         TensorIncrementLock,
         TensorLinearEquation,
@@ -76,14 +76,13 @@ def default_registry() -> dict:
 
 
 class ModelRegistry:
-    """Instance-caching wrapper over builder callables (see
-    default_registry): same (name, args) -> same model object."""
+    """Builder callables by name (see default_registry): (name, args) ->
+    a model built from them."""
 
     def __init__(self, builders: Optional[dict] = None):
         self._builders = (
             dict(builders) if builders is not None else default_registry()
         )
-        self._cache: dict = {}
 
     def names(self) -> list:
         return sorted(self._builders)
@@ -93,11 +92,7 @@ class ModelRegistry:
             raise KeyError(
                 f"unknown model {name!r} (registered: {self.names()})"
             )
-        args = dict(args or {})
-        key = (name, tuple(sorted(args.items())))
-        if key not in self._cache:
-            self._cache[key] = self._builders[name](**args)
-        return self._cache[key]
+        return self._builders[name](**dict(args or {}))
 
 
 # -- pure view builders --------------------------------------------------------

@@ -31,6 +31,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..compile_cache import REPLAYS
 from ..core.discovery import HasDiscoveries
 from ..core.model import Expectation
 from ..core.path import Path
@@ -306,36 +307,61 @@ def record_discovery(discovered, disc_lo, disc_hi, i, hit, lo, hi):
 
 
 class _Replay:
-    """The compiled programs of a path replay for one model instance: the
-    fingerprints of the init rows, and one transition (expand a row
-    uint32[1, L], fingerprint all its successors). Shape-stable, so one
-    compiled transition serves every path of any length.
+    """The compiled programs of a path replay for one model definition, or
+    for one instance of a model that names none: the fingerprints of the
+    init rows, and one transition (expand a row uint32[1, L], fingerprint
+    all its successors). Shape-stable, so one compiled transition serves
+    every path of any length.
 
+    The calling model is a static argument (`_Static`), so a trace reads
+    the model of the call that traces and no program keeps a model alive.
     Both take the model's operand tables (lowered models, `dyn_tables`) as
     an argument and install them as `model._dyn` while tracing, as the
     resident engine's kernels do, so tables that `extend()` refilled are
     read fresh and new table shapes retrace. `max_actions` is a static
     argument of the transition: with the row and table shapes it is what
-    `extend()` can change. `traces` counts the transition's traces."""
+    `extend()` can change. `traces` counts the transition's traces, and
+    each call's `_Static` counts those that call made."""
 
-    def __init__(self, model: TensorModel):
-        ref = weakref.ref(model)  # the programs must not keep the model alive
+    def __init__(self):
         self.traces = 0
 
-        def replay_fingerprint(rows, dyn):
-            return _with_tables(ref(), dyn, state_fingerprint, rows)
+        def replay_fingerprint(static, rows, dyn):
+            return _with_tables(static.ref(), dyn, state_fingerprint, rows)
 
-        def replay_step(row, dyn, max_actions):
+        def replay_step(static, row, dyn, max_actions):
             self.traces += 1
+            static.traces += 1
 
             def expand_fp(m, row):
                 succs, valid = m.expand(row)
                 return (succs[0], valid[0], *state_fingerprint(m, succs[0]))
 
-            return _with_tables(ref(), dyn, expand_fp, row)
+            return _with_tables(static.ref(), dyn, expand_fp, row)
 
-        self.fingerprint = jax.jit(replay_fingerprint)
-        self.step = jax.jit(replay_step, static_argnums=2)
+        self.fingerprint = jax.jit(replay_fingerprint, static_argnums=0)
+        self.step = jax.jit(replay_step, static_argnums=(0, 3))
+
+
+class _Static:
+    """A model as a static argument of its `_Replay`'s programs: every
+    instance compares equal (one `_Replay` serves one definition), and it
+    holds its model weakly, since jit's cache keeps the first it saw. A
+    trace runs with the static argument of the call that made it, so
+    `traces` counts the traces of the replay that passed it, whatever
+    other checks of the definition do at the same time."""
+
+    __slots__ = ("ref", "traces")
+
+    def __init__(self, model: TensorModel):
+        self.ref = weakref.ref(model)
+        self.traces = 0
+
+    def __eq__(self, other) -> bool:
+        return isinstance(other, _Static)
+
+    def __hash__(self) -> int:
+        return 0
 
 
 def _with_tables(model, dyn, fn, rows):
@@ -348,23 +374,32 @@ def _with_tables(model, dyn, fn, rows):
         model._dyn = None
 
 
-#: Replay programs by id(model) (the tensor models are dataclasses, so
-#: unhashable), each dropped when its model is collected.
+#: Replay programs of models that name no definition, by id(model) (the
+#: tensor models are dataclasses, so unhashable), each dropped when its
+#: model is collected. Those of named definitions are in
+#: `compile_cache.REPLAYS`.
 _REPLAYS: dict = {}
 
 
 def _replay_programs(model: TensorModel) -> _Replay:
+    key = model.definition_key()
+    if key is not None:
+        rp = REPLAYS.get(key)
+        if rp is None:
+            rp = _Replay()
+            REPLAYS.put(key, rp)
+        return rp
     key = id(model)
     rp = _REPLAYS.get(key)
     if rp is None:
-        rp = _REPLAYS[key] = _Replay(model)
+        rp = _REPLAYS[key] = _Replay()
         weakref.finalize(model, _REPLAYS.pop, key, None)
     return rp
 
 
 def replay_traces(model: TensorModel) -> int:
     """How often the replay's transition program has been traced for this
-    model instance."""
+    model's definition (for this instance, if it names none)."""
     return _replay_programs(model).traces
 
 
@@ -373,10 +408,14 @@ def replay_fp_chain(model: TensorModel, chain: list) -> Path:
     recovering decoded states and action labels (the host checkers'
     Path.from_fingerprints technique, ref: src/checker/path.rs:20-97).
     Each transition is one call of a compiled program and one fetch."""
+    return _replay_chain(model, chain, _Static(model))
+
+
+def _replay_chain(model: TensorModel, chain: list, static: _Static) -> Path:
     rp = _replay_programs(model)
     dyn = model.dyn_tables() if hasattr(model, "dyn_tables") else {}
     init = np.asarray(model.init_states(), dtype=np.uint32)
-    ilo, ihi = jax.device_get(rp.fingerprint(init, dyn))
+    ilo, ihi = jax.device_get(rp.fingerprint(static, init, dyn))
     init_fps = pack_fp(ilo, ihi)
     rows = np.nonzero(init_fps == np.uint64(chain[0]))[0]
     if len(rows) == 0:
@@ -389,7 +428,7 @@ def replay_fp_chain(model: TensorModel, chain: list) -> Path:
     pairs = []
     for next_fp in chain[1:]:
         succs, valid, slo, shi = jax.device_get(
-            rp.step(cur_row[None], dyn, model.max_actions)
+            rp.step(static, cur_row[None], dyn, model.max_actions)
         )
         sfps = pack_fp(slo, shi)
         hits = np.nonzero(valid & (sfps == np.uint64(next_fp)))[0]
@@ -424,9 +463,9 @@ def reconstruct_path(
         cur = parent_map.get(cur, 0)
     chain.reverse()
     with tracer.span("path.replay", cat="path", steps=len(chain) - 1) as span:
-        traces = replay_traces(model)
-        path = replay_fp_chain(model, chain)
-        traced = int(replay_traces(model) != traces)
+        static = _Static(model)
+        path = _replay_chain(model, chain, static)
+        traced = int(static.traces > 0)
         span.set(traced=traced)
     if counters is not None:
         counters["replay_steps_total"] += len(chain) - 1

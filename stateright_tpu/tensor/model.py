@@ -9,8 +9,9 @@ reference wastes a whole thread on one state at a time.
 
 from __future__ import annotations
 
+import dataclasses
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import Any, Callable, Hashable, Optional
 
 import jax.numpy as jnp
 
@@ -48,6 +49,22 @@ class TensorModel:
     canonicalization kernel; see `stateright_tpu.tensor.symmetry`). When
     defined, the engines fingerprint the canonical form but keep searching
     with the original states (ref: src/checker/dfs.rs:309-334).
+
+    `definition_key()` names the model's definition: a hashable value that
+    two instances share exactly when every program traced from them is the
+    same (their `lanes`, `max_actions`, `init_states()`, `expand`,
+    `properties()`, `within_boundary` and `representative` compute the same
+    things). The engines then share one compiled search and one compiled
+    path replay across all instances of a definition
+    (`stateright_tpu/compile_cache.py`), so a check of a new instance skips
+    the trace, the lowering and the executable load. The default, None,
+    names nothing: each engine compiles its own search and each instance
+    its own replay. Return a key only when it covers everything the traced
+    code reads: a key that leaves out a value the code reads hands one
+    configuration the compiled program of another, which checks the wrong
+    model without any error. `dataclass_definition_key` is the key of a
+    dataclass whose `__post_init__` derives every other attribute from its
+    fields.
     """
 
     lanes: int
@@ -92,6 +109,11 @@ class TensorModel:
     def format_step(self, last_state, action) -> Any:
         return None
 
+    def definition_key(self) -> Optional[Hashable]:
+        """The model's definition, or None when it cannot be named (see the
+        class docstring)."""
+        return None
+
     def property_by_name(self, name: str) -> TensorProperty:
         for p in self.properties():
             if p.name == name:
@@ -104,3 +126,23 @@ class TensorModel:
         from ..checker.builder import CheckerBuilder
 
         return CheckerBuilder(self)
+
+
+def dataclass_definition_key(model, cls) -> Optional[Hashable]:
+    """The definition key of a dataclass model of class `cls` whose
+    `__post_init__` derives every other attribute (tables, `lanes`,
+    `max_actions`, `representative`) from its fields: the class, its code
+    as the class and the instance hold it now (so a method replaced at run
+    time is another definition), and the field values. None for an
+    instance of a subclass, which may read more than the fields: a subclass
+    names a definition only by overriding `definition_key()` itself."""
+    if type(model) is not cls:
+        return None
+    code = tuple(
+        (name, getattr(fn, "__func__", fn))
+        for scope in (*(vars(c) for c in cls.__mro__[:-1]), vars(model))
+        for name, fn in scope.items()
+        if callable(fn)
+    )
+    values = tuple(getattr(model, f.name) for f in dataclasses.fields(model))
+    return cls, code, values

@@ -11,20 +11,24 @@ from dataclasses import dataclass
 
 import jax.numpy as jnp
 
-from .model import TensorModel, TensorProperty
+from .model import TensorModel, TensorProperty, dataclass_definition_key
 
 
 @dataclass
 class TensorLinearEquation(TensorModel):
     """a*x + b*y == c (mod 256) — the canonical checker workload
     (ref: src/test_util.rs:140-192). Lanes: [x, y]; actions: IncreaseX,
-    IncreaseY. Full space 256*256 = 65,536 states."""
+    IncreaseY. Full space 256*256 = 65,536 states. Its `definition_key()`
+    is its class and fields: nothing else is read."""
 
     a: int
     b: int
     c: int
     lanes = 2
     max_actions = 2
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorLinearEquation)
 
     def init_states(self):
         return jnp.zeros((1, 2), dtype=jnp.uint32)
@@ -68,6 +72,9 @@ class TensorTwoPhaseSys(TensorModel):
 
     Actions (static slots): 0 = TmCommit, 1 = TmAbort, then per RM:
     [TmRcvPrepared, RmPrepare, RmChooseToAbort, RmRcvCommit, RmRcvAbort].
+
+    Its `definition_key()` is its class and fields: `__post_init__` derives
+    `lanes`, `max_actions` and `representative` from them.
     """
 
     rm_count: int
@@ -87,6 +94,9 @@ class TensorTwoPhaseSys(TensorModel):
             self.representative = self._representative_value_sort
         elif self.symmetry:
             self.representative = self._representative
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorTwoPhaseSys)
 
     def init_states(self):
         return jnp.zeros((1, self.lanes), dtype=jnp.uint32)
@@ -280,7 +290,9 @@ class TensorIncrement(TensorModel):
 
     The "fin" property (ALWAYS sum(pc==3) == i) is violated by the race; an
     undiscoverable `sometimes` property forces full enumeration when needed,
-    mirroring the host test strategy.
+    mirroring the host test strategy. Its `definition_key()` is its class
+    and fields: `__post_init__` derives `lanes`, `max_actions` and
+    `representative` from them.
     """
 
     thread_count: int
@@ -292,6 +304,9 @@ class TensorIncrement(TensorModel):
         self.max_actions = self.thread_count
         if self.symmetry:
             self.representative = self._representative
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorIncrement)
 
     def init_states(self):
         row = [0] + [0, 1] * self.thread_count
@@ -380,7 +395,9 @@ class TensorIncrementLock(TensorModel):
     host representative (``tuple(sorted(s))``), and since that pair IS the
     entire per-entity state there are no satellite-bit ties to split: the
     reduced counts match the host ``check-sym`` goldens exactly (contrast the
-    2PC case in tensor/symmetry.py's COUNT CONTRACT)."""
+    2PC case in tensor/symmetry.py's COUNT CONTRACT). Its `definition_key()`
+    is its class and fields: `__post_init__` derives `lanes`, `max_actions`
+    and `representative` from them."""
 
     thread_count: int
     symmetry: bool = False
@@ -390,6 +407,9 @@ class TensorIncrementLock(TensorModel):
         self.max_actions = self.thread_count
         if self.symmetry:
             self.representative = self._representative
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorIncrementLock)
 
     def init_states(self):
         return jnp.asarray(
@@ -523,7 +543,9 @@ class TensorRaft(TensorModel):
     "leader elected" (EVENTUALLY — split-vote walks that exhaust max_term
     without a leader are genuine counterexamples: Raft's liveness needs
     randomized timeouts the adversarial scheduler doesn't grant), and
-    "can elect" (SOMETIMES — the positive witness)."""
+    "can elect" (SOMETIMES — the positive witness). Its `definition_key()`
+    is its class and fields: `__post_init__` derives `lanes` and
+    `max_actions` from them."""
 
     server_count: int = 3
     max_term: int = 3
@@ -532,6 +554,9 @@ class TensorRaft(TensorModel):
         n = self.server_count
         self.lanes = 3 * n
         self.max_actions = 2 * n + 2 * n * (n - 1)
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorRaft)
 
     def init_states(self):
         return jnp.zeros((1, self.lanes), dtype=jnp.uint32)

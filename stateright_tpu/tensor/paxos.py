@@ -41,7 +41,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from .model import TensorModel, TensorProperty
+from .model import TensorModel, TensorProperty, dataclass_definition_key
 from .poolops import rank_sort
 
 EMPTY = np.uint32(0xFFFFFFFF)
@@ -57,7 +57,11 @@ def _bits(n_values: int) -> int:
 
 @dataclass
 class TensorPaxos(TensorModel):
-    """Device Paxos over C clients / S servers (default matches the golden)."""
+    """Device Paxos over C clients / S servers (default matches the golden).
+
+    Its `definition_key()` is its class and fields: `__post_init__` derives
+    the layout, `lanes`, `max_actions` and every vocabulary and
+    linearizability table from them."""
 
     client_count: int
     server_count: int = 3
@@ -99,6 +103,9 @@ class TensorPaxos(TensorModel):
 
         self._build_vocab()
         self._build_lin_tables()
+
+    def definition_key(self):
+        return dataclass_definition_key(self, TensorPaxos)
 
     def _build_vocab(self):
         """Envelope vocabulary: contiguous id ranges per message type

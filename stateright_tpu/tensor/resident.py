@@ -34,6 +34,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..compile_cache import EXECUTABLES, ProgramCache, trace_context
 from ..core.discovery import HasDiscoveries
 from ..core.model import Expectation
 from ..knobs import (
@@ -50,6 +51,7 @@ from ..obs import (
     N_COLS,
     PHASES_DETAIL_KEYS,
     REGISTRY,
+    STAGES_COUNTER_KEYS,
     StepRing,
     as_tracer,
     build_detail,
@@ -187,17 +189,19 @@ class Stages:
     span `<engine>.<stage>`: trace, lower, compile (a backend compile or a
     load from the persistent cache), then execute. Always timed (two clock
     reads a stage): `phases` holds this run's seconds (`detail["phases"]`)
-    and `totals` the engine's `<stage>_seconds_total` counters. Shared by
-    the resident and the sharded engines."""
+    and `totals` the engine's `<stage>_seconds_total` counters, with
+    `executable_cache_hits_total`/`executable_cache_misses_total` beside
+    them. Shared by the resident and the sharded engines."""
 
     def __init__(self, engine: str, tracer):
         self.engine = engine
         self.tracer = tracer
         self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
         self.totals = {
-            k[:-2] + "_seconds_total": 0.0 for k in PHASES_DETAIL_KEYS
+            k: 0.0 if k.endswith("_seconds_total") else 0
+            for k in STAGES_COUNTER_KEYS
         }
-        self._executables: dict = {}
+        self._executables = ProgramCache(size=None)
 
     def new_run(self) -> None:
         self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
@@ -213,20 +217,34 @@ class Stages:
             self.phases[stage + "_s"] += dt
             self.totals[stage + "_seconds_total"] += dt
 
-    def executable(self, jitted, args: tuple, key):
-        """The compiled program of `jitted` for `args`, traced, lowered and
-        compiled once per `key` (what jit's own cache would key on): the
-        same module and executable as a plain call of `jitted`."""
-        exe = self._executables.get(key)
-        if exe is None:
+    def executable(self, jitted, args: tuple, key, definition=None):
+        """`(compiled, cached)`: the compiled program of `jitted` for `args`,
+        traced, lowered and compiled once per `key` (what jit's own cache
+        would key on), and whether it came from a cache: the same module
+        and executable as a plain call of `jitted`. With a `definition`
+        (the model's definition key and everything the engine's `_build`
+        reads) the program is shared by every engine of this process
+        (`compile_cache.EXECUTABLES`), under the JAX settings it was traced
+        with; without one it is this engine's."""
+        if definition is None:
+            store = self._executables
+        else:
+            store = EXECUTABLES
+            key = (self.engine, definition, key, trace_context())
+        exe = store.get(key)
+        cached = exe is not None
+        if cached:
+            self.totals["executable_cache_hits_total"] += 1
+        else:
+            self.totals["executable_cache_misses_total"] += 1
             with self.stage("trace"):
                 traced = jitted.trace(*args)
             with self.stage("lower"):
                 lowered = traced.lower()
             with self.stage("compile"):
                 exe = lowered.compile()
-            self._executables[key] = exe
-        return exe
+            store.put(key, exe)
+        return exe, cached
 
 
 _ins_jit = jax.jit(_insert_impl)  # one compile cache shared by every regrow
@@ -531,6 +549,14 @@ class ResidentSearch:
         # with each stage's seconds; the path counters beside them
         # (metrics()).
         self._stages = Stages("resident", self._tracer)
+        # The model's definition and every argument `_build` reads: engines
+        # that agree on it share one search executable (Stages.executable).
+        dk = model.definition_key()
+        self._definition = None if dk is None else (
+            dk, batch_size, table_log2, self.queue_log2, self.append,
+            table_layout, insert_variant, store, high_water, low_water,
+            summary_log2, telemetry, telemetry_log2, donate_chunks,
+        )
         self._counters = {
             "parent_map_entries": 0,
             "replay_steps_total": 0,
@@ -601,6 +627,7 @@ class ResidentSearch:
         A = model.max_actions
         L = model.lanes
         _append = append_new if self.append == "scatter" else append_new_dus
+        kv = self.table_layout == "kv"
         S = 1 << self.table_log2
         tiered = self._store is not None
         if tiered:
@@ -839,7 +866,7 @@ class ResidentSearch:
         def make_carry(init_states, init_lo, init_hi, n0, seed_lo, seed_hi):
             # Tables are allocated in-trace: a fresh search per dispatch, and
             # no host-side zero-fill and host-to-device copy.
-            if self.table_layout == "kv":
+            if kv:
                 t_lo = jnp.zeros(2 * S, dtype=jnp.uint32)  # the kv array
                 t_hi = jnp.zeros(0, dtype=jnp.uint32)  # placeholder
             else:
@@ -1253,8 +1280,9 @@ class ResidentSearch:
                 tmd,
                 self._dyn_dev,
             )
-            with self._tracer.span("resident.search", cat="engine"):
-                exe = self._search_executable(args)
+            with self._tracer.span("resident.search", cat="engine") as span:
+                exe, cached = self._search_executable(args)
+                span.set(cached=int(cached))
                 with self._stages.stage("execute"):
                     t_lo, t_hi, p_lo, p_hi, summary, tm_rows = exe(*(
                         a for i, a in enumerate(args)
@@ -1470,17 +1498,21 @@ class ResidentSearch:
         )
 
     def _search_executable(self, args: tuple):
-        """The compiled whole-search program (`jit_search`) for `args`,
-        built in stages (`Stages.executable`); kept per static arguments
-        and operand-table shapes, which is what jit's own cache would key
-        on here."""
+        """`(compiled, cached)`: the whole-search program (`jit_search`) for
+        `args`, built in stages (`Stages.executable`); kept per static
+        arguments, operand-table shapes and the seed rows' device, which is
+        what jit's own cache would key on here, and shared across engines of
+        one definition and configuration."""
         dyn = args[-1]
         key = (
             tuple(args[i] for i in _SEARCH_STATIC),
             jax.tree.structure(dyn),
             tuple((x.shape, x.dtype) for x in jax.tree.leaves(dyn)),
+            args[0].sharding,
         )
-        return self._stages.executable(self._kernel, args, key)
+        return self._stages.executable(
+            self._kernel, args, key, self._definition
+        )
 
     def telemetry_summary(self) -> Optional[dict]:
         """Step-telemetry digest (obs/ring.py; None with telemetry off) —

@@ -31,3 +31,19 @@ try:
     # reproduced both). The in-process multi-kernel suite compiles fresh.
 except ImportError:  # host-only test environments
     pass
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def fresh_program_caches():
+    """Each test starts with none of the compiled programs this process
+    shares across checks (stateright_tpu/compile_cache.py), and leaves
+    none behind: what a test reads of a trace, a lowering or a load does
+    not depend on which tests its worker ran before it."""
+    from stateright_tpu.compile_cache import clear_program_caches
+
+    clear_program_caches()
+    yield
+    clear_program_caches()

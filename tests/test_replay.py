@@ -1,9 +1,10 @@
 """Path replay (tensor/frontier.py replay_fp_chain): one compiled
 expand-and-fingerprint program per transition must rebuild exactly the
-path the op-by-op replay did, trace once per model instance, and keep the
-model collectable."""
+path the op-by-op replay did, trace once per model definition (once per
+instance of a model that names none), and keep the model collectable."""
 
 import gc
+import weakref
 
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +19,7 @@ from stateright_tpu.tensor.frontier import (
     replay_traces,
     state_fingerprint,
 )
+from stateright_tpu.tensor.models import TensorTwoPhaseSys
 from stateright_tpu.tensor.resident import ResidentSearch
 
 
@@ -124,7 +126,8 @@ def test_compiled_replay_matches_eager_replay(build):
 
 
 def test_replay_traces_once_per_model_instance():
-    from stateright_tpu.tensor.models import TensorTwoPhaseSys
+    """The replay traces at most once per model instance: once per model
+    definition, so a second instance of one definition traces nothing."""
 
     def check(model):
         c = model.checker().spawn_tpu(batch_size=512, table_log2=14).join()
@@ -137,7 +140,7 @@ def test_replay_traces_once_per_model_instance():
     assert m1["replay_steps_total"] == sum(len(p) - 1 for p in paths.values())
     model2 = TensorTwoPhaseSys(4)
     m2, paths2 = check(model2)
-    assert m2["replay_traces_total"] == 1
+    assert m2["replay_traces_total"] == 0
     assert {k: p.into_pairs() for k, p in paths2.items()} == {
         k: p.into_pairs() for k, p in paths.items()}
 
@@ -154,15 +157,29 @@ def test_replay_traces_once_per_model_instance():
     assert replay_traces(model2) == traces
 
 
-def test_replay_programs_do_not_keep_the_model_alive():
-    from stateright_tpu.tensor.models import TensorTwoPhaseSys
+class _Unnamed(TensorTwoPhaseSys):
+    """A 2PC model that names no definition (a subclass that does not
+    override `definition_key()`): its replay programs are its own."""
 
+
+def test_replay_programs_do_not_keep_the_model_alive():
+    def replay_init(model):
+        init = np.asarray(model.init_states(), dtype=np.uint32)
+        lo, hi = state_fingerprint(model, jnp.asarray(init))
+        return replay_fp_chain(model, [int(pack_fp(np.asarray(lo),
+                                                   np.asarray(hi))[0])])
+
+    # A named definition's programs outlive the instance that traced them.
     model = TensorTwoPhaseSys(3)
-    init = np.asarray(model.init_states(), dtype=np.uint32)
-    lo, hi = state_fingerprint(model, jnp.asarray(init))
-    path = replay_fp_chain(model, [int(pack_fp(np.asarray(lo),
-                                               np.asarray(hi))[0])])
-    assert len(path) == 1
+    assert len(replay_init(model)) == 1
+    ref = weakref.ref(model)
+    del model
+    gc.collect()
+    assert ref() is None
+    assert len(replay_init(TensorTwoPhaseSys(3))) == 1
+    # An unnamed model's programs go with it.
+    model = _Unnamed(3)
+    assert len(replay_init(model)) == 1
     key = id(model)
     assert key in _REPLAYS
     del model

@@ -236,6 +236,39 @@ def test_http_front_end_round_trip(svc):
         srv.shutdown()
 
 
+class _Unnamed(TensorTwoPhaseSys):
+    """A 2PC model that names no definition: its group is its instance."""
+
+
+def test_equal_models_share_one_group(svc):
+    """Jobs of equal models share one group (one compiled step, one batch)
+    whichever instance each was submitted with, as the registry builds one
+    per submission; each instance of a model that names no definition has
+    its own group."""
+    from stateright_tpu.service.scheduler import group_key
+
+    groups = svc._engine.groups
+    before = set(groups)
+    reg = ModelRegistry()
+    models = [TensorTwoPhaseSys(3), TensorTwoPhaseSys(3),
+              reg.get("2pc", {"n": 3}), reg.get("2pc", {"n": 3})]
+    assert len({id(m) for m in models}) == 4
+    handles = [svc.submit(m) for m in models]
+    svc.drain(timeout=300)
+    for h in handles:
+        r = h.result()
+        assert (r.state_count, r.unique_state_count) == GOLD_2PC3
+    assert {group_key(m) for m in models} == {group_key(M3)}
+    assert set(groups) - before <= {group_key(M3)}
+    unnamed = [_Unnamed(3), _Unnamed(3)]
+    assert group_key(unnamed[0]) != group_key(unnamed[1])
+    handles = [svc.submit(m) for m in unnamed]
+    svc.drain(timeout=300)
+    assert [(h.result().state_count, h.result().unique_state_count)
+            for h in handles] == [GOLD_2PC3, GOLD_2PC3]
+    assert {group_key(m) for m in unnamed} <= set(groups)
+
+
 # -- tiered store shared across jobs -------------------------------------------
 
 
