@@ -88,7 +88,7 @@ def main() -> int:
         # (the carry gather is a collective); only process 0 writes.
         from jax.experimental import multihost_utils
 
-        from stateright_tpu.tensor.resident import _ckpt_path
+        from stateright_tpu.tensor.search_host import _ckpt_path
 
         r = search.run(budget=6, max_steps=6)  # suspend mid-search
         search.checkpoint(args.checkpoint)

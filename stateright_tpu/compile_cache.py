@@ -5,7 +5,7 @@
   suite keeps it off (tests/conftest.py).
 - The process's own cache of compiled programs (`ProgramCache`): the whole
   search of the resident and sharded engines (`EXECUTABLES`, filled by
-  `tensor/resident.py` `Stages`) and the path replay's programs
+  `tensor/search_host.py` `Stages`) and the path replay's programs
   (`REPLAYS`, `tensor/frontier.py`), kept per model definition
   (`TensorModel.definition_key()`) and engine configuration, so a new model
   instance of one definition reuses what an earlier check traced, lowered

@@ -35,7 +35,7 @@ DETAIL_KEYS = {
     # telemetry spine (obs/ring.py `StepRing.summary`)
     "telemetry": "step-telemetry digest sub-dict (TELEMETRY_KEYS)",
     # the resident and sharded engines' whole-search call, staged
-    # (tensor/resident.py Stages)
+    # (tensor/search_host.py Stages)
     "phases": "per-stage wall seconds of the search call sub-dict "
               "(PHASES_DETAIL_KEYS)",
     # chaos plane + supervisor (stateright_tpu/faults/)
@@ -212,7 +212,7 @@ TELEMETRY_KEYS = {
 }
 
 
-#: Keys of `detail["phases"]` (tensor/resident.py `Stages`, the whole-search
+#: Keys of `detail["phases"]` (tensor/search_host.py `Stages`, the whole-search
 #: path of the resident and the sharded engines): wall seconds of the stages
 #: jit runs for the search program in this run, each timed always and
 #: spanned as `<engine>.<stage>` inside `<engine>.search` (`resident` or
@@ -228,7 +228,7 @@ PHASES_DETAIL_KEYS = {
 }
 
 
-#: Counters of `Stages.totals` (tensor/resident.py), reported by the
+#: Counters of `Stages.totals` (tensor/search_host.py), reported by the
 #: `resident` and `sharded` registry sources through the engines'
 #: `metrics()`: the stages' seconds over the engine's runs, and how its
 #: whole-search calls found their executable.

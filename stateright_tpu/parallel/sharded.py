@@ -54,12 +54,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..faults.ckptio import fenced_savez, load_latest
 from ..faults.plan import maybe_fault
-from ..knobs import INSERT_VARIANTS, STORE_KINDS, WARM_KINDS
-from ..obs import N_COLS, REGISTRY, StepRing, as_tracer
+from ..knobs import WARM_KINDS
+from ..obs import N_COLS
 from ..store import warm as warm_seam
-from ..tensor.fingerprint import pack_fp
 from ..core.discovery import HasDiscoveries
 from ..core.model import Expectation
 from ..tensor.frontier import (
@@ -70,22 +68,22 @@ from ..tensor.frontier import (
     count_add,
     count_ge,
     pop_batch,
-    reconstruct_path,
     record_discovery as _record_impl,
-    seed_init,
     state_fingerprint,
 )
-from ..tensor.inserts import check_table_log2, resolve_insert
+from ..tensor.inserts import resolve_insert
 from ..tensor.model import TensorModel
-from ..tensor.resident import (
+from ..tensor.search_host import (
     ABORT_QUEUE,
     ABORT_TABLE,
     EXIT_SERVICE,
-    Stages,
+    SearchHost,
     _compact_queue,
     _finish_masks,
     _inject_rows,
     _resolve_chunking,
+    summary_fields,
+    to_host,
 )
 
 # Per-shard service kernels: the single-device queue compaction / suspect
@@ -106,33 +104,6 @@ def _service_kernels():
 # all-to-all send buffer's per-destination capacity overflowed — wants a
 # fresh run with a larger dest_capacity, not a table regrow.
 ABORT_ROUTE = 8
-
-
-def _host(x):
-    """Device-to-host transfer that also works in multi-process runs.
-
-    Single-process (all shards addressable): plain `np.asarray`. Under
-    `jax.distributed.initialize()` the kernel outputs are sharded across
-    hosts, so each process first all-gathers the shards it cannot address
-    (`process_allgather(tiled=True)` reassembles the global array on every
-    host). This is what lets `ShardedSearch.run()` return identical global
-    `SearchResult`s on every participating process with no engine changes —
-    the multi-host twin of the reference's spawn-per-host aggregation
-    (ref: src/job_market.rs:149-176 is single-machine; cross-machine the
-    reference has no built-in story at all).
-
-    Accepts a pytree and gathers it with ONE `process_allgather` dispatch —
-    callers batch related outputs into a single `_host` call so multi-host
-    epilogues pay one DCN round-trip, not one per array."""
-    leaves = jax.tree.leaves(x)
-    if any(
-        isinstance(leaf, jax.Array) and not leaf.is_fully_addressable
-        for leaf in leaves
-    ):
-        from jax.experimental import multihost_utils
-
-        x = multihost_utils.process_allgather(x, tiled=True)
-    return jax.tree.map(np.asarray, x)
 
 
 def make_mesh(n_devices: Optional[int] = None, axis: str = "d") -> Mesh:
@@ -190,8 +161,13 @@ class _Carry(NamedTuple):
     tm_rows: jnp.ndarray  # uint32[TMR, N_COLS] per-shard in-carry metrics ring
 
 
-class ShardedSearch:
-    """Whole-search multi-chip engine for a `TensorModel` over a 1-D mesh."""
+class ShardedSearch(SearchHost):
+    """Whole-search multi-chip engine for a `TensorModel` over a 1-D mesh;
+    its host side (result, paths, dump, checkpoint) is `SearchHost`'s over
+    the chip axis."""
+
+    _CKPT_SHARD_AXIS = True
+    _carry_type = _Carry
 
     # Warm-knob registry pins (knobs.check_registry): the kind vocabulary
     # and the mechanics both alias the ONE seam, never a local copy.
@@ -236,7 +212,6 @@ class ShardedSearch:
         columns and tracks per-shard claims for the imbalance digest in
         `SearchResult.detail["telemetry"]`). `tracer` records host phases
         as Chrome trace events."""
-        self.model = model
         self.donate_chunks = donate_chunks
         self.mesh = mesh if mesh is not None else make_mesh()
         (self.axis,) = self.mesh.axis_names
@@ -244,29 +219,12 @@ class ShardedSearch:
         self.append = resolve_append(
             append, self.mesh.devices.flat[0].platform
         )
-        self.batch_size = batch_size
-        self.table_log2 = table_log2
-        # insert_variant: the same visited-set designs the single-device
-        # engines race (tensor/inserts.py is THE dispatch table; the
-        # per-shard table layout is always split here).
-        if insert_variant not in INSERT_VARIANTS:  # knob universe: knobs.py
-            raise ValueError(
-                f"insert_variant must be one of {INSERT_VARIANTS}, "
-                f"got {insert_variant!r}"
-            )
-        check_table_log2(insert_variant, table_log2)  # per-shard tiling guard
-        self.insert_variant = insert_variant
-        if store not in STORE_KINDS:  # knob universe: knobs.py
-            raise ValueError(f"store must be one of {STORE_KINDS}, got {store!r}")
         if store == "tiered" and jax.process_count() > 1:
             raise NotImplementedError(
                 "store='tiered' on the sharded engine requires a "
                 "single-process mesh (the host service must address every "
                 "shard's carry)"
             )
-        self.store = store
-        self._store_args = (high_water, low_water, summary_log2)
-        self._stores = None  # rank-local TieredStore per shard (tiered only)
         # Per-destination all-to-all capacity (see module docstring): default
         # 2x the binomial mean + 64 slack, tile-rounded, capped at the
         # absolute bound K*A. Overflow is detected and surfaced as a
@@ -278,37 +236,22 @@ class ShardedSearch:
             if dest_capacity is not None
             else min(ka, -(-(2 * mean + 64) // 128) * 128)
         )
-        if store == "tiered":
-            self._fresh_stores()
-            # Per-shard per-step claims are bounded by the all-to-all
-            # receive width N*C; the spill trigger keeps that much headroom
-            # (eviction only runs between dispatches).
-            nc = self.n_chips * self.dest_capacity
-            self._spill_trigger = min(
-                self._stores[0].high_slots, (1 << table_log2) - nc
-            )
-            if self._spill_trigger <= self._stores[0].low_slots:
-                raise ValueError(
-                    "per-shard table too small for tiered spilling: table "
-                    f"2^{table_log2} minus one receive batch ({nc}) leaves "
-                    "no room above the low-water mark "
-                    f"({self._stores[0].low_slots} slots); raise table_log2 "
-                    "or lower batch_size/dest_capacity/low_water"
-                )
-            self._SQ = 3 * nc
-        else:
-            self._spill_trigger = 0
-            self._SQ = 0
-        # Per-shard telemetry ring capacity (0 compiles the kernels without
-        # the in-carry ring — the bench A/B knob).
-        self._TMR = (1 << telemetry_log2) if telemetry else 0
-        self._ring = StepRing(self._TMR) if telemetry else None
-        self._tracer = as_tracer(tracer)
-        # The whole-search call in jit's stages, each timed and spanned
-        # (`sharded.trace/lower/compile/execute`), and the engine's path and
-        # exchange counters (metrics()). `_exchange_mark` is the (steps,
-        # generated) point up to which the exchange is counted.
-        self._stages = Stages("sharded", self._tracer)
+        self._stores = None  # rank-local TieredStore per shard (tiered only)
+        # insert_variant: the same visited-set designs the single-device
+        # engines race (the per-shard table layout is always split here).
+        # A shard's per-step claims are bounded by the all-to-all receive
+        # width N*C. The calibration comparator prices ONE shard's lockstep
+        # step (per-shard batch/table — every shard dispatches the same
+        # program).
+        self._init_host(
+            "sharded", model, batch_size, table_log2, insert_variant, store,
+            (high_water, low_water, summary_log2), telemetry, telemetry_log2,
+            tracer, step_claims=self.n_chips * self.dest_capacity,
+        )
+        # The exchange counters; `_exchange_mark` is the (steps, generated)
+        # point up to which the exchange is counted.
+        self._counters.update(exchange_rows_total=0, exchange_slots_total=0)
+        self._exchange_mark = None
         # The model's definition and every argument `_build` reads, the
         # mesh's devices included: engines that agree on it share one
         # search executable (Stages.executable).
@@ -319,71 +262,7 @@ class ShardedSearch:
             telemetry, telemetry_log2, donate_chunks, self.axis,
             tuple(d.id for d in self.mesh.devices.flat),
         )
-        self._counters = {
-            "parent_map_entries": 0,
-            "replay_steps_total": 0,
-            "replay_traces_total": 0,
-            "exchange_rows_total": 0,
-            "exchange_slots_total": 0,
-        }
-        self._exchange_mark = None
-        self._metrics_name = REGISTRY.register("sharded", self.metrics)
-        # Calibration comparator (obs/calib.py): prices ONE shard's
-        # lockstep step (per-shard batch/table — every shard dispatches the
-        # same program) and consumes the already-synced ring drains below.
-        self._calib = None
-        if telemetry:
-            # Lazy import: obs.calib prices through tensor.costmodel, so a
-            # module-level import would cycle when obs loads first.
-            from ..obs.calib import CalibConfig, Comparator, calib_enabled
-            from ..tensor.costmodel import ENGINE_VARIANTS
-
-        if telemetry and calib_enabled():
-            self._calib = Comparator(CalibConfig(
-                engine="sharded",
-                variant=ENGINE_VARIANTS.get(
-                    ("split", insert_variant), "split"
-                ),
-                lanes=model.lanes,
-                max_actions=model.max_actions,
-                batch=batch_size,
-                table_log2=table_log2,
-                spill=(store == "tiered"),
-            ))
-            REGISTRY.register("calib", self._calib.metrics)
-        self.props = model.properties()
         self._kernel, self._seed_k, self._chunk_k = self._build()
-        self._last_tables = None
-        self._parent_map = None
-        self._seed = None
-        # Suspended-search carry (chunked runs only): retained across run()
-        # calls so budget/timeout suspensions and overflows are resumable.
-        self._carry = None
-        self._q_compacted = False
-        # Corpus warm start (store/warm.py): replay meta for a complete
-        # entry, plus the kind/count surfaced in SearchResult.detail.
-        self._warm = None
-        self._warm_states = 0
-        self._warm_kind = None
-        self._warm_summary_pending = False
-
-    def _fresh_stores(self) -> None:
-        """(Re)build the rank-local spill tiers, one per shard."""
-        from ..store.tiered import TieredConfig, TieredStore
-
-        if self._stores is not None:
-            for s in self._stores:
-                s.close()  # stop the old spill tiers' compactors
-        high_water, low_water, summary_log2 = self._store_args
-        cfg = TieredConfig(
-            high_water=high_water,
-            low_water=low_water,
-            summary_log2=summary_log2,
-        )
-        self._stores = [
-            TieredStore(1 << self.table_log2, cfg)
-            for _ in range(self.n_chips)
-        ]
 
     def store_stats(self) -> Optional[dict]:
         """Aggregated per-tier counters across shards (None with the plain
@@ -1068,8 +947,6 @@ class ShardedSearch:
         """Host-build the suspended per-shard carry for a partial-entry
         continuation (the `load_checkpoint` recipe, sourced from a corpus
         frontier snapshot instead of a checkpoint archive)."""
-        from jax.sharding import NamedSharding
-
         N_ = self.n_chips
         Q = self._Q
         S = 1 << self.table_log2
@@ -1155,13 +1032,7 @@ class ShardedSearch:
             "summary": np.stack([s.summary_np for s in self._stores]),
             "tm_rows": np.zeros((N_, self._TMR, N_COLS), np.uint32),
         }
-        sh = NamedSharding(self.mesh, P(self.axis))
-        self._carry = _Carry(
-            **{
-                f_: jax.device_put(jnp.asarray(v), sh)
-                for f_, v in fields.items()
-            }
-        )
+        self._set_carry(fields)
         self._exchange_mark = (0, sc)
         # Queue rows no longer cover every unique state (the prefix lives
         # in the spill tiers) — dump_states must decline.
@@ -1190,46 +1061,12 @@ class ShardedSearch:
         chunked, budget = _resolve_chunking(
             budget, timeout, progress, self._carry
         )
-        model = self.model
-        K = self.batch_size
         start = time.monotonic()
-        self._parent_map = None
-        if self._ring is not None and self._carry is None and self._ring.steps:
-            # Fresh search (no suspended carry): telemetry starts over too.
-            self._ring = self._ring.fresh()
-
-        # seed_init is deterministic per model; cache its padded host form so
-        # resumed runs skip the host expansion/fingerprint work entirely.
-        if self._seed is None:
-            init, init_lo, init_hi, n_raw = seed_init(model)
-            if len(init) > K:
-                raise ValueError(
-                    "more init states than batch_size; raise batch_size"
-                )
-            n0 = len(init)
-            st = np.zeros((K, model.lanes), dtype=np.uint32)
-            st[:n0] = init
-            lo = np.zeros(K, dtype=np.uint32)
-            lo[:n0] = init_lo
-            hi = np.zeros(K, dtype=np.uint32)
-            hi[:n0] = init_hi
-            active = np.arange(K) < n0
-            self._seed = (st, lo, hi, active, n0, n_raw)
-        st, lo, hi, active, n0, n_raw = self._seed
-
-        if finish_when.matches(self.props, set()) or not self.props:
-            # Vacuous finish policy: stop before exploring (bfs.rs:278-280).
-            z = np.zeros((self.n_chips, 1 << self.table_log2), dtype=np.uint32)
-            self._last_tables = (z, z, z, z)
-            return SearchResult(
-                state_count=n_raw,
-                unique_state_count=n0,
-                max_depth=1 if n0 else 0,
-                discoveries={},
-                complete=False,
-                duration=time.monotonic() - start,
-                steps=0,
-            )
+        self._begin_run()
+        (st, lo, hi, active), n0, n_raw = self._seed_rows()
+        vacuous = self._vacuous_result(finish_when, n0, n_raw, start)
+        if vacuous is not None:
+            return vacuous
 
         required_mask, any_mask = _finish_masks(finish_when, self.props)
         target = int(target_state_count or 0)
@@ -1239,7 +1076,6 @@ class ShardedSearch:
             jnp.uint32(n_raw >> 32),
         )
 
-        self._stages.new_run()
         if not chunked:
             # Chaos-plane boundary (faults/plan.py): faults land before the
             # dispatch, never mid-update.
@@ -1274,18 +1110,12 @@ class ShardedSearch:
                         gen_lo, gen_hi, unique_counts, max_depths,
                         discovered, disc_lo, disc_hi, drained, overflow,
                         steps, tm_rows,
-                    ) = _host(tuple(outs))
-            if self._ring is not None:
-                # Whole-search dispatch: one bulk drain of every shard's
-                # ring. The window is the execute stage alone: no tracing,
-                # lowering or executable load.
-                w_us = self._stages.phases["execute_s"] * 1e6
-                self._ring.drain_sharded(tm_rows, int(steps.max()),
-                                         window_us=w_us)
-                if self._calib is not None:
-                    self._calib.observe(
-                        self._ring.steps, w_us, self._ring.generated_total
-                    )
+                    ) = to_host(tuple(outs))
+            # Whole-search dispatch: one bulk drain of every shard's ring.
+            # The window is the execute stage alone: no tracing, lowering or
+            # executable load.
+            self._drain_ring(tm_rows, int(steps.max()),
+                             self._stages.phases["execute_s"] * 1e6)
             if bool(overflow.any()):
                 # A previous run's snapshot must not silently serve paths
                 # for states this failed run discovered.
@@ -1296,14 +1126,18 @@ class ShardedSearch:
                     "checkpoint-then-raise)"
                 )
             self._last_tables = (t_lo, t_hi, p_lo, p_hi)
-            state_count = int(gen_lo[0]) | (int(gen_hi[0]) << 32)
-            disc_mask = int(discovered[0])
-            # disc_lo/disc_hi: [N, P]
-            result_max_depth = int(max_depths.max())
-            result_steps = int(steps.max())
-            complete = bool(drained.all())
+            counts = dict(
+                gen=int(gen_lo[0]) | (int(gen_hi[0]) << 32),
+                unique=unique_counts,
+                max_depth=max_depths,
+                steps=steps,
+                discovered=int(discovered[0]),
+                disc_lo=disc_lo,  # [N, P]
+                disc_hi=disc_hi,
+                complete=bool(drained.all()),
+            )
             self._exchange_mark = (0, n_raw)
-            self._count_exchange(result_steps, state_count)
+            self._count_exchange(int(steps.max()), counts["gen"])
         else:
             if self._carry is None:
                 self._carry = self._seed_k(
@@ -1347,19 +1181,9 @@ class ShardedSearch:
                         self._carry, req, anym, *t32, tmd,
                         jnp.int32(budget), jnp.int32(max_steps),
                     )
-                    s = _host(summary)  # [N, 12 + 2*max(P,1)] — one transfer
-                if self._ring is not None:
-                    # The chunk already synced (summary gather); the ring
-                    # drain is one more bulk copy, never a per-step sync.
-                    w_us = (time.monotonic() - t_chunk0) * 1e6
-                    self._ring.drain_sharded(_host(carry.tm_rows),
-                                             int(s[:, 8].max()),
-                                             window_us=w_us)
-                    if self._calib is not None:
-                        self._calib.observe(
-                            self._ring.steps, w_us,
-                            self._ring.generated_total,
-                        )
+                    s = to_host(summary)  # [N, 12 + 2*max(P,1)] — one transfer
+                self._drain_ring(carry.tm_rows, int(s[:, 8].max()),
+                                 (time.monotonic() - t_chunk0) * 1e6)
                 codes = s[:, 7].astype(np.uint32)
                 fatal = (codes & (ABORT_TABLE | ABORT_QUEUE | ABORT_ROUTE)).any()
                 if not fatal:  # the chunk's carry is kept: count its steps
@@ -1389,12 +1213,7 @@ class ShardedSearch:
                     # chunk boundary for checkpoint+regrow. Refresh the
                     # table snapshot to that boundary so reconstruct_path
                     # serves THIS run's states (not a stale prior run's).
-                    self._last_tables = _host((
-                        self._carry.t_lo,
-                        self._carry.t_hi,
-                        self._carry.p_lo,
-                        self._carry.p_hi,
-                    ))
+                    self._last_tables = to_host(self._carry_tables())
                     self._parent_map = None
                     raise RuntimeError(
                         "sharded search overflow; the carry was kept at the "
@@ -1440,117 +1259,17 @@ class ShardedSearch:
                     if timed:
                         timed_out = True
                         break
-            self._last_tables = _host((
-                self._carry.t_lo,
-                self._carry.t_hi,
-                self._carry.p_lo,
-                self._carry.p_hi,
-            ))
-            P_ = max(len(self.props), 1)
-            state_count = int(s[0, 0]) | (int(s[0, 1]) << 32)
-            disc_mask = int(s[0, 4])
-            disc_lo = s[:, 12 : 12 + P_]
-            disc_hi = s[:, 12 + P_ : 12 + 2 * P_]
-            unique_counts = s[:, 2]
-            result_max_depth = int(s[:, 3].max())
-            result_steps = int(s[:, 8].max())
-            complete = bool((s[:, 5] >= s[:, 6]).all()) and not timed_out
-
-        discoveries = {}
-        for i, p in enumerate(self.props):
-            if disc_mask & (1 << i):
-                witnesses = pack_fp(
-                    disc_lo[:, i].astype(np.uint32),
-                    disc_hi[:, i].astype(np.uint32),
-                )
-                witnesses = witnesses[witnesses != 0]
-                discoveries[p.name] = int(witnesses[0])
-        unique_total = int(unique_counts.sum())
-        if self._warm is not None and complete:
-            # Complete-entry replay: the drain above only proves the seed
-            # re-closes against the preloaded set; the published result is
-            # the result (can_replay guarantees the cold run would match).
-            m = self._warm
-            state_count = int(m.get("state_count", state_count))
-            unique_total = int(m.get("unique_count", unique_total))
-            result_max_depth = int(m.get("max_depth", result_max_depth))
-            discoveries = {
-                k: int(v) for k, v in m.get("discoveries", {}).items()
-            }
-        if self._calib is not None:
-            self._calib.finish()
-            if self._calib.chunks:
-                self._calib.flush_records()
-        return SearchResult(
-            state_count=state_count,
-            unique_state_count=unique_total,
-            max_depth=result_max_depth,
-            discoveries=discoveries,
-            complete=complete,
-            duration=time.monotonic() - start,
-            steps=result_steps,
-            detail={
-                # fp-sharding balance evidence (task: per-chip spread).
-                "per_chip_unique": [int(x) for x in unique_counts],
-                **(
-                    {
-                        "corpus": {
-                            "warm_start": True,
-                            "preloaded_states": self._warm_states,
-                            "warm_kind": self._warm_kind,
-                        }
-                    }
-                    if self._warm_kind is not None
-                    else {}
-                ),
-                **(self.store_stats() or {}),
-                **(
-                    {"telemetry": self.telemetry_summary()}
-                    if self._ring is not None
-                    else {}
-                ),
-                **(
-                    {"calib": self._calib.detail()}
-                    if self._calib is not None and self._calib.chunks
-                    else {}
-                ),
-                **({} if chunked else {"phases": dict(self._stages.phases)}),
-            },
-        )
-
-    def telemetry_summary(self) -> Optional[dict]:
-        """Cross-shard step-telemetry digest (obs/ring.py; None with
-        telemetry off) — includes the per-shard claim imbalance."""
-        if self._ring is None:
-            return None
-        # table_claims drains as the MAX across shards, so the fill digest
-        # is the hottest shard's fill against the PER-SHARD table size (the
-        # store_stats()["hot_fill"] convention); active lanes SUM across
-        # shards, so utilization is against the mesh-wide batch.
-        return self._ring.summary(
-            1 << self.table_log2, self.n_chips * self.batch_size
+            self._last_tables = to_host(self._carry_tables())
+            counts = summary_fields(s, len(self.props), timed_out)
+        return self._result(
+            start, chunked,
+            # fp-sharding balance evidence (task: per-chip spread).
+            extra={"per_chip_unique": [int(x) for x in counts["unique"]]},
+            **counts,
         )
 
     def metrics(self) -> dict:
-        """Flat counter snapshot for the obs registry / Prometheus export
-        (host-side values only — a scrape never syncs the mesh)."""
-        out: dict = {
-            "n_chips": self.n_chips,
-            **self._stages.totals,
-            **self._counters,
-        }
-        if self._ring is not None:
-            out.update(
-                steps=self._ring.steps,
-                generated_states=self._ring.generated_total,
-                claimed_states=self._ring.claimed_total,
-            )
-        stats = self.store_stats()
-        if stats:
-            # Non-numeric leaves (the store's kind string) are dropped by
-            # the Prometheus renderer's flatten step.
-            out["store"] = stats
-        return out
+        return {"n_chips": self.n_chips, **super().metrics()}
 
     def _count_exchange(self, steps: int, generated: int) -> None:
         """Add the exchange since `_exchange_mark` to the counters: every
@@ -1718,132 +1437,47 @@ class ShardedSearch:
         return c._replace(**upd)
 
     def reset(self) -> None:
-        """Drop any suspended carry so the next `run()` starts fresh."""
-        self._carry = None
-        self._parent_map = None
-        self._last_tables = None
-        self._q_compacted = False
+        super().reset()
         self._exchange_mark = None
         self._warm = None
         self._warm_states = 0
         self._warm_kind = None
         self._warm_summary_pending = False
-        if self._ring is not None:
-            self._ring = self._ring.fresh()  # telemetry starts over too
-        if self._stores is not None:
-            self._fresh_stores()  # spill tiers + summaries start empty
 
-    def dump_states(
-        self, decode: bool = True, evaluated_only: bool = False,
-        raw: bool = False, start: int = 0,
-    ) -> list:
-        """Batched state dump across all shards: each chip's frontier queue
-        rows [0, tail) are exactly the unique states that chip owns (every
-        unique state is enqueued on its owner chip once), so the union over
-        shards is the global unique state set. Device analogue of the
-        reference's `StateRecorder` (ref: src/checker/visitor.rs:75-111).
-        Requires a chunked run, which retains the per-shard carry.
-        `evaluated_only` restricts to popped rows ([0, head) per shard)."""
-        if self._carry is None:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "no retained carry to dump: run with budget=... (chunked "
-                "dispatch) before dump_states()"
-            )
-        if self._q_compacted:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "dump_states is unavailable once the tiered store has "
-                "compacted a shard's frontier queue (rows [0, tail) no "
-                "longer cover every unique state) — use store='device' for "
-                "exact state-set dumps"
-            )
-        q, ends = _host((
+    # -- the engine's side of SearchHost ---------------------------------------
+    # The restore mesh must have the checkpoint's chip count (the fp->owner
+    # map depends on it).
+
+    def _seed_operands(self, st, lo, hi, n0):
+        # Host arrays, replicated to every chip at each dispatch.
+        return st, lo, hi, np.arange(self.batch_size) < n0
+
+    def _queue_rows(self, evaluated_only: bool, start: int) -> list:
+        q, ends = to_host((
             self._carry.q_states,  # [N, Q, L]
             self._carry.head if evaluated_only else self._carry.tail,
         ))
-        if raw:
-            # Bulk uint32[n, lanes] union over shards (see the resident
-            # engine's raw form: refine_check's vectorized poison scan).
-            if start and self.n_chips > 1:
-                # A flat index into the concatenation is NOT stable across
-                # runs: when a non-last shard appends, every later shard's
-                # rows shift. Incremental scanning would need per-shard
-                # marks; no caller does this today (refine_check passes
-                # start=0 for the sharded engine).
-                raise ValueError(
-                    "start > 0 is unsupported for multi-shard raw dumps "
-                    "(per-shard appends shift the concatenated indices)"
-                )
-            out = np.concatenate(
-                [q[i, : int(ends[i])] for i in range(self.n_chips)]
-            ) if self.n_chips else q[:0, 0]
-            return out[start:]
-        out = []
-        for i in range(self.n_chips):
-            for r in q[i, : int(ends[i])]:
-                out.append(
-                    self.model.decode(r)
-                    if decode
-                    else tuple(int(x) for x in r)
-                )
-        return out
+        return [q[i, start : int(ends[i])] for i in range(self.n_chips)]
 
-    # -- checkpoint / resume ---------------------------------------------------
-    # SURVEY.md §5: per-shard carry dump. Only chunked runs (budget=...)
-    # keep a carry to dump; the restore mesh must have the same chip count
-    # (the fp→owner map depends on it).
+    def _checkpoint_arrays(self, path: str) -> Optional[dict]:
+        # Multi-process runs: EVERY rank must call checkpoint() (the carry
+        # gather is a collective), but only process 0 writes the file — N
+        # ranks writing the same path on a shared filesystem would corrupt
+        # the archive. For resume, `path` must be readable by every rank
+        # (shared storage).
+        arrays = to_host(self._carry._asdict())
+        return arrays if jax.process_index() == 0 else None
 
-    def checkpoint(self, path: str) -> None:
-        """Dump the suspended per-shard search carry to `path` (.npz).
+    def _checkpoint_meta(self) -> dict:
+        return {"n_chips": self.n_chips, "dest_capacity": self.dest_capacity}
 
-        Multi-process runs: EVERY rank must call this (the carry gather is a
-        collective), but only process 0 writes the file — N ranks writing
-        the same path on a shared filesystem would corrupt the archive. For
-        resume, `path` must be readable by every rank (shared storage)."""
-        import json
+    def _set_carry(self, fields: dict) -> None:
+        from jax.sharding import NamedSharding
 
-        if self._carry is None:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "nothing to checkpoint: no suspended carry (run with "
-                "budget=... to enable chunked dispatch)"
-            )
-        from ..tensor.resident import _ckpt_path
-
-        c = self._carry
-        arrays = _host(dict(zip(c._fields, c)))
-        if jax.process_index() != 0:
-            return
-        store_meta = None
-        if self._stores is not None:
-            # Rank-local spill tiers ride along, one pair of arrays per
-            # shard (shards spill independently, so lengths differ).
-            store_meta = [s.meta() for s in self._stores]
-            for i, s in enumerate(self._stores):
-                ck = s.to_checkpoint()
-                arrays[f"spill_fps_{i}"] = ck["spill_fps"]
-                arrays[f"spill_parents_{i}"] = ck["spill_parents"]
-        arrays["meta"] = np.frombuffer(
-            json.dumps(
-                {
-                    "lanes": self.model.lanes,
-                    "max_actions": self.model.max_actions,
-                    "properties": [p.name for p in self.props],
-                    "table_log2": self.table_log2,
-                    "batch_size": self.batch_size,
-                    "n_chips": self.n_chips,
-                    "dest_capacity": self.dest_capacity,
-                    "insert_variant": self.insert_variant,
-                    "store": store_meta,
-                    "q_compacted": self._q_compacted,
-                }
-            ).encode(),
-            dtype=np.uint8,
+        sh = NamedSharding(self.mesh, P(self.axis))
+        self._carry = _Carry(
+            **{f: jax.device_put(jnp.asarray(v), sh) for f, v in fields.items()}
         )
-        # Crash-atomic write (tmp+fsync+rename, CRC32 footer, previous
-        # generation kept at `path + ".prev"` — faults/ckptio.py).
-        fenced_savez(_ckpt_path(path), arrays)
 
     @classmethod
     def load_checkpoint(
@@ -1858,38 +1492,13 @@ class ShardedSearch:
         """Rebuild a suspended sharded search. A larger `table_log2` re-hashes
         every shard's visited set into a bigger per-chip table (the recovery
         path for an overflow abort). The next `run()` continues exactly."""
-        import json
-
-        from jax.sharding import NamedSharding
-
-        from ..tensor.resident import _ckpt_path, _regrow, _validate_ckpt_meta
-
-        # CRC-verified; a corrupt current generation falls back to
-        # `path + ".prev"` instead of raising (faults/ckptio.load_latest).
-        data, _src = load_latest(_ckpt_path(path))
-        meta = json.loads(bytes(data["meta"].tobytes()).decode())
-        _validate_ckpt_meta(model, meta)
-        store_meta = meta.get("store")
+        data, meta = cls._read_checkpoint(model, path)
         ss = cls(
             model,
             mesh=mesh,
-            batch_size=batch_size or meta["batch_size"],
-            table_log2=table_log2 or meta["table_log2"],
             dest_capacity=meta["dest_capacity"],
             donate_chunks=donate_chunks,
-            # A pallas/capped run must resume on the same insert design
-            # (table slot layout and at-scale cost both depend on it).
-            insert_variant=meta.get("insert_variant", "sort"),
-            store="tiered" if store_meta else "device",
-            **(
-                {
-                    "high_water": store_meta[0]["high_water"],
-                    "low_water": store_meta[0]["low_water"],
-                    "summary_log2": store_meta[0]["summary_log2"],
-                }
-                if store_meta
-                else {}
-            ),
+            **cls._resume_kwargs(meta, batch_size, table_log2),
         )
         if ss.n_chips != meta["n_chips"]:
             raise ValueError(
@@ -1897,151 +1506,20 @@ class ShardedSearch:
                 f"on {ss.n_chips} is not supported (the fingerprint→owner "
                 "map depends on the chip count)"
             )
-        log2 = table_log2 if table_log2 is not None else meta["table_log2"]
-        if log2 < meta["table_log2"]:
-            raise ValueError("cannot shrink the table on resume")
-        # This engine's compiled kernel closes over the slacked per-shard
-        # capacity Q = S + N*C (+ the tiered suspect-injection slack);
-        # checkpoints from other configs (or the pre-slack format) carry
-        # different queue shapes, so regrow/normalize everything to ss's
-        # capacity.
-        ss_Q = ss._Q
-        N_ = ss.n_chips
-        # Pre-tiered checkpoints lack the suspect-buffer/summary fields;
-        # default them to this engine's (empty) shapes.
-        defaults = {
-            "hot_claims": np.asarray(
-                [(np.asarray(data["t_lo"][i]) != 0).sum() for i in range(N_)],
-                dtype=np.int32,
-            ),
-            "s_states": np.zeros((N_, ss._SQ, model.lanes), np.uint32),
-            "s_lo": np.zeros((N_, ss._SQ), np.uint32),
-            "s_hi": np.zeros((N_, ss._SQ), np.uint32),
-            "s_ebits": np.zeros((N_, ss._SQ), np.uint32),
-            "s_depth": np.zeros((N_, ss._SQ), np.uint32),
-            "s_tail": np.zeros(N_, np.int32),
-            "summary": np.zeros((N_, 1), np.uint32),
-            "tm_rows": np.zeros((N_, ss._TMR, N_COLS), np.uint32),
-        }
-        fields = {
-            f: data[f] if f in data else defaults[f] for f in _Carry._fields
-        }
-        fields["overflow"] = np.asarray(fields["overflow"], np.uint32)
-        # Telemetry ring: observability, not search state — a different ring
-        # size (or pre-obs checkpoint) restores empty, with pre-restore
-        # steps counted as uncaptured.
-        if np.asarray(fields["tm_rows"]).shape != (N_, ss._TMR, N_COLS):
-            fields["tm_rows"] = np.zeros((N_, ss._TMR, N_COLS), np.uint32)
-        if ss._ring is not None:
-            ss._ring.skip_to(int(np.asarray(fields["steps"]).max()))
-        if store_meta:
-            from ..store.tiered import TieredStore
-
-            for s in ss._stores:
-                s.close()  # replaced by the checkpointed tiers
-            ss._stores = [
-                TieredStore.from_checkpoint(
-                    1 << log2, store_meta[i],
-                    data[f"spill_fps_{i}"], data[f"spill_parents_{i}"],
-                )
-                for i in range(N_)
-            ]
-            ss._q_compacted = bool(meta.get("q_compacted", False))
-            # The summary is a pure function of each shard's spilled set —
-            # always use the freshly rebuilt words (covers regrown tables).
-            fields["summary"] = np.stack(
-                [s.summary_np for s in ss._stores]
-            )
-        if log2 != meta["table_log2"]:
-            grown = [
-                _regrow(
-                    model,
-                    {
-                        k: fields[k][i]
-                        for k in (
-                            "t_lo", "t_hi", "p_lo", "p_hi",
-                            "q_states", "q_lo", "q_hi", "q_ebits", "q_depth",
-                        )
-                    },
-                    meta["table_log2"],
-                    log2,
-                    ss.batch_size,
-                    queue_rows=ss_Q,
-                    insert_variant=ss.insert_variant,
-                )
-                for i in range(ss.n_chips)
-            ]
-            for k in grown[0]:
-                fields[k] = np.stack([np.asarray(g[k]) for g in grown])
-            # The overflow that prompted this regrow is resolved by the
-            # bigger tables; a stale flag would re-abort the resumed run.
-            fields["overflow"] = np.zeros(ss.n_chips, dtype=np.uint32)
-            # Bucket residency changed wholesale; recount occupied slots.
-            fields["hot_claims"] = np.asarray(
-                [
-                    (np.asarray(fields["t_lo"][i]) != 0).sum()
-                    for i in range(N_)
-                ],
-                dtype=np.int32,
-            )
-        for f in ("q_states", "q_lo", "q_hi", "q_ebits", "q_depth"):
-            old = fields[f]
-            if old.shape[1] != ss_Q:
-                padded = np.zeros(
-                    (old.shape[0], ss_Q) + old.shape[2:], dtype=old.dtype
-                )
-                keep = min(old.shape[1], ss_Q)
-                padded[:, :keep] = old[:, :keep]
-                fields[f] = padded
+        fields = ss._restore(data, meta)
         # The per-shard queue guard was enforced with the CHECKPOINT's
         # batch size; a larger K here could let pop_batch's dynamic_slice
         # clamp past a shard's restored tail.
         max_tail = int(np.max(fields["tail"]))
-        if max_tail > ss_Q - ss.batch_size:
+        if max_tail > ss._Q - ss.batch_size:
             raise ValueError(
                 "batch_size too large for the restored queue occupancy "
-                f"(max per-shard tail={max_tail}, capacity={ss_Q}); "
+                f"(max per-shard tail={max_tail}, capacity={ss._Q}); "
                 "use a smaller batch_size or a larger table_log2"
             )
-        sh = NamedSharding(ss.mesh, P(ss.axis))
-        ss._carry = _Carry(
-            **{
-                f: jax.device_put(jnp.asarray(v), sh)
-                for f, v in fields.items()
-            }
-        )
+        ss._set_carry(fields)
         ss._exchange_mark = (
             int(np.asarray(fields["steps"]).max()),
             int(fields["gen_lo"][0]) | (int(fields["gen_hi"][0]) << 32),
         )
         return ss
-
-    def reconstruct_path(self, fp: int):
-        """Union the per-chip parent maps (the span `path.parent_map`, one
-        transfer of every shard's table), then reconstruct as usual."""
-        if self._parent_map is None:
-            if self._last_tables is None:
-                # srlint: fault-ok caller-contract guard, not an I/O/device surface
-                raise RuntimeError(
-                    "no table snapshot to reconstruct from: run() has not "
-                    "completed since the last reset/donated overflow"
-                )
-            with self._tracer.span("path.parent_map", cat="path") as span:
-                t_lo, t_hi, p_lo, p_hi = (
-                    x.reshape(-1) for x in _host(self._last_tables)
-                )
-                nz = t_lo != 0
-                keys = pack_fp(t_lo[nz], t_hi[nz])
-                parents = pack_fp(p_lo[nz], p_hi[nz])
-                self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
-                if self._stores is not None:
-                    # Rank-local spill entries win on keys in both tiers
-                    # (the original BFS-discovery parent keeps chains
-                    # acyclic).
-                    for s in self._stores:
-                        self._parent_map.update(s.parent_map())
-                span.set(entries=len(self._parent_map))
-            self._counters["parent_map_entries"] = len(self._parent_map)
-        return reconstruct_path(
-            self.model, self._parent_map, fp, self._tracer, self._counters
-        )

@@ -24,7 +24,6 @@ property has a discovery (src/checker/bfs.rs:278-280), when the configured
 
 from __future__ import annotations
 
-import contextlib
 import time
 from functools import partial
 from typing import NamedTuple, Optional
@@ -34,30 +33,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from ..compile_cache import EXECUTABLES, ProgramCache, trace_context
 from ..core.discovery import HasDiscoveries
 from ..core.model import Expectation
-from ..knobs import (
-    INSERT_VARIANTS,
-    PHASED_VARIANTS,
-    STORE_KINDS,
-    TABLE_LAYOUTS,
-    WARM_KINDS,
-)
-from ..faults.ckptio import fenced_savez, load_latest, normalize_ckpt_path
+from ..knobs import PHASED_VARIANTS, TABLE_LAYOUTS, WARM_KINDS
 from ..faults.plan import maybe_fault
 from ..store import warm as warm_seam
-from ..obs import (
-    N_COLS,
-    PHASES_DETAIL_KEYS,
-    REGISTRY,
-    STAGES_COUNTER_KEYS,
-    StepRing,
-    as_tracer,
-    build_detail,
-)
-from .costmodel import ENGINE_VARIANTS
-from .fingerprint import pack_fp
+from ..obs import N_COLS
 from .frontier import (
     SearchResult,
     append_new,
@@ -66,69 +47,34 @@ from .frontier import (
     count_ge,
     expand_insert,
     pop_batch,
-    reconstruct_path,
     resolve_append,
     record_discovery as _record,
-    seed_init,
 )
-from .hashtable import KV_BUCKET, _insert_impl
-from .inserts import check_table_log2, resolve_insert
+from .inserts import resolve_insert
 from .model import TensorModel
+# The abort codes, `_finish_masks` and `_regrow` are imported from here by
+# callers outside the package too.
+from .search_host import (  # noqa: F401
+    ABORT_QUEUE,
+    ABORT_TABLE,
+    EXIT_SERVICE,
+    SearchHost,
+    _abort_reason,
+    _compact_queue,
+    _finish_masks,
+    _fit_rows,
+    _inject_rows,
+    _regrow,
+    _resolve_chunking,
+    _SUSPECTS,
+    summary_fields,
+    to_host,
+)
 
 
 #: Static arguments of the whole-search program: required mask, any mask
 #: and the step cap (one executable per value, as jit keys them).
 _SEARCH_STATIC = (3, 4, 7)
-
-
-def _finish_masks(finish_when: HasDiscoveries, props) -> tuple[int, int]:
-    """Encode a HasDiscoveries policy as (required_mask, any_mask):
-    stop when (discovered & required) == required != 0, or
-    (discovered & any_mask) != 0."""
-    name_bit = {p.name: 1 << i for i, p in enumerate(props)}
-    failure_bits = sum(
-        1 << i
-        for i, p in enumerate(props)
-        if p.expectation in (Expectation.ALWAYS, Expectation.EVENTUALLY)
-    )
-    all_bits = (1 << len(props)) - 1
-    k = finish_when.kind
-    if k == "all":
-        return all_bits, 0
-    if k == "any":
-        return 0, all_bits
-    if k == "any_failures":
-        return 0, failure_bits
-    if k == "all_failures":
-        return failure_bits, 0
-    if k == "all_of":
-        return sum(name_bit[n] for n in finish_when.names), 0
-    if k == "any_of":
-        return 0, sum(name_bit[n] for n in finish_when.names)
-    raise ValueError(f"unknown HasDiscoveries kind {k!r}")
-
-
-# Abort-code bits carried in _Carry.overflow (uint32): nonzero aborts the
-# loop; the bits name the resource that actually ran out, so overflow
-# recovery (checkpoint + load_checkpoint into bigger arrays) can grow the
-# RIGHT one instead of guessing.
-ABORT_TABLE = 1  # hash-table insert exhausted MAX_ROUNDS (table full)
-ABORT_QUEUE = 2  # frontier queue tail crossed its capacity
-# Non-fatal exit (tiered store only): the loop hands control back to the
-# host — occupancy crossed the spill trigger, the suspect buffer is near
-# capacity, or the queue tail needs compaction. The host services the
-# condition (store/tiered.py) and resumes the same carry; it is never
-# surfaced as an error.
-EXIT_SERVICE = 4
-
-
-def _abort_reason(code: int) -> str:
-    parts = []
-    if code & ABORT_TABLE:
-        parts.append("hash table full (raise table_log2)")
-    if code & ABORT_QUEUE:
-        parts.append("frontier queue full (raise queue_log2)")
-    return " and ".join(parts) if parts else "overflow"
 
 
 class _Carry(NamedTuple):
@@ -165,214 +111,11 @@ class _Carry(NamedTuple):
     tm_rows: jnp.ndarray  # uint32[TMR, N_COLS] in-carry metrics ring
 
 
-def _resolve_chunking(budget, timeout, progress, carry):
-    """Shared run()-entry policy for the chunked engines: validate the
-    budget, decide whether this run is chunked, and default the chunk size
-    (64 steps between wall-clock polls; effectively-unbounded otherwise)."""
-    if budget is not None and budget <= 0:
-        raise ValueError("budget must be a positive step count")
-    chunked = (
-        budget is not None
-        or timeout is not None
-        or progress is not None
-        or carry is not None
-    )
-    if timeout is not None and budget is None:
-        budget = 64  # poll granularity for wall-clock checks
-    if chunked and budget is None:
-        budget = 1 << 20
-    return chunked, budget
+class ResidentSearch(SearchHost):
+    """One-dispatch whole-search engine for a `TensorModel`; its host side
+    (result, paths, dump, checkpoint) is `SearchHost`'s one-shard case."""
 
-
-class Stages:
-    """A whole-search call in the stages jit runs internally, each its own
-    span `<engine>.<stage>`: trace, lower, compile (a backend compile or a
-    load from the persistent cache), then execute. Always timed (two clock
-    reads a stage): `phases` holds this run's seconds (`detail["phases"]`)
-    and `totals` the engine's `<stage>_seconds_total` counters, with
-    `executable_cache_hits_total`/`executable_cache_misses_total` beside
-    them. Shared by the resident and the sharded engines."""
-
-    def __init__(self, engine: str, tracer):
-        self.engine = engine
-        self.tracer = tracer
-        self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
-        self.totals = {
-            k: 0.0 if k.endswith("_seconds_total") else 0
-            for k in STAGES_COUNTER_KEYS
-        }
-        self._executables = ProgramCache(size=None)
-
-    def new_run(self) -> None:
-        self.phases = dict.fromkeys(PHASES_DETAIL_KEYS, 0.0)
-
-    @contextlib.contextmanager
-    def stage(self, stage: str):
-        t0 = time.monotonic()
-        try:
-            with self.tracer.span(f"{self.engine}.{stage}", cat="engine"):
-                yield
-        finally:
-            dt = time.monotonic() - t0
-            self.phases[stage + "_s"] += dt
-            self.totals[stage + "_seconds_total"] += dt
-
-    def executable(self, jitted, args: tuple, key, definition=None):
-        """`(compiled, cached)`: the compiled program of `jitted` for `args`,
-        traced, lowered and compiled once per `key` (what jit's own cache
-        would key on), and whether it came from a cache: the same module
-        and executable as a plain call of `jitted`. With a `definition`
-        (the model's definition key and everything the engine's `_build`
-        reads) the program is shared by every engine of this process
-        (`compile_cache.EXECUTABLES`), under the JAX settings it was traced
-        with; without one it is this engine's."""
-        if definition is None:
-            store = self._executables
-        else:
-            store = EXECUTABLES
-            key = (self.engine, definition, key, trace_context())
-        exe = store.get(key)
-        cached = exe is not None
-        if cached:
-            self.totals["executable_cache_hits_total"] += 1
-        else:
-            self.totals["executable_cache_misses_total"] += 1
-            with self.stage("trace"):
-                traced = jitted.trace(*args)
-            with self.stage("lower"):
-                lowered = traced.lower()
-            with self.stage("compile"):
-                exe = lowered.compile()
-            store.put(key, exe)
-        return exe, cached
-
-
-_ins_jit = jax.jit(_insert_impl)  # one compile cache shared by every regrow
-# The pallas table's slot layout is partition-relative (partition = hi mod P,
-# row = hi div P — tensor/pallas_hashtable.py), so a pallas run's regrow must
-# re-hash through the pallas insert itself; every XLA variant shares the
-# global bucket = hi mod n_buckets layout and regrows through _insert_impl.
-_ins_jit_pallas = None
-
-
-def _regrow_insert(insert_variant: str):
-    global _ins_jit_pallas
-    if insert_variant != "pallas":
-        return _ins_jit
-    if _ins_jit_pallas is None:
-        _ins_jit_pallas = jax.jit(resolve_insert("pallas"))
-    return _ins_jit_pallas
-
-
-# `.npz`-suffix normalization so `checkpoint(p)` / `load_checkpoint(..., p)`
-# round-trip on the same string (now owned by the atomic checkpoint writer).
-_ckpt_path = normalize_ckpt_path
-
-
-def _validate_ckpt_meta(model, meta: dict) -> None:
-    """Shared layout/property guards for engine checkpoints: lane widths and
-    property positions index into the dumped arrays, so any mismatch would
-    silently misalign them."""
-    if (meta["lanes"], meta["max_actions"]) != (
-        model.lanes,
-        model.max_actions,
-    ):
-        raise ValueError(
-            "checkpoint was taken with a different model layout "
-            f"(lanes/max_actions {meta['lanes']}/{meta['max_actions']} "
-            f"!= {model.lanes}/{model.max_actions})"
-        )
-    prop_names = [p.name for p in model.properties()]
-    if meta["properties"] != prop_names:
-        raise ValueError(
-            "checkpoint was taken with a different property list "
-            f"({meta['properties']} != {prop_names})"
-        )
-
-
-def _regrow(
-    model, fields, old_log2: int, new_log2: int, K: int,
-    queue_rows: Optional[int] = None,
-    insert_variant: str = "sort",
-) -> dict:
-    """Re-hash a checkpointed visited table into a larger one and pad the
-    frontier queue to `queue_rows` (default: the new table size — what the
-    sharded engine's per-shard queues use; the resident engine passes its
-    slacked capacity so the queue is padded exactly once). Queue rows live
-    at [0, tail). Bucket slots depend on the table size, so growth is a
-    full re-insert of every occupied slot — done on device in `K`-row
-    batches."""
-    S_new = 1 << new_log2
-    Q_new = queue_rows if queue_rows is not None else S_new
-    t_lo, t_hi = fields["t_lo"], fields["t_hi"]
-    p_lo, p_hi = fields["p_lo"], fields["p_hi"]
-    nz = t_lo != 0  # lo == 0 is the empty-slot sentinel (fingerprint.py)
-    keys = [a[nz] for a in (t_lo, t_hi, p_lo, p_hi)]
-    ins = _regrow_insert(insert_variant)
-    zero = jnp.zeros(S_new, dtype=jnp.uint32)
-    tl, th, pl, ph = zero, zero, zero, zero
-    n = keys[0].size
-    for i in range(0, max(n, 1), K):
-        batch = [np.zeros(K, dtype=np.uint32) for _ in range(4)]
-        m = min(K, n - i) if n else 0
-        for b, k in zip(batch, keys):
-            b[:m] = k[i : i + m]
-        active = np.arange(K) < m
-        tl, th, pl, ph, _, ovf = ins(tl, th, pl, ph, *batch, active)
-        if bool(ovf):
-            # srlint: fault-ok deterministic capacity wall during host-side regrow, not injectable infra
-            raise RuntimeError(
-                "table overflow while re-growing; raise table_log2 further"
-            )
-    out = {"t_lo": tl, "t_hi": th, "p_lo": pl, "p_hi": ph}
-    for f in ("q_states", "q_lo", "q_hi", "q_ebits", "q_depth"):
-        old = fields[f]
-        grown = np.zeros((Q_new,) + old.shape[1:], dtype=old.dtype)
-        keep = min(old.shape[0], Q_new)
-        grown[:keep] = old[:keep]
-        out[f] = grown
-    # The carry's abort code is NOT touched here: a checkpointed carry sits
-    # at the last sound chunk boundary (code 0), and the reason for the
-    # abort that prompted the regrow travels in checkpoint meta
-    # ("abort_reason"), where load_checkpoint enforces that the overflowed
-    # resource actually grew.
-    return out
-
-
-@jax.jit
-def _compact_queue(q_states, q_lo, q_hi, q_ebits, q_depth, head):
-    """Shift live queue rows [head, tail) to the front (one gather per
-    array) — the tiered store's answer to the append-only tail growing past
-    capacity once uniques exceed the table. Static shapes: the out-of-range
-    tail of the gather fills with zeros, which nothing past the new tail
-    reads."""
-    idx = head + jnp.arange(q_lo.shape[0], dtype=jnp.int32)
-    one = lambda a: jnp.take(a, idx, mode="fill", fill_value=0)  # noqa: E731
-    return (
-        jnp.take(q_states, idx, axis=0, mode="fill", fill_value=0),
-        one(q_lo), one(q_hi), one(q_ebits), one(q_depth),
-    )
-
-
-@jax.jit
-def _inject_rows(
-    q_states, q_lo, q_hi, q_ebits, q_depth, tail,
-    b_states, b_lo, b_hi, b_eb, b_dp,
-):
-    """Write a host-built block of confirmed-new suspect rows at the queue
-    tail (one contiguous dynamic_update_slice per array; rows past the
-    caller's real count are scratch beyond the new tail). The caller
-    guarantees tail + block_rows <= Q via the tiered queue slack."""
-    upd2 = jax.lax.dynamic_update_slice(q_states, b_states, (tail, 0))
-    one = lambda q, b: jax.lax.dynamic_update_slice(q, b, (tail,))  # noqa: E731
-    return (
-        upd2, one(q_lo, b_lo), one(q_hi, b_hi),
-        one(q_ebits, b_eb), one(q_depth, b_dp),
-    )
-
-
-class ResidentSearch:
-    """One-dispatch whole-search engine for a `TensorModel`."""
+    _carry_type = _Carry
 
     # Corpus warm ladder: the ONE kind vocabulary and the ONE preload seam
     # (store/warm.py) — aliased, never restated; knobs.check_registry()
@@ -423,9 +166,6 @@ class ResidentSearch:
         `SearchResult.detail["telemetry"]`. `tracer` (obs.Tracer) records
         the host phases (chunk dispatch, tiered-store servicing,
         checkpoint) as Chrome trace events."""
-        self.model = model
-        self.batch_size = batch_size
-        self.table_log2 = table_log2
         self.queue_log2 = table_log2 if queue_log2 is None else queue_log2
         self.donate_chunks = donate_chunks
         # Queue-append variant: XLA lays the queue out column-major (fast
@@ -465,11 +205,6 @@ class ResidentSearch:
         #              (tensor/pallas_hashtable.py; interpret mode on
         #              the CPU backend). Split layout only; the table must
         #              tile into (8,128) VMEM blocks, so table_log2 >= 10.
-        if insert_variant not in INSERT_VARIANTS:  # knob universe: knobs.py
-            raise ValueError(
-                f"insert_variant must be one of {INSERT_VARIANTS}, "
-                f"got {insert_variant!r}"
-            )
         if (
             insert_variant in PHASED_VARIANTS or insert_variant == "pallas"
         ) and table_layout == "kv":
@@ -477,78 +212,16 @@ class ResidentSearch:
                 f"insert_variant={insert_variant!r} supports the split "
                 "table layout only"
             )
-        check_table_log2(insert_variant, table_log2)  # pallas tiling guard
-        self.insert_variant = insert_variant
-        # store="tiered": two-tier state store (stateright_tpu/store/) —
-        # past `high_water` fill, cold non-full buckets spill to a host
-        # fingerprint store over PCIe and a device Bloom summary
-        # (2^summary_log2 bits) filters re-probes. The while_loop exits to
-        # the host (EXIT_SERVICE) instead of aborting, so spaces bigger
-        # than the table degrade gracefully; tiered runs are always
-        # chunked (the host must get control between dispatches).
-        if store not in STORE_KINDS:  # knob universe: knobs.py
-            raise ValueError(f"store must be one of {STORE_KINDS}, got {store!r}")
         if store == "tiered" and table_layout != "split":
             raise ValueError("store='tiered' supports the split table layout only")
-        self.store = store
         self._store = None
-        self._store_args = (high_water, low_water, summary_log2)
-        ka = batch_size * model.max_actions
-        if store == "tiered":
-            self._fresh_store()
-            # One-batch headroom: a single step can claim up to K*A slots
-            # and eviction only runs between dispatches.
-            self._spill_trigger = min(
-                self._store.high_slots, (1 << table_log2) - ka
-            )
-            if self._spill_trigger <= self._store.low_slots:
-                raise ValueError(
-                    "table too small for tiered spilling at this batch: "
-                    f"table 2^{table_log2} minus one batch of claims ({ka}) "
-                    "leaves no room above the low-water mark "
-                    f"({self._store.low_slots} slots); raise table_log2 or "
-                    "lower batch_size/low_water"
-                )
-            # Suspect buffer: 2 batches of accumulation + 1 batch of append
-            # slack before a service exit is forced.
-            self._SQ = 3 * ka
-        else:
-            self._spill_trigger = 0
-            self._SQ = 0
-        self._q_compacted = False
-        # Telemetry ring capacity (0 disables the in-carry ring entirely —
-        # the kernels compile without it, the A/B knob for bench OBS rows).
-        self._TMR = (1 << telemetry_log2) if telemetry else 0
-        self._ring = StepRing(self._TMR) if telemetry else None
-        self._tracer = as_tracer(tracer)
-        self._metrics_name = REGISTRY.register("resident", self.metrics)
-        # Calibration comparator (obs/calib.py): consumes the already-synced
-        # ring drains below — no extra device work, observes, never steers.
-        self._calib = None
-        if telemetry:
-            # Lazy import: obs.calib prices through tensor.costmodel, so a
-            # module-level import would cycle when obs loads first.
-            from ..obs.calib import CalibConfig, Comparator, calib_enabled
-
-        if telemetry and calib_enabled():
-            self._calib = Comparator(CalibConfig(
-                engine="resident",
-                variant=ENGINE_VARIANTS.get(
-                    (table_layout, insert_variant), "split"
-                ),
-                lanes=model.lanes,
-                max_actions=model.max_actions,
-                batch=batch_size,
-                table_log2=table_log2,
-                spill=(store == "tiered"),
-            ))
-            REGISTRY.register("calib", self._calib.metrics)
-        self.props = model.properties()
+        # A step claims up to K*A slots (one batch's successors).
+        self._init_host(
+            "resident", model, batch_size, table_log2, insert_variant, store,
+            (high_water, low_water, summary_log2), telemetry, telemetry_log2,
+            tracer, step_claims=batch_size * model.max_actions,
+        )
         self._kernel, self._seed_k, self._chunk_k = self._build()
-        # Whole-search executables, compiled in stages (_search_executable),
-        # with each stage's seconds; the path counters beside them
-        # (metrics()).
-        self._stages = Stages("resident", self._tracer)
         # The model's definition and every argument `_build` reads: engines
         # that agree on it share one search executable (Stages.executable).
         dk = model.definition_key()
@@ -557,14 +230,6 @@ class ResidentSearch:
             table_layout, insert_variant, store, high_water, low_water,
             summary_log2, telemetry, telemetry_log2, donate_chunks,
         )
-        self._counters = {
-            "parent_map_entries": 0,
-            "replay_steps_total": 0,
-            "replay_traces_total": 0,
-        }
-        self._last_tables = None
-        self._parent_map = None
-        self._seed = None
         # Operand tables (lowered models): round-varying baked tables flow
         # into the kernels as ARGUMENTS instead of jaxpr constants, so
         # `set_dyn_tables` can swap their contents (same shapes) with no
@@ -575,34 +240,18 @@ class ResidentSearch:
             if hasattr(model, "dyn_tables")
             else {}
         )
-        # Suspended-search carry (chunked runs only): retained across run()
-        # calls so budget/timeout suspensions and overflows are resumable.
-        self._carry = None
-        # Warm-start corpus payload (store/warm.py; see warm_start).
-        self._warm: Optional[dict] = None
-        self._warm_states = 0
-        self._warm_kind: Optional[str] = None  # knobs.WARM_KINDS rung served
-        self._warm_summary_pending = False
         # Abort code of the last overflow (ABORT_TABLE | ABORT_QUEUE bits);
         # written into checkpoint meta so recovery grows the right resource.
         self._last_abort = 0
 
-    def _fresh_store(self) -> None:
-        """(Re)build the tiered store — a fresh search owes nothing to a
-        previous run's spill tier or Bloom summary."""
-        from ..store.tiered import TieredConfig, TieredStore
+    @property
+    def _stores(self) -> Optional[list]:
+        """The spill tier as the shared host side sees it: one per shard."""
+        return None if self._store is None else [self._store]
 
-        if self._store is not None:
-            self._store.close()  # stop the old spill tier's compactor
-        high_water, low_water, summary_log2 = self._store_args
-        self._store = TieredStore(
-            1 << self.table_log2,
-            TieredConfig(
-                high_water=high_water,
-                low_water=low_water,
-                summary_log2=summary_log2,
-            ),
-        )
+    @_stores.setter
+    def _stores(self, stores: list) -> None:
+        (self._store,) = stores
 
     def store_stats(self) -> Optional[dict]:
         """Per-tier occupancy counters (None with the plain device store) —
@@ -1207,54 +856,12 @@ class ResidentSearch:
         chunked, budget = _resolve_chunking(
             budget, timeout, progress, self._carry
         )
-        model = self.model
-        K = self.batch_size
         start = time.monotonic()
-        self._parent_map = None  # invalidate any prior reconstruction cache
-        self._stages.new_run()
-        if self._ring is not None and self._carry is None and self._ring.steps:
-            # Fresh search (no suspended carry): telemetry starts over too.
-            self._ring = self._ring.fresh()
-
-        # seed_init is deterministic per model; cache it (and its padded
-        # device-side form) so repeat runs skip the host<->device round trips.
-        if self._seed is None:
-            init, init_lo, init_hi, n_raw = seed_init(model)
-            if len(init) > K:
-                raise ValueError(
-                    "more init states than batch_size; raise batch_size"
-                )
-            n0 = len(init)
-            st = np.zeros((K, model.lanes), dtype=np.uint32)
-            st[:n0] = init
-            lo = np.zeros(K, dtype=np.uint32)
-            lo[:n0] = init_lo
-            hi = np.zeros(K, dtype=np.uint32)
-            hi[:n0] = init_hi
-            dev = jax.device_put((st, lo, hi))
-            self._seed = (dev, n0, n_raw)
-        dev, n0, n_raw = self._seed
-
-        # Vacuously-true finish policies (e.g. ALL with zero properties) stop
-        # before exploring anything, matching the host checkers' immediate
-        # is_awaiting_discoveries early-out (ref: bfs.rs:278-280).
-        if finish_when.matches(self.props, set()) or not self.props:
-            z = np.zeros(1 << self.table_log2, dtype=np.uint32)
-            self._last_tables = (
-                (np.zeros(2 << self.table_log2, np.uint32),
-                 np.zeros(0, np.uint32), z, z)
-                if self.table_layout == "kv"
-                else (z, z, z, z)
-            )
-            return SearchResult(
-                state_count=n_raw,
-                unique_state_count=n0,
-                max_depth=1 if n0 else 0,
-                discoveries={},
-                complete=False,
-                duration=time.monotonic() - start,
-                steps=0,
-            )
+        self._begin_run()
+        dev, n0, n_raw = self._seed_rows()
+        vacuous = self._vacuous_result(finish_when, n0, n_raw, start)
+        if vacuous is not None:
+            return vacuous
 
         required_mask, any_mask = _finish_masks(finish_when, self.props)
         target = int(target_state_count or 0)
@@ -1290,18 +897,12 @@ class ResidentSearch:
                     ))
                     # ONE device->host transfer for the entire result.
                     summary = np.asarray(summary)
-            if self._ring is not None:
-                # Whole-search dispatch: one bulk drain at the end (the ring
-                # holds the LAST 2^telemetry_log2 steps; earlier rows count
-                # as dropped). The window is the execute stage alone: no
-                # tracing, lowering or executable load.
-                w_us = self._stages.phases["execute_s"] * 1e6
-                self._ring.drain(np.asarray(tm_rows), int(summary[8]),
-                                 window_us=w_us)
-                if self._calib is not None:
-                    self._calib.observe(
-                        self._ring.steps, w_us, self._ring.generated_total
-                    )
+            # Whole-search dispatch: one bulk drain at the end (the ring
+            # holds the LAST 2^telemetry_log2 steps; earlier rows count as
+            # dropped). The window is the execute stage alone: no tracing,
+            # lowering or executable load.
+            self._drain_ring(tm_rows, int(summary[8]),
+                             self._stages.phases["execute_s"] * 1e6)
             # On overflow the failed run's tables are unsound AND a previous
             # run's snapshot must not silently serve paths for states this
             # run discovered — invalidate (matches the sharded engine).
@@ -1353,17 +954,8 @@ class ResidentSearch:
                         self._dyn_dev,
                     )
                     summary = np.asarray(summary)  # one small transfer/chunk
-                if self._ring is not None:
-                    # The chunk already synced (summary fetch); pulling the
-                    # ring here adds a bulk copy, never a per-step sync.
-                    w_us = (time.monotonic() - t_chunk0) * 1e6
-                    self._ring.drain(np.asarray(carry.tm_rows),
-                                     int(summary[8]), window_us=w_us)
-                    if self._calib is not None:
-                        self._calib.observe(
-                            self._ring.steps, w_us,
-                            self._ring.generated_total,
-                        )
+                self._drain_ring(carry.tm_rows, int(summary[8]),
+                                 (time.monotonic() - t_chunk0) * 1e6)
                 code = int(summary[7])
                 if code & EXIT_SERVICE and not (
                     code & (ABORT_TABLE | ABORT_QUEUE)
@@ -1393,12 +985,7 @@ class ResidentSearch:
                     # from the last sound chunk boundary — and point the
                     # reconstruction snapshot at that same boundary so
                     # paths reflect THIS run, not a stale prior one.
-                    self._last_tables = (
-                        self._carry.t_lo,
-                        self._carry.t_hi,
-                        self._carry.p_lo,
-                        self._carry.p_hi,
-                    )
+                    self._last_tables = self._carry_tables()
                     self._parent_map = None
                     raise RuntimeError(
                         f"hash table or queue full — {reason}; the search "
@@ -1431,70 +1018,17 @@ class ResidentSearch:
                 if timeout is not None and time.monotonic() - start > timeout:
                     timed_out = True
                     break
-            self._last_tables = (
-                self._carry.t_lo,
-                self._carry.t_hi,
-                self._carry.p_lo,
-                self._carry.p_hi,
-            )
+            self._last_tables = self._carry_tables()
 
-        (
-            gen_lo,
-            gen_hi,
-            unique_count,
-            max_depth,
-            discovered,
-            head,
-            tail,
-            overflow,
-            steps,
-            _stop,
-        ) = (int(x) for x in summary[:10])
+        overflow = int(summary[7])
         if overflow:
             self._last_abort = overflow
             raise RuntimeError(
                 f"hash table or queue full — {_abort_reason(overflow)}"
             )
-
-        P = len(self.props)
-        disc_lo = summary[12 : 12 + max(P, 1)]
-        disc_hi = summary[12 + max(P, 1) :]
-        discoveries = {
-            p.name: int(pack_fp(disc_lo[i], disc_hi[i]))
-            for i, p in enumerate(self.props)
-            if discovered & (1 << i)
-        }
-        state_count = gen_lo | (gen_hi << 32)
-        if self._warm is not None and head >= tail and not timed_out:
-            # Warm-start replay (store/warm.py): the run only re-expanded
-            # the init frontier (everything deeper dedup-filtered against
-            # the preloaded corpus through the Bloom suspect path), so the
-            # result bookkeeping is the publisher's — bit-identical to this
-            # engine's own cold run for this content key.
-            w = self._warm
-            state_count = w["state_count"]
-            unique_count = w["unique_count"]
-            max_depth = w["max_depth"]
-            discoveries = dict(w["discoveries"])
-        detail = self._detail()
-        if not chunked:
-            detail = {**(detail or {}), "phases": dict(self._stages.phases)}
-        if self._warm_kind is not None:
-            detail = dict(detail or {})
-            detail["corpus"] = {
-                "warm_start": True,
-                "preloaded_states": self._warm_states,
-                "warm_kind": self._warm_kind,
-            }
-        return SearchResult(
-            state_count=state_count,
-            unique_state_count=unique_count,
-            max_depth=max_depth,
-            discoveries=discoveries,
-            complete=head >= tail and not timed_out,
-            duration=time.monotonic() - start,
-            steps=steps,
-            detail=detail,
+        return self._result(
+            start, chunked,
+            **summary_fields(summary[None], len(self.props), timed_out),
         )
 
     def _search_executable(self, args: tuple):
@@ -1513,43 +1047,6 @@ class ResidentSearch:
         return self._stages.executable(
             self._kernel, args, key, self._definition
         )
-
-    def telemetry_summary(self) -> Optional[dict]:
-        """Step-telemetry digest (obs/ring.py; None with telemetry off) —
-        surfaced in SearchResult.detail["telemetry"] and `/metrics`."""
-        if self._ring is None:
-            return None
-        return self._ring.summary(1 << self.table_log2, self.batch_size)
-
-    def metrics(self) -> dict:
-        """Flat counter snapshot for the obs registry / Prometheus export.
-        Host-side values only (drained telemetry + store counters) — a
-        scrape never syncs the device mid-search."""
-        out: dict = {**self._stages.totals, **self._counters}
-        if self._ring is not None:
-            out.update(
-                steps=self._ring.steps,
-                generated_states=self._ring.generated_total,
-                claimed_states=self._ring.claimed_total,
-            )
-        stats = self.store_stats()
-        if stats:
-            # Non-numeric leaves (the store's kind string) are dropped by
-            # the Prometheus renderer's flatten step.
-            out["store"] = stats
-        return out
-
-    def _detail(self) -> Optional[dict]:
-        """SearchResult.detail under the one documented schema
-        (obs/schema.py, shared assembly in obs.build_detail)."""
-        detail = build_detail(self.store_stats(), self.telemetry_summary())
-        if self._calib is not None:
-            self._calib.finish()
-        if self._calib is not None and self._calib.chunks:
-            detail = dict(detail or {})
-            detail["calib"] = self._calib.detail()
-            self._calib.flush_records()
-        return detail
 
     def _service(self) -> None:
         """Host half of the tiered store, run between chunked dispatches on
@@ -1674,79 +1171,21 @@ class ResidentSearch:
         self._dyn_dev = jax.device_put(tables)
 
     def reset(self) -> None:
-        """Drop any suspended carry so the next `run()` starts fresh."""
-        self._carry = None
-        self._parent_map = None
-        self._last_tables = None
+        super().reset()
         self._last_abort = 0  # a fresh run owes nothing to an old overflow
-        self._q_compacted = False
-        if self._ring is not None:
-            self._ring = self._ring.fresh()  # telemetry starts over too
-        if self._store is not None:
-            self._fresh_store()  # spill tier + Bloom summary start empty
 
-    def dump_states(
-        self, decode: bool = True, evaluated_only: bool = False,
-        raw: bool = False, start: int = 0,
-    ) -> list:
-        """Batched state dump: every unique state the search reached, pulled
-        from the frontier queue in ONE device transfer (the queue never
-        wraps, so rows [0, tail) are exactly the unique states ever
-        enqueued). This is the device analogue of the reference's
-        `StateRecorder` visitor (ref: src/checker/visitor.rs:75-111) — exact
-        state-set assertions against device engines. Requires a chunked run
-        (`budget=`/`timeout=`/`progress=`), which retains the carry.
+    # -- the engine's side of SearchHost ---------------------------------------
 
-        `evaluated_only` restricts the dump to rows the search popped
-        ([0, head)) — on an early exit the tail also holds never-evaluated
-        frontier rows; for an exhausted run the two dumps coincide. (Rows
-        cut off by target_max_depth are popped-but-unevaluated and still
-        appear — the one divergence from reference visitor semantics.)"""
-        if self._carry is None:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "no retained carry to dump: run with budget=... (chunked "
-                "dispatch) before dump_states()"
-            )
-        if self._q_compacted:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "dump_states is unavailable once the tiered store has "
-                "compacted the frontier queue (rows [0, tail) no longer "
-                "cover every unique state; spilled states live host-side) — "
-                "use store='device' for exact state-set dumps"
-            )
+    def _seed_operands(self, st, lo, hi, n0):
+        # Device-side once, so repeat runs skip the host-to-device copies.
+        return jax.device_put((st, lo, hi))
+
+    def _queue_rows(self, evaluated_only: bool, start: int) -> list:
+        # Rows [start, end) sliced on device: one transfer of what is read.
         end = int(self._carry.head if evaluated_only else self._carry.tail)
-        if raw:
-            # The bulk form: uint32[n, lanes]. refine_check's per-round
-            # poison scan works on millions of rows — python tuple-building
-            # dominated the round cost before this. `start` slices on device
-            # so incremental callers transfer only the delta.
-            return np.asarray(self._carry.q_states[start:end])
-        rows = np.asarray(self._carry.q_states[:end])
-        if not decode:
-            return [tuple(int(x) for x in r) for r in rows]
-        return [self.model.decode(r) for r in rows]
+        return [np.asarray(self._carry.q_states[start:end])]
 
-    # -- checkpoint / resume ---------------------------------------------------
-    # SURVEY.md §5: the reference has no partial-search checkpointing; the
-    # whole resident carry (tables + queue + counters) is a handful of device
-    # arrays, so dumping it is one transfer. Only chunked runs
-    # (`run(budget=...)`) keep a carry to dump.
-
-    def checkpoint(self, path: str) -> None:
-        """Dump the suspended search carry to `path` (.npz). Valid after a
-        chunked `run()` has suspended (budget/timeout exhausted) or raised on
-        overflow; `load_checkpoint` rebuilds the search — optionally into a
-        LARGER table — and the next `run()` continues exactly."""
-        import json
-
-        if self._carry is None:
-            # srlint: fault-ok caller-contract guard, not an I/O/device surface
-            raise RuntimeError(
-                "nothing to checkpoint: no suspended carry (run with "
-                "budget=... to enable chunked dispatch)"
-            )
+    def _checkpoint_arrays(self, path: str) -> dict:
         if self.table_layout != "split":
             # load_checkpoint refuses kv checkpoints (regrow is split-only);
             # fail at SAVE time rather than handing back a file that can
@@ -1755,39 +1194,23 @@ class ResidentSearch:
                 "checkpointing is split-layout-only for now; use "
                 "table_layout='split' (default) for checkpoint/resume runs"
             )
-        c = self._carry
         with self._tracer.span("checkpoint", cat="engine", path=path):
-            arrays = {f: np.asarray(getattr(c, f)) for f in c._fields}
-        if self._store is not None:
-            # Spill tier rides along; the Bloom summary is rebuilt from the
-            # fingerprints on load (see store/tiered.py).
-            arrays.update(self._store.to_checkpoint())
-        arrays["meta"] = np.frombuffer(
-            json.dumps(
-                {
-                    "lanes": self.model.lanes,
-                    "max_actions": self.model.max_actions,
-                    "properties": [p.name for p in self.props],
-                    "table_log2": self.table_log2,
-                    "queue_log2": self.queue_log2,
-                    "batch_size": self.batch_size,
-                    "table_layout": self.table_layout,
-                    "insert_variant": self.insert_variant,
-                    "store": (
-                        self._store.meta() if self._store is not None else None
-                    ),
-                    "q_compacted": self._q_compacted,
-                    # Why the run aborted (0 = clean suspension): lets
-                    # load_checkpoint refuse a resume that would hit the
-                    # same wall again.
-                    "abort_reason": self._last_abort,
-                }
-            ).encode(),
-            dtype=np.uint8,
+            return to_host(self._carry._asdict())
+
+    def _checkpoint_meta(self) -> dict:
+        return {
+            "queue_log2": self.queue_log2,
+            "table_layout": self.table_layout,
+            # Why the run aborted (0 = clean suspension): lets
+            # load_checkpoint refuse a resume that would hit the same wall
+            # again.
+            "abort_reason": self._last_abort,
+        }
+
+    def _set_carry(self, fields: dict) -> None:
+        self._carry = _Carry(
+            **{f: jax.device_put(jnp.asarray(v[0])) for f, v in fields.items()}
         )
-        # Crash-atomic write (tmp+fsync+rename, CRC32 footer, previous
-        # generation kept at `path + ".prev"` — faults/ckptio.py).
-        fenced_savez(path, arrays)
 
     @classmethod
     def load_checkpoint(
@@ -1805,20 +1228,15 @@ class ResidentSearch:
         matching capacity. The next `run()` continues where the dump left
         off. The CRC footer is verified; a corrupt current generation falls
         back to `path + ".prev"` instead of raising."""
-        import json
-
-        data, _src = load_latest(path)
-        meta = json.loads(bytes(data["meta"].tobytes()).decode())
-        _validate_ckpt_meta(model, meta)
+        data, meta = cls._read_checkpoint(model, path)
         if meta.get("table_layout", "split") != "split":
             raise NotImplementedError(
                 "checkpoint resume is split-layout-only for now; rerun the "
                 "search with table_layout='split' (default) if you need "
                 "checkpoint/regrow"
             )
-        log2 = table_log2 if table_log2 is not None else meta["table_log2"]
-        if log2 < meta["table_log2"]:
-            raise ValueError("cannot shrink the table on resume")
+        kw = cls._resume_kwargs(meta, batch_size, table_log2)
+        log2 = kw["table_log2"]
         meta_q = meta.get("queue_log2", meta["table_log2"])
         if queue_log2 is None:
             # Default-sized checkpoints (queue == table) keep following the
@@ -1841,171 +1259,39 @@ class ResidentSearch:
                 f"(queue_log2={meta_q}); pass a larger queue_log2 to "
                 "load_checkpoint to regrow the queue"
             )
-        store_meta = meta.get("store")
         rs = cls(
-            model,
-            batch_size=batch_size or meta["batch_size"],
-            table_log2=log2,
-            donate_chunks=donate_chunks,
-            queue_log2=queue_log2,
-            # A capped/phased run must resume on the same insert design —
-            # overflow recovery happens exactly on the long at-scale runs
-            # where silently falling back to the full-batch sort would
-            # reintroduce the cost the variant was chosen to avoid.
-            insert_variant=meta.get("insert_variant", "sort"),
-            store="tiered" if store_meta else "device",
-            **(
-                {
-                    "high_water": store_meta["high_water"],
-                    "low_water": store_meta["low_water"],
-                    "summary_log2": store_meta["summary_log2"],
-                }
-                if store_meta
-                else {}
-            ),
+            model, donate_chunks=donate_chunks, queue_log2=queue_log2, **kw
         )
-        if store_meta:
-            from ..store.tiered import TieredStore
-
-            rs._store.close()  # replaced by the checkpointed tier
-            rs._store = TieredStore.from_checkpoint(
-                1 << log2, store_meta,
-                data["spill_fps"], data["spill_parents"],
-            )
-            rs._q_compacted = bool(meta.get("q_compacted", False))
-        # Pre-tiered checkpoints lack the suspect-buffer/summary fields;
-        # default them to this engine's (empty) shapes.
-        defaults = {
-            "hot_claims": np.int32((np.asarray(data["t_lo"]) != 0).sum()),
-            "s_states": np.zeros((rs._SQ, model.lanes), np.uint32),
-            "s_lo": np.zeros(rs._SQ, np.uint32),
-            "s_hi": np.zeros(rs._SQ, np.uint32),
-            "s_ebits": np.zeros(rs._SQ, np.uint32),
-            "s_depth": np.zeros(rs._SQ, np.uint32),
-            "s_tail": np.int32(0),
-            "summary": np.zeros(1, np.uint32),
-            "tm_rows": np.zeros((rs._TMR, N_COLS), np.uint32),
-        }
-        fields = {
-            f: data[f] if f in data else defaults[f] for f in _Carry._fields
-        }
-        # The telemetry ring is observability, not search state: a restore
-        # with a different ring size (or a pre-obs checkpoint) just starts
-        # the ring empty, with the pre-restore steps counted as uncaptured.
-        if np.asarray(fields["tm_rows"]).shape != (rs._TMR, N_COLS):
-            fields["tm_rows"] = np.zeros((rs._TMR, N_COLS), np.uint32)
-        if rs._ring is not None:
-            rs._ring.skip_to(int(np.asarray(fields["steps"])))
+        fields = rs._restore(data, meta)
+        ka = rs.batch_size * model.max_actions
         # The suspect buffer is sized by batch_size x max_actions: a resume
-        # with a different batch size renormalizes it like the queue below
-        # (live rows [0, s_tail) are preserved; shrinking past them is
-        # refused).
-        if store_meta:
-            s_tail_live = int(fields["s_tail"])
-            if s_tail_live > rs._SQ - rs.batch_size * model.max_actions:
+        # with a different batch size renormalizes it like the queue (live
+        # rows [0, s_tail) are preserved; shrinking past them is refused).
+        if meta.get("store"):
+            s_tail_live = int(fields["s_tail"][0])
+            if s_tail_live > rs._SQ - ka:
                 raise ValueError(
                     "batch_size too small for the checkpointed suspect "
                     f"buffer ({s_tail_live} live suspects); resume with the "
                     "original batch_size"
                 )
-            for f in ("s_states", "s_lo", "s_hi", "s_ebits", "s_depth"):
-                old = fields[f]
-                if old.shape[0] != rs._SQ:
-                    grown = np.zeros(
-                        (rs._SQ,) + old.shape[1:], dtype=old.dtype
-                    )
-                    keep = min(old.shape[0], rs._SQ)
-                    grown[:keep] = old[:keep]
-                    fields[f] = grown
-            # The summary is a pure function of the spilled set — always
-            # use the freshly rebuilt words (also covers regrown tables).
-            fields["summary"] = rs._store.summary_np
-        # Pre-abort-code checkpoints stored overflow as a bool; the carry
-        # now holds a uint32 abort bitmask. Clear it on resume: a chunked
-        # checkpoint sits at a sound boundary (code 0) already, but a
-        # SEED-insert overflow leaves its code in the carry itself — and
-        # the guards above have just enforced that whatever resource
-        # aborted has grown, so carrying the old code forward would only
-        # re-abort the recovered run on its first step.
-        fields["overflow"] = np.zeros_like(
-            np.asarray(fields["overflow"]), dtype=np.uint32
-        )
-        if log2 != meta["table_log2"]:
-            fields.update(
-                _regrow(
-                    model, fields, meta["table_log2"], log2, rs.batch_size,
-                    queue_rows=rs._Q,
-                    insert_variant=rs.insert_variant,
-                )
-            )
-            # Bucket residency changed wholesale; recount occupied slots
-            # (the spilled set is untouched by a regrow).
-            fields["hot_claims"] = np.int32(
-                (np.asarray(fields["t_lo"]) != 0).sum()
-            )
-        # Normalize queue arrays to this search's capacity (covers
-        # checkpoints from the pre-slack format, changed batch sizes, and
-        # regrown tables). Live rows sit at [0, tail); the guard makes the
+            _fit_rows(fields, _SUSPECTS, rs._SQ)
+        # Clear the abort code on resume: a chunked checkpoint sits at a
+        # sound boundary (code 0) already, but a SEED-insert overflow leaves
+        # its code in the carry itself — and the guards above have just
+        # enforced that whatever resource aborted has grown, so carrying the
+        # old code forward would only re-abort the recovered run on its
+        # first step.
+        fields["overflow"] = np.zeros_like(fields["overflow"])
+        # Live queue rows sit at [0, tail); the guard makes the queue's
         # normalization a pure extension — silently dropping frontier rows
         # would corrupt the resumed search.
-        ckpt_tail = int(fields["tail"])
-        if ckpt_tail > rs._Q - rs.batch_size * model.max_actions:
+        ckpt_tail = int(fields["tail"][0])
+        if ckpt_tail > rs._Q - ka:
             raise ValueError(
                 f"queue_log2={rs.queue_log2} gives {rs._Q} rows but the "
                 f"checkpointed frontier tail is {ckpt_tail}; the queue "
                 "cannot shrink below the live frontier"
             )
-        for f in ("q_states", "q_lo", "q_hi", "q_ebits", "q_depth"):
-            old = fields[f]
-            if old.shape[0] != rs._Q:
-                grown = np.zeros(
-                    (rs._Q,) + old.shape[1:], dtype=old.dtype
-                )
-                keep = min(old.shape[0], rs._Q)
-                grown[:keep] = old[:keep]
-                fields[f] = grown
-        rs._carry = _Carry(
-            **{f: jax.device_put(jnp.asarray(v)) for f, v in fields.items()}
-        )
+        rs._set_carry(fields)
         return rs
-
-    def build_parent_map(self) -> dict:
-        """{fingerprint: parent fingerprint (0 = init)} decoded from the
-        last run's table snapshot — layout-aware (split vs kv) and cached;
-        shared by path reconstruction and the TPU checker's visitors."""
-        if self._parent_map is None:
-            if self._last_tables is None:
-                # srlint: fault-ok caller-contract guard, not an I/O/device surface
-                raise RuntimeError(
-                    "no table snapshot to reconstruct from: run() has not "
-                    "completed since the last reset/donated resume"
-                )
-            with self._tracer.span("path.parent_map", cat="path") as span:
-                t_lo, t_hi, p_lo, p_hi = (
-                    np.asarray(x) for x in self._last_tables
-                )
-                if self.table_layout == "kv":
-                    b = min(KV_BUCKET, 1 << self.table_log2)
-                    kv = t_lo.reshape(-1, 2 * b)
-                    t_lo = kv[:, :b].reshape(-1)
-                    t_hi = kv[:, b:].reshape(-1)
-                nz = t_lo != 0
-                keys = pack_fp(t_lo[nz], t_hi[nz])
-                parents = pack_fp(p_lo[nz], p_hi[nz])
-                self._parent_map = dict(zip(keys.tolist(), parents.tolist()))
-                if self._store is not None:
-                    # Spill entries win on keys present in both tiers: they
-                    # carry the ORIGINAL (BFS-discovery) parent, which keeps
-                    # reconstructed chains acyclic.
-                    self._parent_map.update(self._store.parent_map())
-                span.set(entries=len(self._parent_map))
-            self._counters["parent_map_entries"] = len(self._parent_map)
-        return self._parent_map
-
-    def reconstruct_path(self, fp: int):
-        """TLC-style reconstruction from the final table contents (the logic
-        is shared with the host-orchestrated engine)."""
-        self.build_parent_map()
-        return reconstruct_path(
-            self.model, self._parent_map, fp, self._tracer, self._counters
-        )

@@ -742,7 +742,7 @@ class DeviceSimulation:
         finish_when: HasDiscoveries = HasDiscoveries.ALL,
         walks: Optional[int] = None,
     ) -> SearchResult:
-        from .resident import _finish_masks
+        from .search_host import _finish_masks
 
         # Chaos-plane boundary: one round = one device dispatch.
         maybe_fault(
