@@ -100,10 +100,20 @@ class StepRing:
     counted in `dropped_steps`); the running TOTALS (`steps`,
     `generated_total`, `claimed_total`) stay exact for appended rows and for
     every drained row that was still resident in the device ring.
+
+    `tile`: the rows of one queue-append tile of a device engine that
+    appends by tiles (`append_new_dus`; 0 otherwise). Each drained row then
+    adds ceil(claimed / tile) to `append_tiles_total` (per shard row on a
+    mesh, since each chip writes its own tiles), computed here on the host
+    and never on the device. Steps that fell off the device ring before a
+    drain (`dropped_steps`) are not counted, and in a tiered run the
+    suspect buffer's own tiles are not told apart from the queue's.
     """
 
-    def __init__(self, capacity: int = 4096):
+    def __init__(self, capacity: int = 4096, tile: int = 0):
         self.capacity = max(int(capacity), 1)
+        self.tile = int(tile)
+        self.append_tiles_total = 0
         self._rows: list[np.ndarray] = []  # uint32[N_COLS] each
         self._times_us: list[float] = []  # per-step wall times (host engines)
         self._chunk_times: list[tuple[int, float]] = []  # (steps, avg_us)
@@ -117,7 +127,7 @@ class StepRing:
     def fresh(self) -> "StepRing":
         """A new empty ring with the same capacity (engines start one per
         search so resumed runs keep accumulating and fresh runs do not)."""
-        return StepRing(self.capacity)
+        return StepRing(self.capacity, self.tile)
 
     def skip_to(self, steps: int) -> None:
         """Mark steps [0, steps) as having happened elsewhere (checkpoint
@@ -178,6 +188,11 @@ class StepRing:
 
     # -- device-ring drains ----------------------------------------------------
 
+    def _count_tiles(self, claimed: np.ndarray) -> None:
+        if self.tile:
+            c = claimed.astype(np.int64)
+            self.append_tiles_total += int(((c + self.tile - 1) // self.tile).sum())
+
     def drain(
         self,
         ring: np.ndarray,
@@ -194,7 +209,7 @@ class StepRing:
         if steps_total < self._drained:
             # The engine restarted its step counter under us (fresh search on
             # a reused ring): start over rather than mis-slice.
-            self.__init__(self.capacity)
+            self.__init__(self.capacity, self.tile)
         new = steps_total - self._drained
         if new <= 0:
             return 0
@@ -211,6 +226,7 @@ class StepRing:
         rows = np.ascontiguousarray(ring[idx])
         self.generated_total += int(rows[:, _I["generated"]].sum())
         self.claimed_total += int(rows[:, _I["claimed"]].sum())
+        self._count_tiles(rows[:, _I["claimed"]])
         self._extend(rows)
         self.steps = steps_total
         self._drained = steps_total
@@ -234,7 +250,7 @@ class StepRing:
         Also accumulates per-shard claimed totals for the imbalance digest."""
         steps_total = int(steps_total)
         if steps_total < self._drained:
-            self.__init__(self.capacity)
+            self.__init__(self.capacity, self.tile)
         N = rings.shape[0]
         if self.per_shard_claimed is None:
             self.per_shard_claimed = np.zeros(N, dtype=np.int64)
@@ -265,6 +281,7 @@ class StepRing:
         self.generated_total += int(shard_rows[:, :, _I["generated"]].sum())
         self.claimed_total += int(shard_rows[:, :, _I["claimed"]].sum())
         self.per_shard_claimed += shard_rows[:, :, _I["claimed"]].sum(axis=1)
+        self._count_tiles(shard_rows[:, :, _I["claimed"]])
         self._extend(rows)
         self.steps = steps_total
         self._drained = steps_total

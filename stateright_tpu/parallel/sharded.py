@@ -46,6 +46,7 @@ Sharding invariants:
 from __future__ import annotations
 
 import time
+from functools import partial
 from typing import NamedTuple, Optional
 
 import numpy as np
@@ -64,6 +65,7 @@ from ..tensor.frontier import (
     SearchResult,
     append_new,
     append_new_dus,
+    append_rows,
     resolve_append,
     count_add,
     count_ge,
@@ -247,6 +249,7 @@ class ShardedSearch(SearchHost):
             "sharded", model, batch_size, table_log2, insert_variant, store,
             (high_water, low_water, summary_log2), telemetry, telemetry_log2,
             tracer, step_claims=self.n_chips * self.dest_capacity,
+            append_tile=batch_size if self.append == "dus" else 0,
         )
         # The exchange counters; `_exchange_mark` is the (steps, generated)
         # point up to which the exchange is counted.
@@ -313,17 +316,24 @@ class ShardedSearch(SearchHost):
         _insert = resolve_insert(self.insert_variant)
         _insert_step = resolve_insert(self.insert_variant, summary_cfg=s_cfg)
         _fused = getattr(_insert_step, "fused_summary", False)
-        _append = append_new if self.append == "scatter" else append_new_dus
+        # The DUS append writes tiles of T = K rows (a chip's batch) out of
+        # the N*C received lanes, so it can write NC_T = N*C rounded up to
+        # whole tiles past the tail.
+        _append = (
+            append_new if self.append == "scatter"
+            else partial(append_new_dus, tile=K)
+        )
+        NC_T = append_rows(N * C, K)
         SQ = self._SQ
         TMR = self._TMR
-        # N*C rows of slack beyond the per-shard table size: the append
-        # block is N*C rows, and the DUS variant's contract requires the
-        # start never to clamp (append_new_dus docstring) — without the
+        # NC_T rows of slack beyond the per-shard table size: the append
+        # writes up to NC_T rows, and the DUS variant's contract requires
+        # the start never to clamp (append_new_dus docstring) — without the
         # slack a near-full queue would silently overwrite live rows.
         # Tiered runs add SQ more rows of slack for the host's
         # suspect-injection block (the live frontier stays bounded by S:
         # the tail is host-compacted at every service exit).
-        Q = S + N * C + (SQ if tiered else 0)
+        Q = S + NC_T + (SQ if tiered else 0)
         self._Q = Q
         props = self.props
         P_ = len(props)
@@ -632,7 +642,7 @@ class ShardedSearch(SearchHost):
                         )
                     service = (
                         (hot_claims >= TRIGGER)
-                        | (s_tail > SQ - N * C)
+                        | (s_tail > SQ - NC_T)
                         | (tail > S)
                     )
                     q_fatal = jnp.bool_(False)  # host decides after compaction
@@ -640,12 +650,12 @@ class ShardedSearch(SearchHost):
                     s_states, s_lo, s_hi = c.s_states, c.s_lo, c.s_hi
                     s_ebits, s_depth, s_tail = c.s_ebits, c.s_depth, c.s_tail
                     service = jnp.bool_(False)
-                    # Queue-full guard: the N*C append-block slack keeps
-                    # both append variants in bounds, and pop_batch's K-row
+                    # Queue-full guard: the NC_T append slack keeps both
+                    # append variants in bounds, and pop_batch's K-row
                     # dynamic_slice must never clamp either (dest_capacity
                     # may be set below K), so the bound is the stricter of
                     # the two.
-                    q_fatal = tail > Q - max(N * C, K)
+                    q_fatal = tail > Q - max(NC_T, K)
 
                 unique_count = c.unique_count + new_count
                 overflow = (

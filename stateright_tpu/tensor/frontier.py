@@ -230,13 +230,30 @@ def resolve_append(append, platform: str) -> str:
     return append
 
 
+def append_rows(lanes: int, tile: int) -> int:
+    """The most rows one `append_new_dus` call over `lanes` lanes writes
+    past the tail: whole tiles of min(tile, lanes) rows. Queue slack and
+    queue-full guards are sized by it, so the DUS start never clamps."""
+    t = min(tile, lanes)
+    return -(-lanes // t) * t
+
+
 def append_new_dus(  # srlint: step-region
     q_states, q_lo, q_hi, q_ebits, q_depth, tail,
-    flat, slo, shi, ebits_rows, depth_rows, is_new,
+    flat, slo, shi, ebits_rows, depth_rows, is_new, *, tile, fan=1,
 ):
-    """DUS-append: compact the is_new rows to the front of an M-row block,
-    then write the block at the queue tail with ONE contiguous
-    `dynamic_update_slice` per queue array.
+    """DUS-append: write the is_new rows at the queue tail, in lane order,
+    as `tile`-row blocks, each with ONE contiguous `dynamic_update_slice`
+    per queue array.
+
+    Compaction is by lane index, not by row: one int32 scatter maps each
+    compacted row to its source lane (rows past the new count map to M,
+    the padding lane, which gathers zeros); then each of the
+    ceil(new_count / tile) tiles gathers its rows of every column and
+    writes them. A step with few new rows runs one tile, whatever M is.
+    `fan`: `ebits_rows` and `depth_rows` hold one entry per `fan`
+    consecutive lanes (lane i reads entry i // fan), so an engine passes
+    its popped batch's columns without expanding them to M lanes.
 
     Why this exists next to `append_new` (whole-array scatter): XLA reliably
     updates a DUS'd while-loop carry IN PLACE, while the equivalent scatter
@@ -244,24 +261,44 @@ def append_new_dus(  # srlint: step-region
     batch 8192, table 2^27: ~77% of per-step execution time was `copy.*`
     thunks in the round-4 CPU trace — the round-3 "staged append-DUS
     experiment" evidence). CONTRACT: the caller must allocate Q >= max_tail
-    + M slack (the resident engine uses Q = S + K*A) so the DUS start never
-    clamps; rows [tail + new_count, tail + M) become zero scratch beyond the
-    tail, which nothing reads (pops are bounded by tail)."""
+    + append_rows(M, tile) slack so the DUS start never clamps (a clamped
+    start would shift live rows); rows past tail + new_count up to the last
+    tile's end become zero scratch beyond the tail, which nothing reads
+    (pops are bounded by tail)."""
     M, L = flat.shape
+    T = min(tile, M)
+    MP = append_rows(M, T)
     pos_all = jnp.cumsum(is_new.astype(jnp.int32)) - 1
-    pos = jnp.where(is_new, pos_all, M)
-    blk = jnp.zeros((M, L), flat.dtype).at[pos].set(flat, mode="drop")
-    b_lo = jnp.zeros(M, q_lo.dtype).at[pos].set(slo, mode="drop")
-    b_hi = jnp.zeros(M, q_hi.dtype).at[pos].set(shi, mode="drop")
-    b_eb = jnp.zeros(M, q_ebits.dtype).at[pos].set(ebits_rows, mode="drop")
-    b_dp = jnp.zeros(M, q_depth.dtype).at[pos].set(depth_rows, mode="drop")
-    q_states = jax.lax.dynamic_update_slice(q_states, blk, (tail, 0))
-    q_lo = jax.lax.dynamic_update_slice(q_lo, b_lo, (tail,))
-    q_hi = jax.lax.dynamic_update_slice(q_hi, b_hi, (tail,))
-    q_ebits = jax.lax.dynamic_update_slice(q_ebits, b_eb, (tail,))
-    q_depth = jax.lax.dynamic_update_slice(q_depth, b_dp, (tail,))
-    tail = tail + is_new.sum().astype(jnp.int32)
-    return q_states, q_lo, q_hi, q_ebits, q_depth, tail
+    n_new = is_new.sum().astype(jnp.int32)
+    src = jnp.full(MP, M, jnp.int32).at[jnp.where(is_new, pos_all, MP)].set(
+        jnp.arange(M, dtype=jnp.int32), mode="drop", unique_indices=True
+    )
+
+    def take(col, ix):
+        return col.at[ix].get(mode="fill", fill_value=0)
+
+    def write_tile(t, q):
+        rows = jax.lax.dynamic_slice(src, (t * T,), (T,))
+        par = rows // fan if fan > 1 else rows
+        at = tail + t * T
+        qs, ql, qh, qe, qd = q
+        # The state rows are gathered a lane column at a time: with one row
+        # gather XLA laid the queue out row-major and copied all of it back
+        # to column-major for the next pop, every step (paxos-3 on a v5e).
+        states = jnp.stack([take(flat[:, j], rows) for j in range(L)], axis=1)
+        return (
+            jax.lax.dynamic_update_slice(qs, states, (at, 0)),
+            jax.lax.dynamic_update_slice(ql, take(slo, rows), (at,)),
+            jax.lax.dynamic_update_slice(qh, take(shi, rows), (at,)),
+            jax.lax.dynamic_update_slice(qe, take(ebits_rows, par), (at,)),
+            jax.lax.dynamic_update_slice(qd, take(depth_rows, par), (at,)),
+        )
+
+    q_states, q_lo, q_hi, q_ebits, q_depth = jax.lax.fori_loop(
+        0, (n_new + T - 1) // T, write_tile,
+        (q_states, q_lo, q_hi, q_ebits, q_depth),
+    )
+    return q_states, q_lo, q_hi, q_ebits, q_depth, tail + n_new
 
 
 def compact_new(flat, slo, shi, is_new):

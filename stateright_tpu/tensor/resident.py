@@ -220,6 +220,7 @@ class ResidentSearch(SearchHost):
             "resident", model, batch_size, table_log2, insert_variant, store,
             (high_water, low_water, summary_log2), telemetry, telemetry_log2,
             tracer, step_claims=batch_size * model.max_actions,
+            append_tile=batch_size if self.append == "dus" else 0,
         )
         self._kernel, self._seed_k, self._chunk_k = self._build()
         # The model's definition and every argument `_build` reads: engines
@@ -275,7 +276,14 @@ class ResidentSearch(SearchHost):
         K = self.batch_size
         A = model.max_actions
         L = model.lanes
-        _append = append_new if self.append == "scatter" else append_new_dus
+        # The DUS append writes tiles of one batch (K rows) and reads the
+        # popped batch's ebits/depth columns unexpanded (fan=A: lane i's
+        # parent is row i // A); the scatter append takes one per lane.
+        scatter = self.append == "scatter"
+        _append = (
+            append_new if scatter
+            else partial(append_new_dus, tile=K, fan=A)
+        )
         kv = self.table_layout == "kv"
         S = 1 << self.table_log2
         tiered = self._store is not None
@@ -302,11 +310,12 @@ class ResidentSearch(SearchHost):
         # right-sized the queue below the table — see __init__), plus K*A
         # rows of slack so either append variant (scatter `append_new` —
         # the default; measured faster than `append_new_dus` on CPU at
-        # 2pc-10 scale — or the DUS block) stays in bounds right up to the
-        # overflow signal. Tiered runs add SQ more rows: the live frontier
-        # is still bounded by 2^queue_log2 (uniques beyond the table spill,
-        # and the tail is host-compacted at each service exit), and the
-        # extra slack guarantees the suspect-injection block always fits.
+        # 2pc-10 scale — or the DUS tiles: append_rows(K*A, K) == K*A)
+        # stays in bounds right up to the overflow signal. Tiered runs add
+        # SQ more rows: the live frontier is still bounded by 2^queue_log2
+        # (uniques beyond the table spill, and the tail is host-compacted at
+        # each service exit), and the extra slack guarantees the
+        # suspect-injection block always fits.
         QL = 1 << self.queue_log2
         Q = QL + K * A + (SQ if tiered else 0)
         self._Q = Q
@@ -397,11 +406,14 @@ class ResidentSearch(SearchHost):
             enq = is_new & ~suspect if tiered else is_new
 
             # -- append new states to the queue tail (cumsum compaction) -------
-            src_row = jnp.arange(K * A, dtype=jnp.int32) // A
+            eb_rows, dp_rows = ebits, depth + 1
+            if scatter:
+                src_row = jnp.arange(K * A, dtype=jnp.int32) // A
+                eb_rows, dp_rows = eb_rows[src_row], dp_rows[src_row]
             with jax.named_scope("append"):
                 q_states, q_lo, q_hi, q_ebits, q_depth, tail = _append(
                     c.q_states, c.q_lo, c.q_hi, c.q_ebits, c.q_depth, c.tail,
-                    flat, slo, shi, ebits[src_row], depth[src_row] + 1, enq,
+                    flat, slo, shi, eb_rows, dp_rows, enq,
                 )
             new_count = tail - c.tail
             hot_claims = c.hot_claims + is_new.sum().astype(jnp.int32)
@@ -411,9 +423,7 @@ class ResidentSearch(SearchHost):
                         s_states, s_lo, s_hi, s_ebits, s_depth, s_tail,
                     ) = _append(
                         c.s_states, c.s_lo, c.s_hi, c.s_ebits, c.s_depth,
-                        c.s_tail,
-                        flat, slo, shi, ebits[src_row], depth[src_row] + 1,
-                        suspect,
+                        c.s_tail, flat, slo, shi, eb_rows, dp_rows, suspect,
                     )
                 # Host-service exits (non-fatal): spill trigger crossed,
                 # suspect buffer near capacity, or queue tail past the

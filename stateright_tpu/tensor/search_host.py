@@ -388,6 +388,7 @@ class SearchHost:
         self, engine: str, model, batch_size: int, table_log2: int,
         insert_variant: str, store: str, store_args: tuple,
         telemetry: bool, telemetry_log2: int, tracer, step_claims: int,
+        append_tile: int,
     ) -> None:
         """The host state both engines keep; `engine` names the registry
         source, the stage spans and the calibration record.
@@ -401,7 +402,9 @@ class SearchHost:
         between dispatches, so the spill trigger keeps that much headroom;
         the suspect buffer holds two steps of accumulation and one of
         append slack. `telemetry` keeps a ring of 2^telemetry_log2
-        obs.STEP_COLS rows a shard in the carry."""
+        obs.STEP_COLS rows a shard in the carry; `append_tile` is the rows
+        of the queue append's tile (0 for the scatter append), from which
+        the drain counts `append_tiles_total`."""
         self.model = model
         self.props = model.properties()
         self.batch_size = batch_size
@@ -435,7 +438,7 @@ class SearchHost:
                 )
             self._SQ = 3 * step_claims
         self._TMR = (1 << telemetry_log2) if telemetry else 0
-        self._ring = StepRing(self._TMR) if telemetry else None
+        self._ring = StepRing(self._TMR, append_tile) if telemetry else None
         self._tracer = as_tracer(tracer)
         # The whole-search call in jit's stages, each timed and spanned
         # (`<engine>.trace/lower/compile/execute`), and the path counters
@@ -523,6 +526,7 @@ class SearchHost:
                 steps=self._ring.steps,
                 generated_states=self._ring.generated_total,
                 claimed_states=self._ring.claimed_total,
+                append_tiles_total=self._ring.append_tiles_total,
             )
         stats = self.store_stats()
         if stats:

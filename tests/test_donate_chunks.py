@@ -46,48 +46,163 @@ def test_sharded_donated_chunked_run_matches_goldens():
     assert sum(r.detail["per_chip_unique"]) == 1568
 
 
-def test_append_variants_agree():
-    """`append_new_dus` (kept for a TPU re-race; round 4 found
-    scatter wins on CPU) must stay semantically identical to `append_new`
-    on the rows that matter: [0, tail) after any append sequence."""
+_T = 8  # the DUS append's tile in the cases below
+
+# (lanes M, new lanes per append or None for random, fan): n_new at the tile
+# edges, none and all new, M not a multiple of the tile (the four-chip
+# engine's received block), and the one-chip engine's unexpanded parent
+# columns (fan = actions per parent row).
+_APPEND_CASES = {
+    "random": (24, None, 1),
+    "none-new": (24, 0, 1),
+    "all-new": (24, 24, 1),
+    "n-eq-T": (24, _T, 1),
+    "n-eq-T-1": (24, _T - 1, 1),
+    "n-eq-T+1": (24, _T + 1, 1),
+    "n-eq-2T+1": (24, 2 * _T + 1, 1),
+    "ragged-M-random": (20, None, 1),
+    "ragged-M-all-new": (20, 20, 1),
+    "fan-3": (24, None, 3),
+}
+
+
+def _new_mask(rng, M, n):
+    if n is None:
+        return rng.random(M) < 0.5
+    mask = np.zeros(M, bool)
+    mask[rng.choice(M, n, replace=False)] = True
+    return mask
+
+
+@pytest.mark.parametrize("case", list(_APPEND_CASES))
+def test_append_variants_agree(case):
+    """`append_new_dus` (the TPU default; tiles of `_T` rows gathered by
+    lane index) must stay identical to `append_new` (whole-array scatter)
+    on the rows that matter, [0, tail), and on the tail, after any append
+    sequence."""
+    from functools import partial
+
+    import jax
     import jax.numpy as jnp
 
-    from stateright_tpu.tensor.frontier import append_new, append_new_dus
+    from stateright_tpu.tensor.frontier import (
+        append_new,
+        append_new_dus,
+        append_rows,
+    )
 
-    Q, L, M = 64, 3, 8
+    M, n, fan = _APPEND_CASES[case]
+    L, rounds = 3, 4
+    Q = rounds * M + append_rows(M, _T)
+    dus = jax.jit(partial(append_new_dus, tile=_T, fan=fan))
+    scatter = jax.jit(append_new)
 
-    def run(append, rng):
-        qs = jnp.zeros((Q, L), jnp.uint32)
-        ql = jnp.zeros(Q, jnp.uint32)
-        qh = jnp.zeros(Q, jnp.uint32)
-        qe = jnp.zeros(Q, jnp.uint32)
-        qd = jnp.zeros(Q, jnp.uint32)
+    def run(dus_kind, rng):
+        q = [jnp.zeros((Q, L), jnp.uint32)] + [
+            jnp.zeros(Q, jnp.uint32) for _ in range(4)
+        ]
         tail = jnp.int32(0)
-        for _ in range(4):
+        for _ in range(rounds):
             flat = jnp.asarray(rng.integers(1, 99, (M, L), dtype=np.uint32))
             lo = jnp.asarray(rng.integers(1, 99, M, dtype=np.uint32))
-            hi = lo + 1
-            eb = jnp.zeros(M, jnp.uint32)
-            dp = jnp.ones(M, jnp.uint32)
-            new = jnp.asarray(rng.random(M) < 0.5)
-            qs, ql, qh, qe, qd, tail = append(
-                qs, ql, qh, qe, qd, tail, flat, lo, hi, eb, dp, new
-            )
+            eb = rng.integers(1, 99, M // fan, dtype=np.uint32)
+            dp = rng.integers(1, 99, M // fan, dtype=np.uint32)
+            new = jnp.asarray(_new_mask(rng, M, n))
+            if dus_kind:
+                *q, tail = dus(*q, tail, flat, lo, lo + 1, eb, dp, new)
+            else:
+                eb, dp = np.repeat(eb, fan), np.repeat(dp, fan)
+                *q, tail = scatter(*q, tail, flat, lo, lo + 1, eb, dp, new)
         t = int(tail)
-        return (
-            np.asarray(qs)[:t],
-            np.asarray(ql)[:t],
-            np.asarray(qh)[:t],
-            np.asarray(qe)[:t],
-            np.asarray(qd)[:t],
-            t,
-        )
+        return [np.asarray(a)[:t] for a in q], t
 
-    a = run(append_new, np.random.default_rng(3))
-    b = run(append_new_dus, np.random.default_rng(3))
-    assert a[5] == b[5]
-    for x, y in zip(a[:5], b[:5]):
+    a, ta = run(False, np.random.default_rng(3))
+    b, tb = run(True, np.random.default_rng(3))
+    assert ta == tb
+    if n is not None:
+        assert ta == rounds * n
+    for x, y in zip(a, b):
         assert np.array_equal(x, y)
+
+
+def test_append_dus_at_the_queue_full_guard():
+    """At the largest tail the four-chip engine's queue-full guard admits
+    (Q - max(append_rows(M, T), K)), an all-new append with M not a
+    multiple of the tile fills the queue to its last row: no DUS start
+    clamps, so no row already written moves."""
+    import jax.numpy as jnp
+
+    from stateright_tpu.tensor.frontier import append_new_dus, append_rows
+
+    M, L, S = 20, 3, 40
+    Q = S + append_rows(M, _T)
+    tail = Q - max(append_rows(M, _T), _T)
+    assert append_rows(M, _T) == 24 and tail + append_rows(M, _T) == Q
+    old = np.arange(Q * L, dtype=np.uint32).reshape(Q, L) + 1000
+    col = np.arange(Q, dtype=np.uint32) + 1000
+    flat = np.arange(M * L, dtype=np.uint32).reshape(M, L) + 1
+    lane = np.arange(M, dtype=np.uint32) + 1
+    qs, ql, qh, qe, qd, t = append_new_dus(
+        jnp.asarray(old), *(jnp.asarray(col) for _ in range(4)),
+        jnp.int32(tail), jnp.asarray(flat), jnp.asarray(lane),
+        jnp.asarray(lane + 100), jnp.asarray(lane + 200),
+        jnp.asarray(lane + 300), jnp.ones(M, bool), tile=_T,
+    )
+    assert int(t) == tail + M
+    assert np.array_equal(np.asarray(qs)[:tail], old[:tail])
+    assert np.array_equal(np.asarray(qs)[tail:tail + M], flat)
+    for q, off in zip((ql, qh, qe, qd), (0, 100, 200, 300)):
+        assert np.array_equal(np.asarray(q)[:tail], col[:tail])
+        assert np.array_equal(np.asarray(q)[tail:tail + M], lane + off)
+
+
+def _tiles_within_bounds(search, r, tile, chips=1):
+    """`append_tiles_total` (host-side, from the drained telemetry) lies
+    between the steps that claimed anything and ceil(claimed / T) plus one
+    partial tile per step and chip."""
+    m = search.metrics()
+    assert r.detail["telemetry"]["dropped_steps"] == 0
+    claimed = search._ring._col("claimed").astype(np.int64)
+    assert m["claimed_states"] == claimed.sum()
+    assert (claimed > 0).sum() <= m["append_tiles_total"]
+    assert m["append_tiles_total"] <= (
+        -(-m["claimed_states"] // tile) + r.steps * chips
+    )
+
+
+@pytest.mark.parametrize(
+    "model, batch, log2, golden",
+    [
+        ("2pc4", 256, 13, (8258, 1568)),
+        ("paxos2", 2048, 16, (32971, 16668)),
+    ],
+)
+def test_dus_append_goldens(model, batch, log2, golden):
+    from stateright_tpu.tensor.paxos import TensorPaxos
+
+    m = (
+        TensorTwoPhaseSys(4) if model == "2pc4"
+        else TensorPaxos(client_count=2)
+    )
+    rs = ResidentSearch(m, batch, log2, append="dus")
+    r = rs.run()
+    assert (r.state_count, r.unique_state_count) == golden
+    assert r.complete
+    _tiles_within_bounds(rs, r, batch)
+
+
+def test_sharded_dus_append_golden():
+    from stateright_tpu.parallel import ShardedSearch, make_mesh
+
+    ss = ShardedSearch(
+        TensorTwoPhaseSys(4), mesh=make_mesh(8), batch_size=128,
+        table_log2=11, append="dus",
+    )
+    r = ss.run()
+    assert (r.state_count, r.unique_state_count) == (8258, 1568)
+    assert r.complete
+    assert sum(r.detail["per_chip_unique"]) == 1568
+    _tiles_within_bounds(ss, r, 128, chips=8)
 
 
 def test_whole_search_overflow_invalidates_snapshot():

@@ -95,6 +95,28 @@ def test_ring_drain_sharded_aggregates_and_imbalance():
     assert s["steps"] == 4 and s["dropped_steps"] == 0
 
 
+def test_ring_counts_append_tiles_per_step_and_shard():
+    # ceil(claimed / tile) per drained row: 0, 4, 5, 9 claims in tiles of 4
+    # are 0 + 1 + 2 + 3 tiles; a scatter-append ring (tile 0) counts none.
+    claims = (0, 4, 5, 9)
+    dev = _device_ring({i: _row(i, claimed=c) for i, c in enumerate(claims)}, 8)
+    ring, plain = StepRing(8, tile=4), StepRing(8)
+    ring.drain(dev, 4)
+    plain.drain(dev, 4)
+    assert (ring.append_tiles_total, plain.append_tiles_total) == (6, 0)
+    # Each chip writes its own tiles: 5 + 5 claims are 2 + 2 tiles a step,
+    # not ceil(10 / 4).
+    rings = np.zeros((2, 8, N_COLS), dtype=np.uint32)
+    rings[:, :3] = [_row(i, claimed=5) for i in range(3)]
+    sharded = StepRing(8, tile=4)
+    sharded.drain_sharded(rings, 3)
+    assert sharded.append_tiles_total == 3 * (2 + 2)
+    # A fresh ring, or one whose engine restarted, keeps its tile.
+    assert ring.fresh().tile == 4
+    ring.drain(_device_ring({0: _row(0, claimed=1)}, 8), 1)
+    assert (ring.tile, ring.append_tiles_total) == (4, 1)
+
+
 def test_ring_summary_keys_match_schema():
     ring = StepRing(8)
     ring.append(active=4, generated=10, claimed=5, queue_len=7,
