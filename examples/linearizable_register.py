@@ -28,6 +28,27 @@ def main():
             .threads(thread_count())
             .spawn_dfs()
         )
+    elif cmd == "check-tpu":
+        client_count = argv_int(2, 2)
+        if client_count > 2:
+            print(
+                "The device check is sized for at most 2 clients (2 clients "
+                "reach 41,138,199 unique states, a 2^27 table); for more, "
+                "check on the host (check)."
+            )
+            return
+        print(
+            f"Model checking a linearizable register with {client_count} "
+            "clients on the device frontier checker."
+        )
+        from stateright_tpu.tensor.abd import TensorAbd
+
+        size = (
+            dict(batch_size=1024, table_log2=14)
+            if client_count < 2
+            else dict(batch_size=8192, table_log2=27, queue_log2=26)
+        )
+        report(TensorAbd(client_count=client_count).checker().spawn_tpu(**size))
     elif cmd == "explore":
         client_count = argv_int(2, 2)
         address = argv_str(3, "localhost:3000")
@@ -64,6 +85,7 @@ def main():
     else:
         print("USAGE:")
         print("  ./linearizable_register.py check [CLIENT_COUNT] [NETWORK]")
+        print("  ./linearizable_register.py check-tpu [CLIENT_COUNT<=2]")
         print("  ./linearizable_register.py explore [CLIENT_COUNT] [ADDRESS] [NETWORK]")
         print("  ./linearizable_register.py spawn")
         print(f"NETWORK: {network_names()}")

@@ -210,7 +210,8 @@ class TpuChecker(Checker):
             maybe_fault(
                 "checker.run", engine=type(self._search).__name__
             )
-            with self._search._tracer.span("search.run", cat="checker"):
+            with self._search._tracer.span("search.run", cat="checker",
+                                           **self._model.trace_args()):
                 self._result = self._search.run(**kwargs)
             if self._recorder is not None:
                 from ..core.visitor import StateRecorder

@@ -35,9 +35,11 @@ from .lowering import (
     refine_check,
 )
 from .simulation import DeviceSimulation
+from .abd import TensorAbd
 
 __all__ = [
     "DeviceSimulation",
+    "TensorAbd",
     "TensorModelAdapter",
     "as_host_model",
     "TensorModel",

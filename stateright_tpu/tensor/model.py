@@ -109,6 +109,12 @@ class TensorModel:
     def format_step(self, last_state, action) -> Any:
         return None
 
+    def trace_args(self) -> dict:
+        """The constants a check's `search.run` span records as its args,
+        so that a trace says what it ran: `lanes` and `max_actions`, and
+        what a model adds (vocabulary size, table rows, pool slots)."""
+        return {"lanes": self.lanes, "max_actions": self.max_actions}
+
     def definition_key(self) -> Optional[Hashable]:
         """The model's definition, or None when it cannot be named (see the
         class docstring)."""

@@ -18,16 +18,9 @@ per-field comments):
   encoding canonical, and duplicate-id action slots are masked so the action
   enumeration matches the host's one-Deliver-per-distinct-envelope exactly.
 - Each server packs into two lanes (ballot/proposal/accepted/decided/accepts
-  and the per-peer `prepares` entries); each client packs into 8 bits of one
-  shared lane (phase, read return value, and the real-time frontier its Get
-  captured — everything the LinearizabilityTester state adds to the checker
-  state for this workload).
-- The linearizability property enumerates, at build time, every interleaving
-  of the <= 2C client ops that respects per-thread order (puts are mandatory
-  once completed, in-flight ops optional — ref:
-  src/semantics/linearizability.rs:193-280), compiles each to constant
-  constraint tables, and evaluates ALL of them branchlessly per state batch:
-  an exhaustive linearizability check as a TPU mask.
+  and the per-peer `prepares` entries); the clients share one lane, 8 bits
+  each, and the linearizability property is a mask over that lane: both
+  are `tensor/register.py`'s `RegisterClients`, which TensorAbd uses too.
 
 Count parity with the host model was validated against the 16,668-state
 golden (tests/test_tensor_paxos.py).
@@ -39,16 +32,14 @@ from dataclasses import dataclass
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 
 from .model import TensorModel, TensorProperty, dataclass_definition_key
 from .poolops import rank_sort
+from .register import PH_GET_INFLIGHT, PH_PUT_INFLIGHT, RegisterClients
 
 EMPTY = np.uint32(0xFFFFFFFF)
-
-# Client phases (host RegisterClient with put_count=1 never rests between
-# PutOk and the Get send, so only three phases exist).
-PH_PUT_INFLIGHT, PH_GET_INFLIGHT, PH_DONE = 0, 1, 2
 
 
 def _bits(n_values: int) -> int:
@@ -75,10 +66,9 @@ class TensorPaxos(TensorModel):
             # Broadcast emission slots and quorum arithmetic are laid out for
             # the reference's 3-server configuration (em1/em2 = the two peers).
             raise ValueError("TensorPaxos currently supports server_count=3")
-        if C > 3:
-            # 2-bit proposal field and 8-bit client field (2 phase + 2 ret +
-            # 2*(C-1) frontier bits) both cap C at 3.
-            raise ValueError("client field encoding supports client_count <= 3")
+        # The 2-bit proposal field and the 8-bit client field both cap C at
+        # 3 (RegisterClients raises above it).
+        self.clients = RegisterClients(C)
         self.NB = 1 + C * S  # ballot codes: 0 = (0, Id(0)); 1+(r-1)*S+l
         self.NLA = 1 + C * S * C  # last_accepted codes: 0 = None; 1+(b-1)*C+k
         self.bb = _bits(self.NB)
@@ -102,7 +92,6 @@ class TensorPaxos(TensorModel):
         self.max_actions = self.pool_size
 
         self._build_vocab()
-        self._build_lin_tables()
 
     def definition_key(self):
         return dataclass_definition_key(self, TensorPaxos)
@@ -199,109 +188,6 @@ class TensorPaxos(TensorModel):
             off += w
         self._PACKED = packed
 
-    def _build_lin_tables(self):
-        """Static interleaving enumeration for the on-device linearizability
-        mask. Each combo = (which ops are included, in which order); compiled
-        to: allowed-phase bitmask per client, expected Get return per client
-        (-1: no Get / unconstrained), and the max real-time frontier each
-        included Get tolerates toward each peer."""
-        C = self.client_count
-        NULL = -2  # register holds no client value yet
-
-        combos_phase, combos_ret, combos_maxf = [], [], []
-
-        def orders(included):
-            """All interleavings of the included ops (tuples of (client,
-            'p'|'g')) that keep each client's put before its get."""
-            ops = []
-            for c, pat in enumerate(included):
-                if pat >= 1:
-                    ops.append((c, "p"))
-                if pat == 2:
-                    ops.append((c, "g"))
-            seqs = [[]]
-            for _ in range(len(ops)):
-                nxt = []
-                for seq in seqs:
-                    used = set(seq)
-                    for op in ops:
-                        if op in used:
-                            continue
-                        if op[1] == "g" and (op[0], "p") not in used:
-                            continue
-                        nxt.append(seq + [op])
-                seqs = nxt
-            return seqs or [[]]
-
-        def gen(prefix):
-            if len(prefix) == C:
-                for seq in orders(prefix):
-                    # Phase constraints per client: pattern 0 (put excluded)
-                    # requires phase==PUT_INFLIGHT; pattern 1 (put only)
-                    # requires the get not completed; pattern 2 allows any
-                    # phase with the get in existence.
-                    pm, ret, maxf = [], [], []
-                    for c, pat in enumerate(prefix):
-                        if pat == 0:
-                            pm.append(1 << PH_PUT_INFLIGHT)
-                        elif pat == 1:
-                            pm.append((1 << PH_PUT_INFLIGHT) | (1 << PH_GET_INFLIGHT))
-                        else:
-                            pm.append((1 << PH_GET_INFLIGHT) | (1 << PH_DONE))
-                    # Replay the register through the sequence; expected value
-                    # of each included get is static.
-                    val = NULL
-                    expected = {c: None for c in range(C)}
-                    for c, kind in seq:
-                        if kind == "p":
-                            val = c
-                        else:
-                            expected[c] = val
-                    for c, pat in enumerate(prefix):
-                        if pat == 2:
-                            e = expected[c]
-                            ret.append(-1 if e == NULL else e)
-                        else:
-                            ret.append(-1 if pat < 2 else 0)
-                    # -1 ret with pattern 2 means: only an in-flight get can
-                    # satisfy this combo (a completed get returned a real
-                    # value, but the combo serializes it before any write).
-                    mf = [[2] * C for _ in range(C)]
-                    for c, pat in enumerate(prefix):
-                        if pat != 2:
-                            continue
-                        gpos = seq.index((c, "g"))
-                        for c2 in range(C):
-                            if c2 == c:
-                                continue
-                            before = set(seq[:gpos])
-                            if (c2, "p") not in before:
-                                mf[c][c2] = 0
-                            elif (c2, "g") not in before:
-                                mf[c][c2] = 1
-                    combos_phase.append(pm)
-                    combos_ret.append(ret)
-                    combos_maxf.append(mf)
-                return
-            for pat in (0, 1, 2):
-                gen(prefix + [pat])
-
-        gen([])
-        phase = np.asarray(combos_phase, np.uint32)  # [NC, C]
-        ret = np.asarray(combos_ret, np.int32)  # [NC, C]
-        maxf = np.asarray(combos_maxf, np.uint32)  # [NC, C, C]
-        # Distinct interleavings often compile to identical constraint rows
-        # (e.g. two puts both overwritten before any included read); dedupe —
-        # every row costs a [B, NC, C] mask evaluation in the hot loop.
-        stacked = np.concatenate(
-            [phase, ret.astype(np.int64), maxf.reshape(len(maxf), -1)], axis=1
-        )
-        _, keep = np.unique(stacked, axis=0, return_index=True)
-        keep = np.sort(keep)
-        self._lin_phase = phase[keep]
-        self._lin_ret = ret[keep]
-        self._lin_maxf = maxf[keep]
-
     # -- field unpack helpers (all shapes broadcast) ---------------------------
 
     def _srv_unpack(self, laneA):
@@ -338,241 +224,225 @@ class TensorPaxos(TensorModel):
         C, S, M = self.client_count, self.server_count, self.pool_size
         B = states.shape[0]
         u = jnp.uint32
-        pool = states[:, self.pool_off :]  # [B, M]
-        clients = states[:, self.client_lane]  # [B]
+        with jax.named_scope("deliver"):
+            pool = states[:, self.pool_off :]  # [B, M]
+            clients = states[:, self.client_lane]  # [B]
 
-        e = pool  # delivered envelope id per action slot
-        idx = jnp.minimum(e, u(self.V - 1)).astype(jnp.int32)
-        # ONE packed-table gather; fields unpack with fused shifts/masks
-        # (see _build_vocab — seven separate gathers dominated the expand
-        # fusion on v5e).
-        packed = jnp.take(jnp.asarray(self._PACKED), idx)
+            e = pool  # delivered envelope id per action slot
+            idx = jnp.minimum(e, u(self.V - 1)).astype(jnp.int32)
+            # ONE packed-table gather; fields unpack with fused shifts/masks
+            # (see _build_vocab — seven separate gathers dominated the expand
+            # fusion on v5e).
+            packed = jnp.take(jnp.asarray(self._PACKED), idx)
 
-        def field(name):
-            off, mask = self._field_off[name]
-            return (packed >> u(off)) & u(mask)
+            def field(name):
+                off, mask = self._field_off[name]
+                return (packed >> u(off)) & u(mask)
 
-        typ = field("typ")
-        dst = field("dst")
-        bal = field("bal")
-        prp = field("prp")
-        la_m = field("la")
-        src = field("src")
-        val = field("val")
+            typ = field("typ")
+            dst = field("dst")
+            bal = field("bal")
+            prp = field("prp")
+            la_m = field("la")
+            src = field("src")
+            val = field("val")
 
-        # One Deliver action per DISTINCT in-flight envelope (host parity:
-        # nonduplicating iter_deliverable yields distinct envelopes). The pool
-        # is sorted, so duplicates are adjacent.
-        nonempty = e != EMPTY
-        first = jnp.concatenate(
-            [jnp.ones((B, 1), bool), e[:, 1:] != e[:, :-1]], axis=1
-        )
-        deliverable = nonempty & first
+            # One Deliver action per DISTINCT in-flight envelope (host parity:
+            # nonduplicating iter_deliverable yields distinct envelopes). The pool
+            # is sorted, so duplicates are adjacent.
+            nonempty = e != EMPTY
+            first = jnp.concatenate(
+                [jnp.ones((B, 1), bool), e[:, 1:] != e[:, :-1]], axis=1
+            )
+            deliverable = nonempty & first
 
-        is_server_msg = (typ == 0) | (typ == 1) | (typ >= 4)
+            is_server_msg = (typ == 0) | (typ == 1) | (typ >= 4)
 
-        # Select the target server's lanes per action slot as a one-hot sum
-        # over the S=3 servers — branchless VPU selects fuse; a
-        # take_along_axis gather does not.
-        srvA_all = states[:, 0 : 2 * S : 2]  # [B, S]
-        srvB_all = states[:, 1 : 2 * S : 2]
-        d_srv = jnp.where(is_server_msg, dst, 0).astype(jnp.int32)
-        sA = jnp.zeros((B, M), u)
-        sB = jnp.zeros((B, M), u)
-        for s in range(S):
-            sel_s = d_srv == s
-            sA = jnp.where(sel_s, srvA_all[:, s : s + 1], sA)
-            sB = jnp.where(sel_s, srvB_all[:, s : s + 1], sB)
-        ballot, prop, accepted, decided, accepts = self._srv_unpack(sA)
-        not_dec = decided == 0
+            # Select the target server's lanes per action slot as a one-hot sum
+            # over the S=3 servers — branchless VPU selects fuse; a
+            # take_along_axis gather does not.
+            srvA_all = states[:, 0 : 2 * S : 2]  # [B, S]
+            srvB_all = states[:, 1 : 2 * S : 2]
+            d_srv = jnp.where(is_server_msg, dst, 0).astype(jnp.int32)
+            sA = jnp.zeros((B, M), u)
+            sB = jnp.zeros((B, M), u)
+            for s in range(S):
+                sel_s = d_srv == s
+                sA = jnp.where(sel_s, srvA_all[:, s : s + 1], sA)
+                sB = jnp.where(sel_s, srvB_all[:, s : s + 1], sB)
+            ballot, prop, accepted, decided, accepts = self._srv_unpack(sA)
+            not_dec = decided == 0
 
-        # Per-client fields of the delivered-to client (client msgs).
-        csh = (jnp.where(is_server_msg, 0, dst) * 8).astype(jnp.uint32)
-        cfield = (clients[:, None] >> csh) & u(0xFF)
-        cphase = cfield & u(3)
+            # Per-client fields of the delivered-to client (client msgs).
+            csh, cfield, cphase = self.clients.field(clients, dst, is_server_msg)
 
-        # ---- outcome scaffolding -------------------------------------------
-        nA, nB = sA, sB  # new server lanes
-        ncf = cfield  # new client field
-        em1 = jnp.full((B, M), EMPTY)  # up to three emissions
-        em2 = jnp.full((B, M), EMPTY)
-        em3 = jnp.full((B, M), EMPTY)
-        ok = jnp.zeros((B, M), bool)  # transition not elided
+        with jax.named_scope("server"):
+            # ---- outcome scaffolding -------------------------------------------
+            nA, nB = sA, sB  # new server lanes
+            ncf = cfield  # new client field
+            em1 = jnp.full((B, M), EMPTY)  # up to three emissions
+            em2 = jnp.full((B, M), EMPTY)
+            em3 = jnp.full((B, M), EMPTY)
+            ok = jnp.zeros((B, M), bool)  # transition not elided
 
-        maskS = u((1 << S) - 1)
+            maskS = u((1 << S) - 1)
 
-        def r_of(b):  # ballot code -> round
-            return jnp.where(b == 0, u(0), (b - 1) // u(S) + 1)
+            def r_of(b):  # ballot code -> round
+                return jnp.where(b == 0, u(0), (b - 1) // u(S) + 1)
 
-        # ---- Put (typ 0): propose (ref: examples/paxos.rs:163-183) ----------
-        g = (typ == 0) & not_dec & (prop == 0)
-        nb = u(1) + r_of(ballot) * u(S) + dst  # (r+1, dst)
-        prepB = (u(1) | (accepted << u(1))) << (dst * u(self.bprep)).astype(u)
-        nA = jnp.where(g, self._srv_pack(nb, prp + u(1), accepted, u(0), u(0)), nA)
-        nB = jnp.where(g, prepB, nB)
-        pre0 = u(self.PREPARE0) + (nb - u(1)) * u(S - 1)
-        em1 = jnp.where(g, pre0, em1)
-        em2 = jnp.where(g, pre0 + u(1), em2)
-        ok = ok | g
+            # ---- Put (typ 0): propose (ref: examples/paxos.rs:163-183) ----------
+            g = (typ == 0) & not_dec & (prop == 0)
+            nb = u(1) + r_of(ballot) * u(S) + dst  # (r+1, dst)
+            prepB = (u(1) | (accepted << u(1))) << (dst * u(self.bprep)).astype(u)
+            nA = jnp.where(g, self._srv_pack(nb, prp + u(1), accepted, u(0), u(0)), nA)
+            nB = jnp.where(g, prepB, nB)
+            pre0 = u(self.PREPARE0) + (nb - u(1)) * u(S - 1)
+            em1 = jnp.where(g, pre0, em1)
+            em2 = jnp.where(g, pre0 + u(1), em2)
+            ok = ok | g
 
-        # ---- Get (typ 1): reply when decided (ref: paxos.rs:145-157) --------
-        g = (typ == 1) & (decided == 1)
-        vprop = jnp.where(accepted > 0, (accepted - u(1)) % u(C), u(0))
-        em1 = jnp.where(g, u(self.GETOK0) + prp * u(C) + vprop, em1)
-        ok = ok | g  # state unchanged; reply makes it a real transition
+            # ---- Get (typ 1): reply when decided (ref: paxos.rs:145-157) --------
+            g = (typ == 1) & (decided == 1)
+            vprop = jnp.where(accepted > 0, (accepted - u(1)) % u(C), u(0))
+            em1 = jnp.where(g, u(self.GETOK0) + prp * u(C) + vprop, em1)
+            ok = ok | g  # state unchanged; reply makes it a real transition
 
-        # ---- Prepare (typ 4) (ref: paxos.rs:186-192) ------------------------
-        g = (typ == 4) & not_dec & (ballot < bal)
-        nA = jnp.where(g, self._srv_pack(bal, prop, accepted, u(0), accepts), nA)
-        lead = (bal - u(1)) % u(S)
-        slot = dst - (dst > lead)
-        em1 = jnp.where(
-            g,
-            u(self.PREPARED0)
-            + ((bal - u(1)) * u(S - 1) + slot) * u(self.NLA)
-            + accepted,
-            em1,
-        )
-        ok = ok | g
+            # ---- Prepare (typ 4) (ref: paxos.rs:186-192) ------------------------
+            g = (typ == 4) & not_dec & (ballot < bal)
+            nA = jnp.where(g, self._srv_pack(bal, prop, accepted, u(0), accepts), nA)
+            lead = (bal - u(1)) % u(S)
+            slot = dst - (dst > lead)
+            em1 = jnp.where(
+                g,
+                u(self.PREPARED0)
+                + ((bal - u(1)) * u(S - 1) + slot) * u(self.NLA)
+                + accepted,
+                em1,
+            )
+            ok = ok | g
 
-        # ---- Prepared (typ 5) (ref: paxos.rs:193-231) -----------------------
-        g = (typ == 5) & not_dec & (bal == ballot)
-        sslot = src  # replier server id
-        pbit = u(1) << (sslot * u(self.bprep)).astype(u)
-        already = (sB & pbit) != 0
-        addB = sB | pbit | (la_m << (sslot * u(self.bprep) + u(1)).astype(u))
-        # popcount of present bits after insertion
-        pres = jnp.zeros((B, M), u)
-        best_la = jnp.zeros((B, M), u)
-        for j in range(S):
-            pj = (addB >> u(j * self.bprep)) & u(1)
-            laj = (addB >> u(j * self.bprep + 1)) & u((1 << self.bla) - 1)
-            pres = pres + pj
-            best_la = jnp.maximum(best_la, jnp.where(pj == 1, laj, u(0)))
-        quorum = (~already) & (pres == self.maj)
-        chosen = jnp.where(
-            best_la > 0, (best_la - u(1)) % u(C), prop - u(1)
-        )  # proposal k
-        acc0 = u(self.ACCEPT0) + ((bal - u(1)) * u(C) + chosen) * u(S - 1)
-        em1 = jnp.where(g & quorum, acc0, em1)
-        em2 = jnp.where(g & quorum, acc0 + u(1), em2)
-        nA = jnp.where(
-            g,
-            jnp.where(
-                quorum,
-                self._srv_pack(
-                    ballot,
-                    chosen + u(1),
-                    u(1) + (bal - u(1)) * u(C) + chosen,  # accepted=(b, chosen)
-                    u(0),
-                    u(1) << dst,  # accepts = {self}
+            # ---- Prepared (typ 5) (ref: paxos.rs:193-231) -----------------------
+            g = (typ == 5) & not_dec & (bal == ballot)
+            sslot = src  # replier server id
+            pbit = u(1) << (sslot * u(self.bprep)).astype(u)
+            already = (sB & pbit) != 0
+            addB = sB | pbit | (la_m << (sslot * u(self.bprep) + u(1)).astype(u))
+            # popcount of present bits after insertion
+            pres = jnp.zeros((B, M), u)
+            best_la = jnp.zeros((B, M), u)
+            for j in range(S):
+                pj = (addB >> u(j * self.bprep)) & u(1)
+                laj = (addB >> u(j * self.bprep + 1)) & u((1 << self.bla) - 1)
+                pres = pres + pj
+                best_la = jnp.maximum(best_la, jnp.where(pj == 1, laj, u(0)))
+            quorum = (~already) & (pres == self.maj)
+            chosen = jnp.where(
+                best_la > 0, (best_la - u(1)) % u(C), prop - u(1)
+            )  # proposal k
+            acc0 = u(self.ACCEPT0) + ((bal - u(1)) * u(C) + chosen) * u(S - 1)
+            em1 = jnp.where(g & quorum, acc0, em1)
+            em2 = jnp.where(g & quorum, acc0 + u(1), em2)
+            nA = jnp.where(
+                g,
+                jnp.where(
+                    quorum,
+                    self._srv_pack(
+                        ballot,
+                        chosen + u(1),
+                        u(1) + (bal - u(1)) * u(C) + chosen,  # accepted=(b, chosen)
+                        u(0),
+                        u(1) << dst,  # accepts = {self}
+                    ),
+                    self._srv_pack(ballot, prop, accepted, u(0), accepts),
                 ),
-                self._srv_pack(ballot, prop, accepted, u(0), accepts),
-            ),
-            nA,
-        )
-        nB = jnp.where(g, addB, nB)
-        ok = ok | g
+                nA,
+            )
+            nB = jnp.where(g, addB, nB)
+            ok = ok | g
 
-        # ---- Accept (typ 6) (ref: paxos.rs:232-240) -------------------------
-        g = (typ == 6) & not_dec & (ballot <= bal)
-        nacc = u(1) + (bal - u(1)) * u(C) + prp
-        nA = jnp.where(g, self._srv_pack(bal, prop, nacc, u(0), accepts), nA)
-        lead = (bal - u(1)) % u(S)
-        slot = dst - (dst > lead)
-        em1 = jnp.where(g, u(self.ACCEPTED0) + (bal - u(1)) * u(S - 1) + slot, em1)
-        ok = ok | g
+            # ---- Accept (typ 6) (ref: paxos.rs:232-240) -------------------------
+            g = (typ == 6) & not_dec & (ballot <= bal)
+            nacc = u(1) + (bal - u(1)) * u(C) + prp
+            nA = jnp.where(g, self._srv_pack(bal, prop, nacc, u(0), accepts), nA)
+            lead = (bal - u(1)) % u(S)
+            slot = dst - (dst > lead)
+            em1 = jnp.where(g, u(self.ACCEPTED0) + (bal - u(1)) * u(S - 1) + slot, em1)
+            ok = ok | g
 
-        # ---- Accepted (typ 7) (ref: paxos.rs:241-263) -----------------------
-        g = (typ == 7) & not_dec & (bal == ballot)
-        abit = u(1) << src
-        naccs = (accepts | abit) & maskS
-        cnt = jnp.zeros((B, M), u)
-        for j in range(S):
-            cnt = cnt + ((naccs >> u(j)) & u(1))
-        aquorum = ((accepts & abit) == 0) & (cnt == self.maj)
-        dec0 = u(self.DECIDED0) + ((bal - u(1)) * u(C) + (prop - u(1))) * u(S - 1)
-        em1 = jnp.where(g & aquorum, dec0, em1)
-        em2 = jnp.where(g & aquorum, dec0 + u(1), em2)
-        em3 = jnp.where(
-            g & aquorum, u(self.PUTOK0) + dst * u(C) + (prop - u(1)), em3
-        )
-        nA = jnp.where(
-            g,
-            self._srv_pack(
-                ballot, prop, accepted, jnp.where(aquorum, u(1), u(0)), naccs
-            ),
-            nA,
-        )
-        ok = ok | g
+            # ---- Accepted (typ 7) (ref: paxos.rs:241-263) -----------------------
+            g = (typ == 7) & not_dec & (bal == ballot)
+            abit = u(1) << src
+            naccs = (accepts | abit) & maskS
+            cnt = jnp.zeros((B, M), u)
+            for j in range(S):
+                cnt = cnt + ((naccs >> u(j)) & u(1))
+            aquorum = ((accepts & abit) == 0) & (cnt == self.maj)
+            dec0 = u(self.DECIDED0) + ((bal - u(1)) * u(C) + (prop - u(1))) * u(S - 1)
+            em1 = jnp.where(g & aquorum, dec0, em1)
+            em2 = jnp.where(g & aquorum, dec0 + u(1), em2)
+            em3 = jnp.where(
+                g & aquorum, u(self.PUTOK0) + dst * u(C) + (prop - u(1)), em3
+            )
+            nA = jnp.where(
+                g,
+                self._srv_pack(
+                    ballot, prop, accepted, jnp.where(aquorum, u(1), u(0)), naccs
+                ),
+                nA,
+            )
+            ok = ok | g
 
-        # ---- Decided (typ 8) (ref: paxos.rs:264-271) ------------------------
-        g = (typ == 8) & not_dec
-        nacc = u(1) + (bal - u(1)) * u(C) + prp
-        nA = jnp.where(g, self._srv_pack(bal, prop, nacc, u(1), accepts), nA)
-        ok = ok | g
+            # ---- Decided (typ 8) (ref: paxos.rs:264-271) ------------------------
+            g = (typ == 8) & not_dec
+            nacc = u(1) + (bal - u(1)) * u(C) + prp
+            nA = jnp.where(g, self._srv_pack(bal, prop, nacc, u(1), accepts), nA)
+            ok = ok | g
 
-        # ---- PutOk (typ 2): client advances to Get --------------------------
-        # History effects in one transition: on_return(Write) then
-        # on_invoke(Read) with the real-time frontier captured from the other
-        # clients' CURRENT completed-op counts (ref:
-        # src/actor/model.rs:348-357 ordering; linearizability.rs:102-129).
-        g = (typ == 2) & (cphase == PH_PUT_INFLIGHT)
-        frontier = jnp.zeros((B, M), u)
-        fshift = u(0)
-        for c2 in range(C):
-            # completed ops of client c2: 0 / 1 / 2 by phase
-            f2 = (clients[:, None] >> u(8 * c2)) & u(3)
-            comp = jnp.where(f2 == PH_DONE, u(2), jnp.where(f2 == PH_GET_INFLIGHT, u(1), u(0)))
-            is_peer = dst != c2
-            frontier = frontier | jnp.where(is_peer, comp << fshift, u(0))
-            # peer slots are assigned in increasing client order, skipping self
-            fshift = fshift + jnp.where(is_peer, u(2), u(0))
-        ncf = jnp.where(g, u(PH_GET_INFLIGHT) | (frontier << u(4)), ncf)
-        em1 = jnp.where(g, u(self.GET0) + dst, em1)
-        ok = ok | g
+            # ---- PutOk (typ 2): client advances to Get --------------------------
+            g = (typ == 2) & (cphase == PH_PUT_INFLIGHT)
+            ncf = self.clients.put_ok(g, clients, dst, ncf)
+            em1 = jnp.where(g, u(self.GET0) + dst, em1)
+            ok = ok | g
 
-        # ---- GetOk (typ 3): client done -------------------------------------
-        g = (typ == 3) & (cphase == PH_GET_INFLIGHT)
-        ncf = jnp.where(g, (cfield & ~u(3) & ~u(3 << 2)) | u(PH_DONE) | (val << u(2)), ncf)
-        ok = ok | g
+            # ---- GetOk (typ 3): client done -------------------------------------
+            g = (typ == 3) & (cphase == PH_GET_INFLIGHT)
+            ncf = self.clients.get_ok(g, cfield, val, ncf)
+            ok = ok | g
 
-        valid = deliverable & ok
+            valid = deliverable & ok
 
-        # ---- assemble successors -------------------------------------------
-        # Server lanes: scatter the new pair back into the dst server's slot.
-        succ = jnp.broadcast_to(states[:, None, :], (B, M, self.lanes))
-        srv_sel = (
-            jnp.arange(S)[None, None, :] == d_srv[:, :, None]
-        ) & is_server_msg[:, :, None]  # [B, M, S]
-        newA = jnp.where(srv_sel, nA[:, :, None], srvA_all[:, None, :])
-        newB = jnp.where(srv_sel, nB[:, :, None], srvB_all[:, None, :])
-        succ = succ.at[:, :, 0 : 2 * S : 2].set(newA)
-        succ = succ.at[:, :, 1 : 2 * S : 2].set(newB)
+            # ---- assemble successors -------------------------------------------
+            # Server lanes: scatter the new pair back into the dst server's slot.
+            succ = jnp.broadcast_to(states[:, None, :], (B, M, self.lanes))
+            srv_sel = (
+                jnp.arange(S)[None, None, :] == d_srv[:, :, None]
+            ) & is_server_msg[:, :, None]  # [B, M, S]
+            newA = jnp.where(srv_sel, nA[:, :, None], srvA_all[:, None, :])
+            newB = jnp.where(srv_sel, nB[:, :, None], srvB_all[:, None, :])
+            succ = succ.at[:, :, 0 : 2 * S : 2].set(newA)
+            succ = succ.at[:, :, 1 : 2 * S : 2].set(newB)
 
-        # Client lane.
-        ncl = (
-            clients[:, None] & ~(u(0xFF) << csh)
-        ) | (ncf << csh)
-        ncl = jnp.where(is_server_msg, clients[:, None], ncl)
-        succ = succ.at[:, :, self.client_lane].set(ncl)
+            # Client lane.
+            ncl = self.clients.lane(clients, ncf, csh, is_server_msg)
+            succ = succ.at[:, :, self.client_lane].set(ncl)
 
-        # Pool: drop the delivered slot, add emissions, restore the
-        # canonical sorted-multiset form via the unrolled rank-sort
-        # (tensor/poolops.py — a jnp.sort along the minor axis was the
-        # single largest slice of this kernel's fusion on v5e). pool_size
-        # has slack over the measured max in-flight; if a successor would
-        # exceed it anyway, the row becomes the reserved all-ones POISON
-        # state (terminal — its pool is all EMPTY) and the "pool capacity"
-        # property below reports it as a discovery instead of silently
-        # truncating the state space.
-        act = jnp.arange(M, dtype=jnp.uint32)[None, :]
-        parts = [
-            jnp.where(act == i, EMPTY, pool[:, i : i + 1]) for i in range(M)
-        ] + [em1, em2, em3]
-        npool, overflow = rank_sort(parts, M)
-        succ = succ.at[:, :, self.pool_off :].set(npool)
-        succ = jnp.where(overflow[:, :, None], jnp.uint32(EMPTY), succ)
+        with jax.named_scope("emit"):
+            # Pool: drop the delivered slot, add emissions, restore the
+            # canonical sorted-multiset form via the unrolled rank-sort
+            # (tensor/poolops.py — a jnp.sort along the minor axis was the
+            # single largest slice of this kernel's fusion on v5e). pool_size
+            # has slack over the measured max in-flight; if a successor would
+            # exceed it anyway, the row becomes the reserved all-ones POISON
+            # state (terminal — its pool is all EMPTY) and the "pool capacity"
+            # property below reports it as a discovery instead of silently
+            # truncating the state space.
+            act = jnp.arange(M, dtype=jnp.uint32)[None, :]
+            parts = [
+                jnp.where(act == i, EMPTY, pool[:, i : i + 1]) for i in range(M)
+            ] + [em1, em2, em3]
+            npool, overflow = rank_sort(parts, M)
+            succ = succ.at[:, :, self.pool_off :].set(npool)
+            succ = jnp.where(overflow[:, :, None], jnp.uint32(EMPTY), succ)
 
         return succ, valid
 
@@ -582,55 +452,11 @@ class TensorPaxos(TensorModel):
         C = self.client_count
 
         def linearizable(model, states):
-            clients = states[:, model.client_lane]
-            u = jnp.uint32
-            phase = jnp.stack(
-                [(clients >> u(8 * c)) & u(3) for c in range(C)], axis=1
-            )  # [B, C]
-            ret = jnp.stack(
-                [(clients >> u(8 * c + 2)) & u(3) for c in range(C)], axis=1
-            )
-            frontier = jnp.stack(
-                [
-                    jnp.stack(
-                        [
-                            (
-                                (clients >> u(8 * c + 4 + 2 * (c2 - (c2 > c))))
-                                & u(3)
-                                if c2 != c
-                                else jnp.zeros_like(clients)
-                            )
-                            for c2 in range(C)
-                        ],
-                        axis=1,
-                    )
-                    for c in range(C)
-                ],
-                axis=1,
-            )  # [B, C, C] — f of get_c toward peer c2 (0 when c2 == c)
-
-            pm = jnp.asarray(model._lin_phase)  # [NC, C]
-            exp = jnp.asarray(model._lin_ret)  # [NC, C]
-            maxf = jnp.asarray(model._lin_maxf)  # [NC, C, C]
-
-            ph = phase[:, None, :]  # [B, 1, C]
-            phase_ok = ((pm[None] >> ph) & u(1)) == 1  # [B, NC, C]
-            has_get = (pm[None] & u(1 << PH_DONE)) != 0
-            ret_ok = (
-                ~has_get
-                | (ph == PH_GET_INFLIGHT)
-                | ((exp[None] >= 0) & (ret[:, None, :] == exp[None].astype(u)))
-            )
-            # Completed gets in combos whose sequence reads NULL can never
-            # match (GetOk always returns a real value): exp < 0 with a
-            # completed get fails unless the get is merely in flight.
-            rt_ok = jnp.all(
-                frontier[:, None, :, :] <= maxf[None], axis=3
-            )  # [B, NC, C]
-            combo_ok = jnp.all(phase_ok & ret_ok & rt_ok, axis=2)  # [B, NC]
             # Poison (pool-overflow) rows are reported by "pool capacity",
             # not as spurious linearizability violations.
-            return jnp.any(combo_ok, axis=1) | _is_poison(states)
+            return model.clients.linearizable(
+                states[:, model.client_lane]
+            ) | _is_poison(states)
 
         def value_chosen(model, states):
             pool = states[:, model.pool_off :]
@@ -649,6 +475,11 @@ class TensorPaxos(TensorModel):
             TensorProperty.sometimes("value chosen", value_chosen),
             TensorProperty.always("pool capacity", pool_capacity),
         ]
+
+    def trace_args(self) -> dict:
+        return dict(super().trace_args(), vocabulary=self.V,
+                    lin_rows=len(self.clients.lin_phase),
+                    pool_size=self.pool_size)
 
     # -- display ---------------------------------------------------------------
 
@@ -675,10 +506,7 @@ class TensorPaxos(TensorModel):
                     ],
                 )
             )
-        clients = []
-        for c in range(C):
-            f = (row[self.client_lane] >> (8 * c)) & 0xFF
-            clients.append(dict(phase=f & 3, ret=(f >> 2) & 3, frontier=f >> 4))
+        clients = self.clients.decode(row[self.client_lane])
         pool = [x for x in row[self.pool_off :] if x != int(EMPTY)]
         return dict(servers=servers, clients=clients, network=pool)
 
