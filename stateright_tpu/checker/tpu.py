@@ -382,8 +382,8 @@ class TpuChecker(Checker):
         if self._result is None:
             return {}
         if self._discovery_paths is None:
-            # Reconstruction dumps the device table; results are immutable
-            # once the search thread finishes, so build the paths once.
+            # Results are immutable once the search thread finishes, so
+            # build the paths once.
             with self._search._tracer.span("checker.discoveries",
                                            cat="checker"):
                 self._discovery_paths = {

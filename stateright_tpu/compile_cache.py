@@ -9,7 +9,8 @@
   (`REPLAYS`, `tensor/frontier.py`), kept per model definition
   (`TensorModel.definition_key()`) and engine configuration, so a new model
   instance of one definition reuses what an earlier check traced, lowered
-  and loaded.
+  and loaded; and the discovery paths' parent walks (`WALKS`,
+  `tensor/parent_walk.py`), which depend on no model.
 """
 
 from __future__ import annotations
@@ -87,6 +88,8 @@ class ProgramCache:
 EXECUTABLES = ProgramCache()
 #: Path replay programs by model definition.
 REPLAYS = ProgramCache()
+#: Parent walks by table layout and shape, mesh and chain length.
+WALKS = ProgramCache()
 
 
 def trace_context() -> Hashable:
@@ -112,3 +115,4 @@ def clear_program_caches() -> None:
     next check of each definition traces, lowers and loads again."""
     EXECUTABLES.clear()
     REPLAYS.clear()
+    WALKS.clear()

@@ -482,23 +482,28 @@ def _replay_chain(model: TensorModel, chain: list, static: _Static) -> Path:
     return Path(pairs)
 
 
-def reconstruct_path(
-    model: TensorModel, parent_map: dict, fp: int, tracer=NULL_TRACER,
-    counters: Optional[dict] = None,
-) -> Path:
-    """Walk device parent pointers, then re-execute (the TLC
-    fingerprint-stack technique, ref: src/checker/bfs.rs:380-409).
-    Fingerprints are packed host ints (see tensor/fingerprint.py pack_fp).
-    The replay is the span `path.replay`, its `steps` the transitions
-    replayed (one expand of one state each) and `traced` 1 if it traced
-    the transition program. `counters` (an engine's), when given, add
-    both up in `replay_steps_total` and `replay_traces_total`."""
+def map_chain(parent_map: dict, fp: int) -> list:
+    """`fp` and its parents in `parent_map`, `fp` first, up to a state whose
+    parent is 0 (an init state) or missing."""
     chain: list[int] = []
     cur = fp
     while cur:
         chain.append(cur)
         cur = parent_map.get(cur, 0)
-    chain.reverse()
+    return chain
+
+
+def replay_path(
+    model: TensorModel, chain: list, tracer=NULL_TRACER,
+    counters: Optional[dict] = None,
+) -> Path:
+    """Re-execute the model along `chain` (packed fingerprints, see
+    tensor/fingerprint.py pack_fp; an init state's first): the TLC
+    fingerprint-stack technique, ref: src/checker/bfs.rs:380-409. The
+    replay is the span `path.replay`, its `steps` the transitions replayed
+    (one expand of one state each) and `traced` 1 if it traced the
+    transition program. `counters` (an engine's), when given, add both up
+    in `replay_steps_total` and `replay_traces_total`."""
     with tracer.span("path.replay", cat="path", steps=len(chain) - 1) as span:
         static = _Static(model)
         path = _replay_chain(model, chain, static)
@@ -1397,4 +1402,6 @@ class FrontierSearch:
             # chain acyclic; a post-spill re-claim's parent can sit deeper
             # than the state itself.
             parent_map.update(self._store.parent_map())
-        return reconstruct_path(self.model, parent_map, fp, self._tracer)
+        return replay_path(
+            self.model, map_chain(parent_map, fp)[::-1], self._tracer
+        )

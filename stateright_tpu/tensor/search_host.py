@@ -42,9 +42,10 @@ from ..obs import (
 )
 from .costmodel import ENGINE_VARIANTS
 from .fingerprint import pack_fp
-from .frontier import SearchResult, reconstruct_path, seed_init
+from .frontier import SearchResult, map_chain, replay_path, seed_init
 from .hashtable import KV_BUCKET, _insert_impl
 from .inserts import check_table_log2, resolve_insert
+from .parent_walk import slot_keys, walk_parents
 
 
 def _finish_masks(finish_when: HasDiscoveries, props) -> tuple[int, int]:
@@ -446,6 +447,8 @@ class SearchHost:
         self._stages = Stages(engine, self._tracer)
         self._counters = {
             "parent_map_entries": 0,
+            "parent_walk_steps_total": 0,
+            "parent_walk_dispatches_total": 0,
             "replay_steps_total": 0,
             "replay_traces_total": 0,
         }
@@ -693,28 +696,34 @@ class SearchHost:
         c = self._carry
         return c.t_lo, c.t_hi, c.p_lo, c.p_hi
 
+    def _tables(self) -> tuple:
+        if self._last_tables is None:
+            # srlint: fault-ok caller-contract guard, not an I/O/device surface
+            raise RuntimeError(
+                "no table snapshot to reconstruct from: run() has not "
+                "completed since the last reset, donated resume or "
+                "donated overflow"
+            )
+        return self._last_tables
+
+    def _kv_bucket(self) -> int:
+        """Slots a kv bucket row holds (0 for the split layout)."""
+        if self.table_layout != "kv":
+            return 0
+        return min(KV_BUCKET, 1 << self.table_log2)
+
     def build_parent_map(self) -> dict:
         """{fingerprint: parent fingerprint (0 = init)} decoded from the
         last run's table snapshot, every shard's table in one transfer —
-        layout-aware (split vs kv) and cached; shared by path
-        reconstruction and the TPU checker's visitors."""
+        layout-aware (split vs kv) and cached; the TPU checker's visitors
+        read it, and path reconstruction where the tables span several
+        processes."""
         if self._parent_map is None:
-            if self._last_tables is None:
-                # srlint: fault-ok caller-contract guard, not an I/O/device surface
-                raise RuntimeError(
-                    "no table snapshot to reconstruct from: run() has not "
-                    "completed since the last reset, donated resume or "
-                    "donated overflow"
-                )
+            tables = self._tables()
             with self._tracer.span("path.parent_map", cat="path") as span:
-                t_lo, t_hi, p_lo, p_hi = to_host(self._last_tables)
-                if self.table_layout == "kv":
-                    b = min(KV_BUCKET, 1 << self.table_log2)
-                    kv = t_lo.reshape(-1, 2 * b)
-                    t_lo, t_hi = kv[:, :b], kv[:, b:]
-                t_lo, t_hi, p_lo, p_hi = (
-                    x.reshape(-1) for x in (t_lo, t_hi, p_lo, p_hi)
-                )
+                t_lo, t_hi, p_lo, p_hi = to_host(tables)
+                t_lo, t_hi = slot_keys(t_lo, t_hi, self._kv_bucket())
+                p_lo, p_hi = p_lo.reshape(-1), p_hi.reshape(-1)
                 nz = t_lo != 0
                 keys = pack_fp(t_lo[nz], t_hi[nz])
                 parents = pack_fp(p_lo[nz], p_hi[nz])
@@ -729,11 +738,30 @@ class SearchHost:
         return self._parent_map
 
     def reconstruct_path(self, fp: int):
-        """TLC-style reconstruction from the final table contents (the logic
-        is shared with the host-orchestrated engine)."""
-        self.build_parent_map()
-        return reconstruct_path(
-            self.model, self._parent_map, fp, self._tracer, self._counters
+        """TLC-style reconstruction from the final table contents: `fp`'s
+        parents looked up where the table lies (`tensor/parent_walk.py`,
+        the span `path.parents`), then the path replayed (the logic is
+        shared with the host-orchestrated engine). Tables spread over
+        several processes go through `build_parent_map` instead."""
+        tables = self._tables()
+        with self._tracer.span("path.parents", cat="path") as span:
+            if all(
+                not isinstance(a, jax.Array) or a.is_fully_addressable
+                for a in tables
+            ):
+                spill: dict = {}
+                for s in self._stores or ():
+                    spill.update(s.parent_map())
+                chain, dispatches = walk_parents(
+                    tables, fp, self._kv_bucket(), spill
+                )
+            else:
+                chain, dispatches = map_chain(self.build_parent_map(), fp), 0
+            span.set(steps=len(chain), dispatches=dispatches)
+        self._counters["parent_walk_steps_total"] += len(chain)
+        self._counters["parent_walk_dispatches_total"] += dispatches
+        return replay_path(
+            self.model, chain[::-1], self._tracer, self._counters
         )
 
     # -- retained carry --------------------------------------------------------

@@ -343,9 +343,12 @@ def test_traced_check_spans_nest_and_carry_counters(traced_check):
     # The four stages are the search call: they cover its span.
     assert sum(e["dur"] for e in stages) >= 0.98 * search["dur"]
     (disc,) = by["checker.discoveries"]
-    (pmap,) = by["path.parent_map"]
-    assert _inside(pmap, disc)
-    assert pmap["args"]["entries"] == checker.unique_state_count()
+    walks = by["path.parents"]
+    assert all(_inside(e, disc) for e in walks)
+    assert sorted(e["args"]["steps"] for e in walks) == sorted(
+        len(p) for p in paths.values())
+    assert all(e["args"]["dispatches"] == 1 for e in walks)
+    assert "path.parent_map" not in by
     replays = by["path.replay"]
     assert sorted(e["args"]["steps"] for e in replays) == sorted(
         len(p) - 1 for p in paths.values())
@@ -379,7 +382,7 @@ def test_profiler_host_plane_has_the_span_names(traced_check):
     for e in events:
         counts[e["name"]] = counts.get(e["name"], 0) + 1
     assert {*STAGES, "resident.search", "search.run", "checker.discoveries",
-            "path.parent_map", "path.replay"} <= set(counts)
+            "path.parents", "path.replay"} <= set(counts)
     for name, n in counts.items():
         assert host.count(name) == n, name
 
@@ -425,9 +428,12 @@ def test_sharded_check_spans_nest_and_stages_cover_the_search(
     assert set(phases) == set(PHASES_DETAIL_KEYS)
     assert validate_detail(checker._result.detail) == []
     (disc,) = by["checker.discoveries"]
-    (pmap,) = by["path.parent_map"]
-    assert _inside(pmap, disc)
-    assert pmap["args"]["entries"] == checker.unique_state_count() == 1568
+    walks = by["path.parents"]
+    assert all(_inside(e, disc) for e in walks)
+    assert sorted(e["args"]["steps"] for e in walks) == sorted(
+        len(p) for p in paths.values())
+    assert checker.unique_state_count() == 1568
+    assert "path.parent_map" not in by
     replays = by["path.replay"]
     assert sorted(e["args"]["steps"] for e in replays) == sorted(
         len(p) - 1 for p in paths.values())
@@ -450,7 +456,9 @@ def test_sharded_counters_are_exact_on_2pc4(sharded_traced_check):
     assert m["exchange_rows_total"] == m["generated_states"]
     assert m["exchange_slots_total"] == (
         r.steps * search.n_chips ** 2 * search.dest_capacity)
-    assert m["parent_map_entries"] == 1568
+    assert m["parent_map_entries"] == 0
+    assert m["parent_walk_steps_total"] == sum(len(p) for p in paths.values())
+    assert m["parent_walk_dispatches_total"] == len(paths)
     assert m["replay_steps_total"] == sum(len(p) - 1 for p in paths.values())
     assert m["replay_traces_total"] == 1
     for k, v in r.detail["phases"].items():
